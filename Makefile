@@ -42,8 +42,7 @@ bench-input:
 
 # Training-attention A/B (docs/training-perf.md): dense -> flash(f32) ->
 # flash(bf16) -> flash+overlap, interleaved on this machine's mesh
-# (numerics gates) plus the v5e roofline anchored to the 50.5% dense
-# baseline (step_ms strictly improving per leg; final MFU >= 55%).
+# (numerics gates; off a TPU the flash legs run interpreted).
 bench-train:
 	$(PY) bench.py --only train_attn
 

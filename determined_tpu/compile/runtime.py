@@ -9,8 +9,8 @@ content-addressed blob store:
   costs a deserialize (tens of ms) instead of seconds. This is what takes
   `cached_median_compile_s` to ~0.
 - everything else — files from the persistent XLA compilation cache dir
-  (`DET_XLA_CACHE_DIR`), uploaded verbatim under XLA's own content-hash
-  names. Pre-warming a node with them is always SAFE regardless of
+  (`enable_compilation_cache`), uploaded verbatim under XLA's own
+  content-hash names. Pre-warming a node with them is always SAFE regardless of
   signature precision: XLA only ever hits a cache entry whose key (full
   HLO + compile options + versions) matches exactly; a stray file is
   wasted bytes, never a wrong executable.
@@ -44,20 +44,84 @@ def aot_artifact_name(executable: str) -> str:
 
 
 def serialize_compiled(compiled: Any) -> bytes:
-    """Pickle a jax Compiled (payload + in/out treedefs) for the store."""
+    """Pickle a jax Compiled (payload + in/out treedefs + the ids of the
+    devices it was compiled for) for the store."""
     from jax.experimental import serialize_executable as se
 
-    return pickle.dumps(se.serialize(compiled))
+    device_ids = [d.id for d in compiled.runtime_executable().local_devices()]
+    return pickle.dumps(se.serialize(compiled) + (device_ids,))
 
 
 def load_compiled(data: bytes) -> Callable:
     """Inverse of serialize_compiled. Raises on any incompatibility
-    (platform, jax version, aval mismatch surfaces at first call) — callers
-    catch and fall back to jit."""
+    (platform, jax version, a device the executable names that this host
+    lacks; an aval mismatch surfaces at first call) — callers catch and
+    fall back to jit.
+
+    The executable is loaded onto the devices it was compiled for:
+    `deserialize_and_load` otherwise assumes every device of the backend,
+    and a one-chip serving executable on a four-chip host then demands
+    four shards of each argument."""
+    import jax
     from jax.experimental import serialize_executable as se
 
-    payload, in_tree, out_tree = pickle.loads(data)
-    return se.deserialize_and_load(payload, in_tree, out_tree)
+    payload, in_tree, out_tree, device_ids = pickle.loads(data)
+    by_id = {d.id: d for d in jax.devices()}
+    return se.deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in device_ids])
+
+
+# <checkout>/.jax_cache: fixed (the directory is part of every cache key,
+# so a temp name, pid or time in it could never hit twice) and git-ignored.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def enable_compilation_cache() -> str:
+    """Turn on jax's persistent compilation cache for this process; the
+    one switch `core.init`, `serve.task.main`, the compile worker, the
+    bench scripts and `chip_smoke.py` all call. Returns the directory in
+    effect ("" when off).
+
+    Where the cache lives is decided from outside, in this order:
+      - `DET_XLA_CACHE_DIR=` (set but empty — the documented expconf
+        override): off;
+      - `JAX_COMPILATION_CACHE_DIR`: jax reads it itself, and no directory
+        is set in code — whoever placed it (the machine, the agent) owns
+        the location;
+      - `DET_XLA_CACHE_DIR` (agent-injected, one dir per host shared by
+        every trial it runs);
+      - else `DEFAULT_CACHE_DIR`, so a plain local `Trainer.fit` or
+        `det serve --local` pays a cold compile once per checkout.
+
+    min_compile_time 0: ASHA rung trials are many and SMALL; the default
+    1 s floor would skip exactly the compiles they repeat most. The size
+    bound matters on long-lived hosts (jax only evicts when one is set).
+    """
+    import jax
+
+    if os.environ.get("DET_XLA_CACHE_DIR") == "":
+        jax.config.update("jax_enable_compilation_cache", False)
+        return ""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        cache_dir = os.environ.get("DET_XLA_CACHE_DIR") or DEFAULT_CACHE_DIR
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_compilation_cache_max_size", int(os.environ.get(
+        "DET_XLA_CACHE_MAX_BYTES", str(4 << 30))))
+    return compilation_cache_dir()
+
+
+def compilation_cache_dir() -> str:
+    """The persistent-cache directory jax is using ("" when off)."""
+    import jax
+
+    if not jax.config.jax_enable_compilation_cache:
+        return ""
+    return jax.config.jax_compilation_cache_dir or ""
 
 
 def snapshot_cache_dir(cache_dir: Optional[str]) -> Set[str]:
@@ -103,7 +167,7 @@ class FarmClient:
         self.aot_dir = aot_dir if aot_dir is not None else \
             os.environ.get("DET_COMPILE_AOT_DIR", "")
         self.xla_cache_dir = xla_cache_dir if xla_cache_dir is not None else \
-            os.environ.get("DET_XLA_CACHE_DIR", "")
+            compilation_cache_dir()
         self._cache_before = snapshot_cache_dir(self.xla_cache_dir)
         self._threads: List[threading.Thread] = []
 

@@ -73,21 +73,19 @@ def run_job(session, signature: str, hparams: Dict[str, Any], slots: int,
     (the caller reports FAILED)."""
     import jax
 
-    from determined_tpu import _jax_compat
     from determined_tpu.compile.bucketing import CompileConfig
     from determined_tpu.compile.runtime import (
         FarmClient,
         aot_artifact_name,
+        enable_compilation_cache,
         serialize_compiled,
     )
     from determined_tpu.compile.signature import step_fingerprint
-    from determined_tpu.core._context import _enable_compilation_cache
     from determined_tpu.parallel.mesh import create_mesh
     from determined_tpu.train.state import abstract_train_state
     from determined_tpu.train.step import make_eval_step, make_train_step
 
-    _jax_compat.install()
-    _enable_compilation_cache()
+    enable_compilation_cache()
     t_start = time.time()
 
     if workdir is None:

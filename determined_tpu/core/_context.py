@@ -12,7 +12,6 @@ Reference: harness/determined/core/_context.py:190-320. Two modes:
 from __future__ import annotations
 
 import logging
-import os
 from typing import Any, Dict, Optional
 
 from determined_tpu._info import ClusterInfo, get_cluster_info
@@ -85,31 +84,6 @@ class Context:
         self.close()
 
 
-def _enable_compilation_cache() -> None:
-    """Persistent XLA compilation cache (SURVEY hard part b): the agent
-    injects DET_XLA_CACHE_DIR (one dir per host, shared across trials),
-    so identical-shape ASHA rung trials skip retrace+compile — on real
-    v5e sub-slices recompilation is the dominant per-trial overhead.
-    min_compile_time 0: rung trials are many and SMALL; the default 1s
-    floor would skip exactly the compiles ASHA repeats most."""
-    cache_dir = os.environ.get("DET_XLA_CACHE_DIR", "")
-    if not cache_dir:
-        return
-    try:
-        import jax
-
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        # Bounded: long-lived hosts accumulate one entry per distinct
-        # program forever otherwise (jax only evicts when max_size set).
-        max_bytes = int(os.environ.get(
-            "DET_XLA_CACHE_MAX_BYTES", str(4 << 30)))
-        jax.config.update("jax_compilation_cache_max_size", max_bytes)
-    except Exception:
-        logger.debug("compilation cache unavailable", exc_info=True)
-
-
 def init(
     *,
     max_length: Optional[int] = None,
@@ -119,7 +93,14 @@ def init(
     async_checkpointing: bool = True,
 ) -> Context:
     """Bring up the Core API. Managed vs local is auto-detected from env."""
-    _enable_compilation_cache()
+    try:
+        from determined_tpu.compile.runtime import enable_compilation_cache
+
+        enable_compilation_cache()
+    except ImportError:
+        # A task environment without jax/numpy (torch or plain-Python
+        # trials under their own venv): there is no XLA compile to cache.
+        logger.debug("jax not importable; no compilation cache")
     info = get_cluster_info()
 
     if distributed is None:

@@ -16,8 +16,8 @@ that the host must always run ahead of the accelerator.
     the batch is resident on HBM — the H2D copy overlaps the previous
     step's compute instead of serializing with it,
   - multi-host processes go through
-    `jax.make_array_from_process_local_data` (behind the `_jax_compat`
-    shim) so each host transfers only its local shard,
+    `jax.make_array_from_process_local_data` so each host transfers
+    only its local shard,
   - iterator exceptions are re-raised in the consumer (after any batches
     queued before the failure — order preserved), and `close()` tears the
     thread down deterministically on preemption / op boundaries,

@@ -92,6 +92,28 @@ def apply_task_environment(env: dict, config: dict) -> dict:
     return env
 
 
+def bind_tpu_chips(env: dict, slot_ids: list) -> None:
+    """Bind the allocation's slots to chips: libtpu claims EVERY chip of
+    the host for the first process that initializes it, so without this a
+    1-slot trial on a 4-chip host takes all four and a second concurrent
+    trial cannot start. The whole host needs no binding (libtpu's
+    default); one chip is a 1x1x1 process on that chip. Other sub-host
+    shapes would need the chips' ICI coordinates to form the bounds — not
+    supported, and said so rather than handing the task the wrong chips.
+    An expconf `environment_variables` entry for any of these wins."""
+    host_slots = int(env.get("DET_HOST_SLOTS", "0"))
+    if len(slot_ids) == host_slots:
+        return
+    if len(slot_ids) != 1:
+        raise RuntimeError(
+            f"allocation of slots {slot_ids} on a {host_slots}-chip host: "
+            "only one chip or the whole host can be bound to a task "
+            "(resources.slots_per_trial: 1 or the host's chip count)")
+    env.setdefault("TPU_VISIBLE_CHIPS", str(slot_ids[0]))
+    env.setdefault("TPU_CHIPS_PER_PROCESS_BOUNDS", "1,1,1")
+    env.setdefault("TPU_PROCESS_BOUNDS", "1,1,1")
+
+
 def main() -> int:
     import json
 
@@ -110,10 +132,10 @@ def main() -> int:
 
     # Virtual-slot devclusters (JAX_PLATFORMS=cpu): make the task's visible
     # JAX device count MATCH its allocated slot count, so the mesh resolves
-    # at the size the scheduler granted — on a real TPU-VM the runtime
-    # exposes the host's chips and this is a no-op. This is what lets an
-    # elastic re-placement at a new size (docs/elasticity.md) actually
-    # re-resolve the mesh instead of always seeing one CPU device.
+    # at the size the scheduler granted (tpu slots: bind_tpu_chips below).
+    # This is what lets an elastic re-placement at a new size
+    # (docs/elasticity.md) actually re-resolve the mesh instead of always
+    # seeing one CPU device.
     try:
         slot_ids = json.loads(env.get("DET_SLOT_IDS", "[]"))
     except ValueError:
@@ -124,6 +146,9 @@ def main() -> int:
         env["XLA_FLAGS"] = (
             env.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={len(slot_ids)}")
+    if slot_ids and env.get("DET_SLOT_TYPE") == "tpu" \
+            and env.get("JAX_PLATFORMS", "") != "cpu":
+        bind_tpu_chips(env, slot_ids)
 
     # startup-hook.sh from the context dir runs before the entrypoint
     # (reference exec/prep_container.py + entrypoint.sh: dependency

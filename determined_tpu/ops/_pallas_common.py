@@ -9,9 +9,11 @@ dimension, initialized at the first tile and normalized out at the last.
 This module is the single home for that machinery so the two kernels
 cannot drift (the decode kernel once carried its own private copies):
 
-  - availability / interpret-mode policy (`HAVE_PALLAS`,
-    `interpret_default`): tier-1 runs every kernel on CPU through the
-    pallas interpreter, real TPUs compile the same code via Mosaic;
+  - availability (`HAVE_PALLAS`). There is no interpret-mode policy
+    here: the kernels compile through Mosaic unless the caller asks
+    otherwise — a test passes `interpret=True` or traces under
+    `pltpu.force_tpu_interpret_mode()`; nothing sniffs the backend, so a
+    kernel can never be selected and then quietly interpreted;
   - grid sizing (`pick_blocks`): MXU/VMEM-friendly tile edges that
     divide the sequence;
   - VMEM scratch shapes for the online-softmax state
@@ -26,7 +28,7 @@ and CPU-only deploys must not pay a hard pallas dependency.
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -43,11 +45,6 @@ except ImportError:  # pragma: no cover - pallas not in this build
 # row is masked (the first causal tile's padding rows); a large-negative
 # finite value keeps exp() at exactly 0.0 without poisoning m.
 NEG_INF = -1e30
-
-
-def interpret_default() -> bool:
-    """Pallas TPU kernels run interpreted off-TPU (tier-1 on CPU)."""
-    return jax.default_backend() != "tpu"
 
 
 def pick_blocks(s: int, max_block: int = 512) -> Tuple[int, int]:
@@ -67,11 +64,12 @@ def pick_blocks(s: int, max_block: int = 512) -> Tuple[int, int]:
     return block_q, block_k
 
 
-def softmax_scratch(rows: int, d: int):
+def softmax_scratch(rows: Union[int, Tuple[int, ...]], d: int):
     """VMEM scratch for one online-softmax accumulator: [acc, m, l].
 
-    `rows` is the per-program row count (query rows for the training
-    kernel, heads for the decode kernel); `d` the output feature depth.
+    `rows` is the per-program row shape (query rows for the training
+    kernel; `(heads, 1)` for the decode kernel, whose matmuls are batched
+    over heads); `d` the output feature depth.
     All three are fp32 regardless of the i/o dtype — the running
     statistics are the one place bf16 is never acceptable (exp/sum
     cancellation), which is also why they live in dedicated scratch
@@ -79,10 +77,11 @@ def softmax_scratch(rows: int, d: int):
     """
     if not HAVE_PALLAS:  # pragma: no cover - guarded by callers
         raise RuntimeError("pallas unavailable in this jax build")
+    lead = (rows,) if isinstance(rows, int) else tuple(rows)
     return [
-        pltpu.VMEM((rows, d), jnp.float32),  # acc
-        pltpu.VMEM((rows, 1), jnp.float32),  # running max m
-        pltpu.VMEM((rows, 1), jnp.float32),  # running normalizer l
+        pltpu.VMEM(lead + (d,), jnp.float32),  # acc
+        pltpu.VMEM(lead + (1,), jnp.float32),  # running max m
+        pltpu.VMEM(lead + (1,), jnp.float32),  # running normalizer l
     ]
 
 
@@ -97,9 +96,10 @@ def online_softmax_update(st, v, acc_ref, m_ref, l_ref,
                           dimension_numbers=(((1,), (0,)), ((), ()))):
     """Fold one masked logits tile into the VMEM (acc, m, l) state.
 
-    st: fp32 logits tile [rows, cols] with masked entries at NEG_INF;
+    st: fp32 logits tile [..., rows, cols] with masked entries at NEG_INF;
     v:  the matching value tile, contracted with the tile's probabilities
-        per `dimension_numbers` (default: plain [cols, d] matmul).
+        per `dimension_numbers` (default: plain [cols, d] matmul; the
+        decode kernel batches over a leading heads dim).
 
     The p·v matmul runs in the value dtype (bf16 inputs hit the MXU's
     bf16 path) but accumulates into fp32 (`preferred_element_type`) —
@@ -121,5 +121,5 @@ def finish_softmax_scratch(o_ref, acc_ref, l_ref, idx=...) -> None:
     """Normalize the accumulator out to the output block's dtype.
 
     `idx` addresses the output block when it carries a leading unit dim
-    (the decode kernel's (1, H, Dh) slot block passes idx=0)."""
+    (the decode kernel's (1, H, 1, Dh) slot block passes idx=0)."""
     o_ref[idx] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)

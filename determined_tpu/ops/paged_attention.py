@@ -27,8 +27,11 @@ Two interchangeable implementations (selected by
     exists. The inner loop is an online softmax: fp32 running max `m`,
     normalizer `l`, and accumulator `acc` live in VMEM scratch across
     the `b` iterations of one slot; the output block is written at the
-    final block index. Tier-1 runs it on CPU through pallas interpret
-    mode (`_jax_compat`); on TPU the same kernel compiles natively.
+    final block index. Both inner products are matmuls batched over a
+    LEADING heads dim with 3-D operands (`[H, 1, Dh] x [H, bs, Dh]`) —
+    the form Mosaic lowers; a 2-D `[H, Dh]` lhs with a batch dim and no
+    non-contracting dim is refused by its dot-dimension parser. Compiles
+    through Mosaic unless the caller passes `interpret=True` (tests).
 
 Inactive slots point every table entry at a reserved trash block and sit
 at position 0 — they compute garbage the batcher discards, exactly like
@@ -48,7 +51,6 @@ from determined_tpu.ops._pallas_common import (
     NEG_INF,
     finish_softmax_scratch,
     init_softmax_scratch,
-    interpret_default as _interpret_default,
     online_softmax_update,
     softmax_scratch,
 )
@@ -111,17 +113,17 @@ def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     # programs still run (the TPU grid is static) but touch no state.
     @pl.when(b * block_size <= pos)
     def _accumulate():
-        q = q_ref[0].astype(jnp.float32)                       # [H, Dh]
-        k = jnp.swapaxes(k_ref[0], 0, 1).astype(jnp.float32)   # [H, bs, Dh]
-        v = jnp.swapaxes(v_ref[0], 0, 1).astype(jnp.float32)
+        q = q_ref[0]                                           # [H, 1, Dh]
+        k = jnp.swapaxes(k_ref[0], 0, 1)                       # [H, bs, Dh]
+        v = jnp.swapaxes(v_ref[0], 0, 1)
         st = jax.lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale        # [H, bs]
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale        # [H, 1, bs]
         idx = b * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_size), 1)
+            jnp.int32, (1, 1, block_size), 2)
         st = jnp.where(idx <= pos, st, NEG_INF)
         online_softmax_update(st, v, acc_ref, m_ref, l_ref,
-                              (((1,), (1,)), ((0,), (0,))))    # [H, Dh]
+                              (((2,), (1,)), ((0,), (0,))))    # [H, 1, Dh]
 
     @pl.when(b == mb - 1)
     def _finish():
@@ -134,7 +136,7 @@ def paged_attention_pallas(
     v_pool: jax.Array,        # [num_pool_blocks, block_size, H, Dh]
     block_tables: jax.Array,  # [slots, max_blocks] int32
     positions: jax.Array,     # [slots] int32
-    interpret=None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Pallas paged decode attention → [slots, H, Dh] in q.dtype."""
     if not HAVE_PALLAS:
@@ -144,28 +146,25 @@ def paged_attention_pallas(
     slots, nh, dh = q.shape
     bs = k_pool.shape[1]
     mb = block_tables.shape[1]
-    if interpret is None:
-        interpret = _interpret_default()
+    # The query rides as [slots, H, 1, Dh]: the unit dim is the matmuls'
+    # non-contracting lhs dim, added out here because Mosaic cannot
+    # reshape a packed bf16 [H, Dh] tile to [H, 1, Dh] in-kernel.
+    q_block = pl.BlockSpec((1, nh, 1, dh), lambda s, b, tbl, pos: (s, 0, 0, 0))
+    kv_block = pl.BlockSpec((1, bs, nh, dh),
+                            lambda s, b, tbl, pos: (tbl[s, b], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # block_tables, positions
         grid=(slots, mb),
-        in_specs=[
-            pl.BlockSpec((1, nh, dh), lambda s, b, tbl, pos: (s, 0, 0)),
-            pl.BlockSpec((1, bs, nh, dh),
-                         lambda s, b, tbl, pos: (tbl[s, b], 0, 0, 0)),
-            pl.BlockSpec((1, bs, nh, dh),
-                         lambda s, b, tbl, pos: (tbl[s, b], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, nh, dh), lambda s, b, tbl, pos: (s, 0, 0)),
-        scratch_shapes=softmax_scratch(nh, dh),  # fp32 acc/m/l in VMEM
+        in_specs=[q_block, kv_block, kv_block],
+        out_specs=q_block,
+        scratch_shapes=softmax_scratch((nh, 1), dh),  # fp32 acc/m/l in VMEM
     )
     kernel = functools.partial(
         _paged_kernel, block_size=bs, scale=1.0 / (dh ** 0.5))
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((slots, nh, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((slots, nh, 1, dh), q.dtype),
         cost_estimate=pl.CostEstimate(
             # Worst case: every table entry live. 2 matmuls over the lane.
             flops=int(4 * slots * mb * bs * nh * dh),
@@ -174,7 +173,8 @@ def paged_attention_pallas(
             transcendentals=int(slots * mb * bs * nh),
         ),
         interpret=interpret,
-    )(block_tables, positions, q, k_pool, v_pool)
+    )(block_tables, positions, q[:, :, None, :], k_pool, v_pool)
+    return out[:, :, 0, :]
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, positions,

@@ -22,10 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from determined_tpu import _jax_compat
-
-_jax_compat.install()  # jax.sharding.get_abstract_mesh on jax < 0.5
-
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
 

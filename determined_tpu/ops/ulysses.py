@@ -27,10 +27,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from determined_tpu import _jax_compat
-
-_jax_compat.install()  # jax.sharding.get_abstract_mesh on jax < 0.5
-
 
 def _inner_attention(q, k, v, causal: bool):
     """[B, S, H, Dh] full-sequence attention (XLA path)."""

@@ -20,10 +20,13 @@ unconditionally — XLA treats size-1 mesh axes as free.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import math
-from typing import Any, Mapping, Optional, Sequence
+from typing import Any, Iterable, Mapping, Optional, Sequence
 
 import numpy as np
+
+logger = logging.getLogger("determined_tpu.parallel")
 
 AXIS_ORDER = ("data", "pipeline", "fsdp", "expert", "context", "tensor")
 
@@ -109,9 +112,15 @@ def create_mesh(
     shape = mesh_shape_for_devices(len(devices), config)
     try:
         dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except (ValueError, AssertionError, NotImplementedError):
-        # Virtual/CPU devices or odd shapes: plain reshape is fine — there is
-        # no physical topology to optimise for.
+    except (ValueError, AssertionError, NotImplementedError) as e:
+        # A shape the physical topology cannot host (or a device subset,
+        # e.g. an elastic resize over a prefix of the chips): device order
+        # is still a correct mesh, only the ICI ring placement is lost —
+        # say so instead of degrading quietly.
+        logger.warning(
+            "create_device_mesh could not lay %s over %d %s devices (%s); "
+            "using plain device order", dict(zip(AXIS_ORDER, shape)),
+            len(devices), devices[0].platform, e)
         dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, AXIS_ORDER)
 
@@ -123,3 +132,37 @@ def single_device_mesh(device: Optional[Any] = None):
     if device is None:
         device = jax.devices()[0]
     return create_mesh(MeshConfig(data=1), [device])
+
+
+def ambient_mesh():
+    """The abstract mesh in effect for the current trace, or None.
+
+    `jax.sharding.set_mesh` (the Trainer, the deviceless compile recipe)
+    and `use_abstract_mesh` both install it; inside a `shard_map` body its
+    `manual_axes` name the axes already bound."""
+    import jax
+
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
+
+
+def on_tpu(devices: Optional[Iterable[Any]] = None) -> bool:
+    """THE answer to "is this a TPU" (kernel selection, bf16 compute,
+    peak-FLOPs lookup): the platform of the devices being compiled for.
+
+    `devices` when the caller holds them (a Trainer's mesh, an engine's
+    device). Else the ambient mesh's abstract device — which is what makes
+    a TPU compile from a CPU default backend (the deviceless topology
+    recipe, a compile-farm worker) select the Mosaic kernels instead of
+    whatever the host happens to run; an abstract device carries only its
+    kind, and every TPU generation's kind starts with "TPU". Else the
+    process's default backend.
+    """
+    import jax
+
+    if devices is not None:
+        return next(iter(devices)).platform == "tpu"
+    mesh = ambient_mesh()
+    if mesh is not None and mesh.abstract_device is not None:
+        return mesh.abstract_device.device_kind.startswith("TPU")
+    return jax.default_backend() == "tpu"

@@ -90,14 +90,6 @@ def shard_logical(x, logical_axes: Sequence[Optional[str]], rules: Optional[Logi
     """`with_sharding_constraint` by logical axis names (no-op outside jit/mesh)."""
     import jax
 
-    from determined_tpu import _jax_compat
-
-    if _jax_compat.in_manual_shard_map():
-        # Fully-manual shard_map body (old-jax pipeline fallback): every
-        # mesh axis is already bound, so a constraint naming one fails at
-        # lowering (past any try here) — and the hint is meaningless on a
-        # local block anyway.
-        return x
     spec = logical_to_mesh_spec(logical_axes, rules)
     try:
         return jax.lax.with_sharding_constraint(x, spec)

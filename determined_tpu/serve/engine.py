@@ -25,12 +25,9 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from determined_tpu import _jax_compat
 from determined_tpu.models.gpt2 import Config
 from determined_tpu.parallel.sharding import LogicalRules
 from determined_tpu.serve import model as smodel
-
-_jax_compat.install()
 
 logger = logging.getLogger("determined_tpu.serve")
 
@@ -91,15 +88,26 @@ def load_checkpoint_params(
 def _restore_raw(checkpoint_ctx, storage_id: str) -> Any:
     """Whole-tree restore without a template (serving has no optimizer, so
     it cannot reconstruct the TrainState template the trainer restores
-    into; orbax rebuilds the saved structure from checkpoint metadata)."""
+    into; orbax rebuilds the saved structure from checkpoint metadata).
+
+    Leaves come back as host numpy arrays: a template-less device restore
+    re-creates the SAVED shardings, so a checkpoint trained over four
+    chips would refuse to load on a one-chip replica ("available devices
+    are different") and, on a four-chip host, would hand the one-device
+    engine params spread over all four."""
     import os
 
+    import jax
     import orbax.checkpoint as ocp
 
     path = checkpoint_ctx._array_path(storage_id)
     state_dir = path + "/state" if "://" in path else os.path.join(
         path, "state")
-    return ocp.StandardCheckpointer().restore(state_dir)
+    ckptr = ocp.PyTreeCheckpointer()
+    as_numpy = jax.tree_util.tree_map(
+        lambda _: ocp.RestoreArgs(restore_type=np.ndarray),
+        ckptr.metadata(state_dir).item_metadata.tree)
+    return ckptr.restore(state_dir, restore_args=as_numpy)
 
 
 def resolve_attention_impl(impl: str) -> str:
@@ -107,11 +115,12 @@ def resolve_attention_impl(impl: str) -> str:
 
     "auto" picks the Pallas kernel on TPU and the jnp gather reference
     elsewhere (both paged); "pallas"/"reference"/"dense" force a path —
-    off-TPU the kernel runs through pallas interpret mode (tier-1)."""
-    import jax
+    a forced "pallas" off-TPU compiles only under a test's
+    `pltpu.force_tpu_interpret_mode()`."""
+    from determined_tpu.parallel.mesh import on_tpu
 
     if impl == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "reference"
+        return "pallas" if on_tpu() else "reference"
     if impl in ("pallas", "reference", "dense"):
         return impl
     raise ValueError(
@@ -161,7 +170,10 @@ class ServingEngine:
             for b in (prefill_buckets or default_buckets(self.max_seq_len))))
         self.prefill_buckets = buckets
         self.rules = rules or LogicalRules()
-        self.params = jax.device_put(params)
+        # One replica, one device: everything the executables take lives
+        # on it (params may arrive as numpy or sharded over a mesh).
+        device = jax.local_devices()[0]
+        self.params = jax.device_put(params, device)
         # Multi-adapter serving (docs/serving.md "Model lifecycle"):
         # adapter name → params tree of a head-tuned fine-tune. Only the
         # (tied) embedding/LM-head table participates: the stack
@@ -188,8 +200,9 @@ class ServingEngine:
                         f"!= base {tuple(base_wte.shape)} — adapters must "
                         "share the base model's geometry")
                 self.adapter_ids[name] = len(tables)
-                tables.append(jnp.asarray(wte, base_wte.dtype))
-            self._adapter_stack = jax.device_put(jnp.stack(tables))
+                tables.append(jax.device_put(
+                    jnp.asarray(wte, base_wte.dtype), device))
+            self._adapter_stack = jnp.stack(tables)
             self._slot_adapters = np.zeros((slots,), np.int32)
         self.attention_impl = resolve_attention_impl(attention_impl)
         self.paged = self.attention_impl != "dense"

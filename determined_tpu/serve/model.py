@@ -33,7 +33,7 @@ dedicated trash block.
 Works for dense and MoE blocks (the MoE FFN routes per token, so a
 1-token decode step reuses ops/moe.moe_block unchanged). All functions are
 shape-static and jit/AOT-friendly; tier-1 exercises them on the CPU
-backend via the `_jax_compat` shims.
+backend.
 """
 
 from __future__ import annotations

@@ -264,6 +264,11 @@ def main(argv=None) -> int:
 
         session = Session(master, os.environ.get("DET_SESSION_TOKEN"))
 
+    from determined_tpu.compile.runtime import enable_compilation_cache
+
+    # Before the first compile: a respawned replica whose AOT artifacts
+    # are gone still finds its executables in the persistent cache.
+    enable_compilation_cache()
     engine, batcher = build_replica(config, session=session)
 
     # Per-request span tracing (docs/observability.md "Request spans"):

@@ -106,8 +106,7 @@ def make_multi_step(
     `steps_per_call` optimizer steps inside ONE jitted call via `lax.scan`.
 
     TPU-first rationale: a per-step host→device dispatch costs real latency
-    (hundreds of µs on a TPU-VM, far more through remote tunnels) and forces
-    a host sync point. Scanning N steps per dispatch amortizes that to ~0
+    and forces a host sync point. Scanning N steps per dispatch amortizes that to ~0
     and lets XLA overlap the next step's grads with the optimizer update —
     the same structure production LLM trainers use. Batches: every leaf has
     a leading [steps_per_call, ...] axis (stack loader batches). Returned

@@ -24,8 +24,8 @@ from typing import Any, Dict, Iterable, Iterator, Optional
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
-from determined_tpu import _jax_compat
 from determined_tpu import core as core_mod
 from determined_tpu.common import faultpoint
 from determined_tpu.common import trace as trace_mod
@@ -47,8 +47,6 @@ from determined_tpu.train.step import (
 )
 from determined_tpu.train.trial import JaxTrial
 from determined_tpu.train.watchdog import StepWatchdog
-
-_jax_compat.install()  # jax.sharding.set_mesh on jax < 0.5
 
 logger = logging.getLogger("determined_tpu.train")
 
@@ -165,8 +163,9 @@ class Trainer:
         self._compile_cfg: Optional[CompileConfig] = None
         self._compile_events: list = []
         # Resolved `optimizations.attention_impl` (auto → pallas/reference
-        # by backend) — attached to the harness.compile span and the
-        # compile-event metrics flush so A/B runs are attributable.
+        # by the mesh devices' platform) — attached to the harness.compile
+        # span and the compile-event metrics flush so A/B runs are
+        # attributable.
         self._attention_impl: Optional[str] = None
 
     # -- setup ---------------------------------------------------------
@@ -268,8 +267,15 @@ class Trainer:
 
         opt = self._optimizations_config(self.core)
         self._attention_impl = resolve_attention_impl(
-            opt.get("attention_impl"))
+            opt.get("attention_impl"), self.mesh.devices.flat)
         span_attrs = {"attention_impl": self._attention_impl}
+        dev = self.mesh.devices.flat[0]
+        logger.info(
+            "mesh %s over %d %s device(s) (%s); attention_impl %s by "
+            "platform (calls the kernel cannot serve are logged by ops)",
+            {a: n for a, n in self.mesh.shape.items() if n > 1} or "1x",
+            self.mesh.size, dev.platform, dev.device_kind,
+            self._attention_impl)
         # Pre-partitioned step inputs (docs/training-perf.md): declare the
         # batch argument's in_shardings; fit() hands the DevicePrefetcher
         # the same value, so arrivals already match the compiled layout.
@@ -543,6 +549,12 @@ class Trainer:
                                         core, target, step,
                                         last_checkpointed, data_iter,
                                         prefetcher)
+                                # The eager split under set_mesh left
+                                # the key committed to the OLD mesh's
+                                # devices; the next split runs under the
+                                # new one.
+                                rng = jax.device_put(rng, NamedSharding(
+                                    self.mesh, PartitionSpec()))
                                 last_checkpointed = step
                                 preempted = False
                                 watchdog.beat()

@@ -11,6 +11,7 @@ example runs air-gapped; point `tokens_path` at a memory-mapped token file
 (np.memmap int32, produced by any tokenizer) for real pretraining.
 """
 
+import logging
 import os
 
 import numpy as np
@@ -136,6 +137,9 @@ class GPT2Trial(JaxTrial):
 
 
 if __name__ == "__main__":
+    # The Trainer says at INFO which devices and attention path it got.
+    logging.basicConfig(format="%(name)s: %(message)s")
+    logging.getLogger("determined_tpu").setLevel(logging.INFO)
     with core.init() as ctx:
         trial = GPT2Trial(
             TrialContext(hparams=ctx.hparams, core_context=ctx,

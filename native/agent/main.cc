@@ -6,8 +6,9 @@
 //  - transport is HTTP long-poll against the master instead of a websocket;
 //  - tasks are host processes, not docker containers (a TPU-VM host runs
 //    one process owning all local chips; the agent supervises it directly);
-//  - slots are TPU chips detected from /dev/accel* (or vfio), with
-//    DET_AGENT_SLOTS as the "artificial slots" testing override
+//  - slots are TPU chips detected from /dev/accel* or, where the host
+//    exposes its chips through vfio-pci instead (v5e), /dev/vfio/<group>;
+//    DET_AGENT_SLOTS is the "artificial slots" testing override
 //    (detect.go:39-56).
 //
 // Log shipping follows master/static/srv/ship_logs.py: reader threads
@@ -140,6 +141,7 @@ std::map<std::string, std::shared_ptr<Task>> g_tasks GUARDED_BY(g_mu);
 // Observability state for /metrics (docs/observability.md).
 std::atomic<bool> g_draining{false};  // termination notice posted
 std::atomic<int> g_slots{0};          // slots registered with the master
+std::atomic<bool> g_tpu_slots{false};  // ...and whether they are tpu chips
 const auto g_started = std::chrono::steady_clock::now();
 
 // Ownership-lease state (docs/cluster-ops.md "Leases, fencing &
@@ -372,23 +374,59 @@ void drain_task_logs(std::shared_ptr<Task> task) {
   });
 }
 
+// ---- persistent XLA compilation cache -----------------------------------
+//
+// Where the cache lives is decided from outside (docs/compile-farm.md,
+// compile/runtime.py enable_compilation_cache): JAX_COMPILATION_CACHE_DIR
+// in the agent's environment is inherited by every task untouched and is
+// where pre-warmed entries go; only without it does the agent inject its
+// own fixed host-local dir (work_root/xla_cache) as DET_XLA_CACHE_DIR.
+
+bool cache_dir_placed_outside() {
+  const char* p = getenv("JAX_COMPILATION_CACHE_DIR");
+  return p != nullptr && *p != '\0';
+}
+
+std::string xla_cache_dir(const AgentOptions& opts) {
+  return cache_dir_placed_outside()
+             ? std::string(getenv("JAX_COMPILATION_CACHE_DIR"))
+             : opts.work_root + "/xla_cache";
+}
+
+// Child-side, after fork. overwrite=0: an expconf environment_variables
+// override (including the documented `DET_XLA_CACHE_DIR=` off switch) wins.
+void inject_xla_cache_env(const AgentOptions& opts) {
+  if (cache_dir_placed_outside()) return;
+  setenv("DET_XLA_CACHE_DIR", xla_cache_dir(opts).c_str(), 0);
+}
+
 // ---- device detection ---------------------------------------------------
 
-int detect_tpu_chips() {
-  // TPU VMs expose chips as /dev/accel0..N (PCI) or /dev/vfio entries.
+// Entries of `dir` named `prefix` followed by a number.
+int count_dev_nodes(const char* dir, const char* prefix) {
   int count = 0;
-  DIR* d = opendir("/dev");
-  if (d != nullptr) {
-    dirent* e;
-    while ((e = readdir(d)) != nullptr) {
-      if (strncmp(e->d_name, "accel", 5) == 0) ++count;
-    }
-    closedir(d);
+  DIR* d = opendir(dir);
+  if (d == nullptr) return 0;
+  size_t plen = strlen(prefix);
+  dirent* e;
+  while ((e = readdir(d)) != nullptr) {
+    if (strncmp(e->d_name, prefix, plen) != 0) continue;
+    const char* rest = e->d_name + plen;
+    if (*rest != '\0' && strspn(rest, "0123456789") == strlen(rest)) ++count;
   }
+  closedir(d);
   return count;
 }
 
-Json detect_slots(AgentOptions& opts) {
+int detect_tpu_chips() {
+  // One /dev/accelN per chip with the accel driver; with vfio-pci (v5e
+  // hosts) one IOMMU group node /dev/vfio/<N> per chip — /dev/vfio/vfio
+  // is the container control node, not a chip.
+  int n = count_dev_nodes("/dev", "accel");
+  return n > 0 ? n : count_dev_nodes("/dev/vfio", "");
+}
+
+Json detect_slots(const AgentOptions& opts) {
   Json slots = Json::array();
   int n;
   std::string type;
@@ -397,8 +435,15 @@ Json detect_slots(AgentOptions& opts) {
     type = opts.slot_type == "auto" ? "tpu" : opts.slot_type;
   } else if ((n = detect_tpu_chips()) > 0) {
     type = "tpu";
+  } else if (opts.slot_type == "tpu") {
+    // Asked for TPU slots and found no chip: registering a cpu slot
+    // instead would schedule TPU trials onto a host that cannot run them.
+    std::cerr << "agent: --slot-type tpu but no TPU chip found (no "
+                 "/dev/accel* and no /dev/vfio/<group>); refusing to "
+                 "register a cpu slot in its place" << std::endl;
+    exit(2);
   } else {
-    n = 1;  // cpu fallback: one schedulable slot per host
+    n = 1;  // no accelerator: one schedulable cpu slot per host
     type = "cpu";
   }
   for (int i = 0; i < n; ++i) {
@@ -732,7 +777,7 @@ PrewarmResult prewarm_compile_cache(const AgentOptions& opts,
   Json doc = Json::parse_or_null(r.body);
   std::string aot_dir = opts.work_root + "/aot_cache";
   std::string sig_dir = aot_dir + "/" + signature;
-  std::string xla_dir = opts.work_root + "/xla_cache";
+  std::string xla_dir = xla_cache_dir(opts);
   mkdir(opts.work_root.c_str(), 0755);
   for (const auto& f : doc["files"].as_array()) {
     std::string name = f["name"].as_string("");
@@ -793,8 +838,7 @@ void run_compile_job(const AgentOptions& opts, const Json& action) {
     }
     // The worker compiles INTO the node's shared persistent cache, so
     // this host is warm before any artifact round-trips.
-    std::string xla_cache = opts.work_root + "/xla_cache";
-    setenv("DET_XLA_CACHE_DIR", xla_cache.c_str(), 0);
+    inject_xla_cache_env(opts);
     execlp("python3", "python3", "-m", "determined_tpu.compile",
            static_cast<char*>(nullptr));
     _exit(127);
@@ -906,9 +950,12 @@ void start_task(const AgentOptions& opts, const Json& action) {
     // Host-local persistent XLA compilation cache, shared across every
     // trial this agent runs: identical-shape ASHA rung trials skip the
     // retrace+compile that otherwise dominates short trials.
-    // overwrite=0: an expconf environment_variables override wins.
-    std::string xla_cache = opts.work_root + "/xla_cache";
-    setenv("DET_XLA_CACHE_DIR", xla_cache.c_str(), 0);
+    inject_xla_cache_env(opts);
+    // What the slots ARE and how many the host has: exec/launch.py binds
+    // DET_SLOT_IDS to chips when they are tpu (libtpu otherwise claims
+    // every chip of the host for the first task to start).
+    setenv("DET_SLOT_TYPE", g_tpu_slots.load() ? "tpu" : "cpu", 1);
+    setenv("DET_HOST_SLOTS", std::to_string(g_slots.load()).c_str(), 1);
     // Prewarmed AOT executables (compile farm); the harness looks in
     // $DET_COMPILE_AOT_DIR/$DET_COMPILE_SIGNATURE/.
     std::string aot_cache = opts.work_root + "/aot_cache";
@@ -1085,9 +1132,10 @@ bool register_with_master(const AgentOptions& opts, bool reconnect) {
   body["addr"] = opts.addr;
   body["reconnect"] = reconnect;
   body["preemptible"] = opts.preemptible;
-  AgentOptions mut = opts;
-  Json slots = detect_slots(mut);
+  Json slots = detect_slots(opts);
   g_slots = static_cast<int>(slots.as_array().size());
+  g_tpu_slots = g_slots > 0 &&
+                slots.as_array()[0]["type"].as_string("") == "tpu";
   body["slots"] = slots;
   try {
     auto r = master_call(opts.master_url, "POST",

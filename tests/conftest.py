@@ -13,16 +13,33 @@ os.environ["XLA_FLAGS"] = (
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("KERAS_BACKEND", "jax")  # Keras 3 on the JAX backend
 
+import atexit  # noqa: E402
+import shutil  # noqa: E402
+import tempfile  # noqa: E402
+
 import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
-
-from determined_tpu import _jax_compat  # noqa: E402
-
-_jax_compat.install()  # jax.sharding.set_mesh & co on jax < 0.5
-
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+
+from determined_tpu.compile import runtime as _runtime  # noqa: E402
+
+# Every session compiles from a cold cache of its own, like a fresh
+# checkout does. Not only for hermeticity: on XLA:CPU (jax 0.9.0) an
+# executable LOADED from the persistent cache cannot be re-serialized —
+# the AOT round trip (compile farm tests) then dies with "Function
+# wrapped_* not found" — so a warm <checkout>/.jax_cache from an earlier
+# session fails tests a cold one passes. (TPU executables round-trip fine:
+# checked on the chip, PR 21.) Children decide for themselves: agents
+# inject their own dir, other subprocesses use the checkout's.
+_CHECKOUT_CACHE_DIR = _runtime.DEFAULT_CACHE_DIR
+_runtime.DEFAULT_CACHE_DIR = tempfile.mkdtemp(prefix="det-test-jax-cache-")
+atexit.register(shutil.rmtree, _runtime.DEFAULT_CACHE_DIR, ignore_errors=True)
+
+
+@pytest.fixture()
+def checkout_cache_dir():
+    """What `DEFAULT_CACHE_DIR` is outside the test session."""
+    return _CHECKOUT_CACHE_DIR
 
 
 @pytest.fixture(scope="session")

@@ -1,0 +1,225 @@
+"""What the CPU sandbox can say about the chip: the real XLA:TPU + Mosaic
+compilers run without one.
+
+libtpu is installed here, so `jax.experimental.topologies` describes a
+v5e 2x2 host and `jit(f).lower(<ShapeDtypeStructs sharded on that
+mesh>).compile()` compiles for it under JAX_PLATFORMS=cpu — no device, no
+execution, seconds per kernel. These tests hold the two Pallas kernels
+and their multi-device placement to "Mosaic accepts this"; whether the
+numbers are right on the chip is chip_smoke.py's job. Also here: where
+the persistent compile cache lives (`enable_compilation_cache`).
+
+Budget: < 30 s for the file (tier-1 is time-boxed).
+"""
+
+import functools
+import importlib.util
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from determined_tpu.parallel.mesh import AXIS_ORDER, MeshConfig, on_tpu
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+pytestmark = pytest.mark.skipif(
+    importlib.util.find_spec("libtpu") is None,
+    reason="deviceless TPU compilation needs libtpu")
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    return topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices
+
+
+def _mesh(devices, **axes):
+    shape = MeshConfig(**axes).resolve(len(devices)).sizes()
+    return Mesh(np.asarray(devices).reshape(shape), AXIS_ORDER)
+
+
+def _sds(mesh, shape, dtype, *spec):
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=NamedSharding(mesh, P(*spec)))
+
+
+def test_on_tpu_follows_the_devices_compiled_for(v5e, devices):
+    """One answer to "is this a TPU", from the target devices — not the
+    host's default backend (cpu here)."""
+    assert not on_tpu()
+    assert on_tpu(v5e) and not on_tpu(devices)
+    with jax.sharding.use_abstract_mesh(_mesh(v5e, data=4).abstract_mesh):
+        assert on_tpu()
+    with jax.sharding.set_mesh(_mesh(devices, data=8)):
+        assert not on_tpu()
+
+
+@pytest.mark.parametrize("bf16", [False, True], ids=["f32probs", "bf16probs"])
+def test_flash_fwd_bwd_lowers_through_mosaic(v5e, bf16):
+    from determined_tpu.ops.flash_attention import pallas_flash_attention
+
+    mesh = _mesh(v5e[:1], data=1)
+    q = _sds(mesh, (2, 256, 2, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(pallas_flash_attention(q, k, v, True, bf16)
+                       .astype(jnp.float32) ** 2)
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, q).compile().as_text()
+    assert hlo.count("tpu_custom_call") >= 3  # fwd, dq, dk/dv
+
+
+@pytest.mark.parametrize("geometry", [
+    pytest.param((4, 2, 128, 16, 4), id="small-aligned"),
+    pytest.param((8, 12, 64, 16, 64), id="gpt2-small"),
+])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_paged_decode_lowers_through_mosaic(v5e, geometry, dtype):
+    """The kernel Mosaic refused before PR 21 (a 2-D lhs with a batch dim
+    and no non-contracting dim), at the served geometry."""
+    from determined_tpu.ops.paged_attention import paged_attention_pallas
+
+    slots, heads, dh, bs, mb = geometry
+    mesh = _mesh(v5e[:1], data=1)
+    pool = _sds(mesh, (slots * mb + 1, bs, heads, dh), dtype)
+    hlo = jax.jit(paged_attention_pallas).lower(
+        _sds(mesh, (slots, heads, dh), dtype), pool, pool,
+        _sds(mesh, (slots, mb), jnp.int32),
+        _sds(mesh, (slots,), jnp.int32)).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("axes", [
+    dict(data=4), dict(data=1, fsdp=4), dict(data=2, fsdp=2),
+    dict(data=2, tensor=2),
+], ids=["data4", "fsdp4", "data2xfsdp2", "data2xtensor2"])
+def test_flash_on_four_devices_lowers(v5e, axes):
+    """GSPMD cannot partition a Mosaic call ("wrap the call in a
+    shard_map"): the dispatcher places it by the logical rules, with no
+    collective around the kernel."""
+    from determined_tpu.ops.flash_attention import flash_attention
+
+    mesh = _mesh(v5e, **axes)
+    q = _sds(mesh, (8, 256, 4, 64), jnp.bfloat16,
+             ("data", "fsdp"), None, "tensor", None)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, impl="auto")
+                       .astype(jnp.float32) ** 2)
+
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, q, q).compile().as_text()
+    assert hlo.count("tpu_custom_call") >= 3
+    assert "all-gather" not in hlo and "all-to-all" not in hlo
+
+
+def test_kernel_refusals_name_the_reason(v5e):
+    """Where the kernel cannot serve a call, explicit `pallas` raises and
+    says why; it never hands back the reference under the kernel's name."""
+    from determined_tpu.ops.flash_attention import flash_attention
+
+    q = jnp.zeros((4, 256, 4, 64), jnp.bfloat16)
+    seq_sharded = _mesh(v5e, data=2, context=2)
+    with jax.sharding.use_abstract_mesh(seq_sharded.abstract_mesh):
+        with pytest.raises(ValueError, match="context"):
+            jax.eval_shape(functools.partial(
+                flash_attention, impl="pallas"), q, q, q)
+        # auto may choose: the reference path, announced in the log.
+        out = jax.eval_shape(functools.partial(
+            flash_attention, impl="auto"), q, q, q)
+    assert out.shape == q.shape
+
+
+# ------------------------------------------------ where the cache lives
+
+
+@pytest.fixture()
+def cache_config():
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir", "jax_enable_compilation_cache",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_compilation_cache_max_size")}
+    yield
+    for k, v in saved.items():
+        jax.config.update(k, v)
+
+
+def test_cache_dir_placed_from_outside_is_left_alone(
+        monkeypatch, cache_config):
+    """With JAX_COMPILATION_CACHE_DIR set, jax owns the directory: no code
+    path sets one (thresholds may still be tuned)."""
+    from determined_tpu.compile import runtime
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/x")
+    monkeypatch.setenv("DET_XLA_CACHE_DIR", "/agent/xla_cache")
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda k, v: (updates.append(k), real_update(k, v))[1])
+    before = jax.config.jax_compilation_cache_dir
+    runtime.enable_compilation_cache()
+    assert "jax_compilation_cache_dir" not in updates
+    assert jax.config.jax_compilation_cache_dir == before
+    assert "jax_persistent_cache_min_compile_time_secs" in updates
+
+
+def test_cache_dir_defaults_to_the_checkout(
+        monkeypatch, cache_config, checkout_cache_dir, tmp_path):
+    from determined_tpu.compile import runtime
+
+    # (conftest points the session's own default at a temp dir)
+    assert checkout_cache_dir == os.path.join(REPO, ".jax_cache")
+    monkeypatch.setattr(runtime, "DEFAULT_CACHE_DIR", str(tmp_path / "dflt"))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("DET_XLA_CACHE_DIR", raising=False)
+    assert runtime.enable_compilation_cache() == str(tmp_path / "dflt")
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "dflt")
+    # DET_XLA_CACHE_DIR= (empty) keeps meaning "off".
+    monkeypatch.setenv("DET_XLA_CACHE_DIR", "")
+    assert runtime.enable_compilation_cache() == ""
+    assert runtime.compilation_cache_dir() == ""
+
+
+# ------------------------------------------------------ the smoke itself
+
+
+def test_chip_smoke_refuses_without_a_chip():
+    r = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
+                       capture_output=True, text=True, timeout=120,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert r.returncode == 3 and r.stdout == "", (r.returncode, r.stdout)
+
+
+@pytest.mark.slow
+def test_chip_smoke_cpu_tiny_passes_and_a_broken_phase_fails_it():
+    """The sandbox dry run (explicit, platform cpu) keeps the script
+    itself from rotting; `--break` shows a failed phase fails the run."""
+    import json
+
+    cmd = [sys.executable, os.path.join(REPO, "chip_smoke.py"), "--cpu-tiny"]
+    r = subprocess.run(cmd, capture_output=True, text=True, timeout=900)
+    summary, last = map(json.loads, r.stdout.strip().splitlines()[-2:])
+    assert r.returncode == 0 and last["ok"], r.stdout
+    assert summary["phase"] == "summary" and summary["claim"] is None
+    # The result line's contract: exactly these keys, nothing more.
+    assert set(last) == {"ok", "device"}, last
+    assert set(last["device"]) == {"platform", "kind", "count"}, last
+    assert last["device"]["platform"] == "cpu"
+    assert isinstance(last["device"]["count"], int)
+    r = subprocess.run(cmd + ["--break", "serve"], capture_output=True,
+                       text=True, timeout=900)
+    last = json.loads(r.stdout.strip().splitlines()[-1])
+    assert r.returncode == 1 and last["ok"] is False, r.stdout
+    assert set(last) == {"ok", "device"}, last

@@ -37,9 +37,10 @@ class TestFlashAttention:
 class TestPallasFlashAttention:
     """Numerical equivalence of the pallas kernel vs _xla_attention.
 
-    Runs the TPU kernel in interpreter mode on the CPU test mesh; on real
-    TPU hardware the same code path compiles via Mosaic (exercised by
-    bench.py and the dryrun gate).
+    Runs the TPU kernel in interpreter mode on the CPU test mesh (asked
+    for here — nothing in the library interprets on its own); the Mosaic
+    lowering of the same code is tests/test_chip_lowering.py, its
+    numbers on the chip are chip_smoke.py.
     """
 
     def _run(self, fn, *args):
@@ -174,17 +175,51 @@ class TestPallasReferenceEquivalence:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=1e-5, rtol=1e-5)
 
-    def test_explicit_pallas_unsupported_shape_falls_back(self):
-        from determined_tpu.ops.flash_attention import (
-            _xla_attention, flash_attention)
+    def test_multi_device_placement_matches_unsharded(self, devices,
+                                                      monkeypatch):
+        """On a multi-device mesh the dispatcher places the kernel call in
+        a shard_map over the batch (data, fsdp) and heads (tensor) axes:
+        values and gradients must equal the unsharded computation. The
+        per-shard function is stood in by the reference (the interpreter
+        simulates inter-device semaphores and crawls on an 8-device
+        mesh); the kernel itself under that shard_map is lowered by
+        tests/test_chip_lowering.py and run by chip_smoke.py."""
+        import importlib
 
-        # d=8 can't tile on the MXU: explicit pallas must still answer,
-        # via the reference path, with dense arithmetic.
+        # (determined_tpu.ops re-exports the function under this name)
+        fa = importlib.import_module("determined_tpu.ops.flash_attention")
+        monkeypatch.setattr(
+            fa, "pallas_flash_attention",
+            lambda q, k, v, causal, bf16: fa.reference_attention(
+                q, k, v, causal=causal, bf16=bf16))
+        mesh = create_mesh(MeshConfig(data=2, fsdp=2, tensor=2), devices)
+        q, k, v = _qkv(jax.random.PRNGKey(5), b=4, s=128, h=2, d=64)
+
+        def loss(attend):
+            return lambda q, k, v: jnp.sum(attend(q, k, v) ** 2)
+
+        placed = jax.jit(jax.value_and_grad(loss(
+            lambda q, k, v: fa.flash_attention(q, k, v, impl="pallas")),
+            argnums=(0, 1, 2)))
+        with jax.sharding.set_mesh(mesh):
+            assert fa._kernel_placement(q, None) == jax.sharding.PartitionSpec(
+                ("data", "fsdp"), None, ("tensor",), None)
+            out, grads = placed(q, k, v)
+        ref, ref_grads = jax.value_and_grad(
+            loss(fa.reference_attention), argnums=(0, 1, 2))(q, k, v)
+        np.testing.assert_allclose(out, ref, rtol=1e-5)
+        for a, r in zip(grads, ref_grads):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                       atol=1e-5, rtol=1e-5)
+
+    def test_explicit_pallas_unsupported_shape_raises(self):
+        from determined_tpu.ops.flash_attention import flash_attention
+
+        # d=8 can't tile on the MXU: an explicit pallas must say so, not
+        # hand back the reference path under the kernel's name.
         q, k, v = _qkv(jax.random.PRNGKey(12), b=1, s=32, h=2, d=8)
-        out = flash_attention(q, k, v, causal=True, impl="pallas")
-        ref = _xla_attention(q, k, v, causal=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=1e-5)
+        with pytest.raises(ValueError, match="not a multiple of 128"):
+            flash_attention(q, k, v, causal=True, impl="pallas")
 
 
 class TestRingAttention:

@@ -438,9 +438,14 @@ def test_batcher_stop_fails_outstanding(tiny_params):
 
 @pytest.mark.parametrize("impl", ["reference", "pallas", "dense"])
 def test_attention_impl_greedy_equivalence(tiny_params, impl):
+    from jax.experimental.pallas import tpu as pltpu
+
     eng = ServingEngine(tiny_params, TINY, slots=2, max_seq_len=32,
                         prefill_buckets=[8, 16, 32], attention_impl=impl)
-    eng.compile()
+    # The kernel leg compiles on the CPU only because this test asks for
+    # the interpreter; the engine itself never does.
+    with pltpu.force_tpu_interpret_mode():
+        eng.compile()
     prompt = np.array([5, 9, 17, 3], np.int32)
     first = eng.prefill_request(0, prompt)
     out = [first]
@@ -726,6 +731,31 @@ def test_load_checkpoint_params_roundtrip(tmp_path, tiny_params):
     assert len(flat_a) == len(flat_b)
     assert all(np.array_equal(np.asarray(x), np.asarray(y))
                for x, y in zip(flat_a, flat_b))
+
+
+def test_checkpoint_trained_on_a_mesh_serves_on_one_device(
+        tmp_path, tiny_params, devices):
+    """A checkpoint written under a multi-chip (fsdp) layout must load
+    into a one-device replica: restored to the host, placed on the
+    engine's device — not re-created with the trainer's shardings (which
+    a smaller host cannot even load)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.asarray(devices[:4]), ("fsdp",))
+    sharded = jax.tree_util.tree_map(
+        lambda x: jax.device_put(x, NamedSharding(
+            mesh, P("fsdp") if x.ndim and x.shape[0] % 4 == 0 else P())),
+        tiny_params)
+    ctx, sid = _save_checkpoint(tmp_path, sharded, 2)
+    loaded = load_checkpoint_params(ctx.checkpoint, sid)
+    assert all(isinstance(x, np.ndarray)
+               for x in jax.tree_util.tree_leaves(loaded))
+    # ...and whatever layout params arrive in, the engine holds them on
+    # its one device (its executables are compiled for exactly that).
+    eng = ServingEngine(sharded, TINY, slots=2, max_seq_len=32,
+                        prefill_buckets=[8], attention_impl="reference")
+    assert all(x.sharding.device_set == {devices[0]}
+               for x in jax.tree_util.tree_leaves(eng.params))
 
 
 def test_load_checkpoint_latest_resolves_lineage(tmp_path, tiny_params):
