@@ -12,9 +12,9 @@ from drifting apart on the same gauge, in BOTH directions:
     `_total` counters, unit suffixes on measured quantities).
 
 Emission sites are found syntactically: `det_*` tokens inside string
-literals for metrics; `*.span("...")` / `*.emit("...")` / `._span("...")`
-(Python) and `make_span(..., "...")` (C++) call sites for spans. Run by
-`make lint` via `python -m determined_tpu.analysis`.
+literals for metrics; `*.span("...")` / `*.emit("...")` / `._span("...")` /
+`*.phase("...")` (Python) and `make_span(..., "...")` (C++) call sites for
+spans. Run by `make lint` via `python -m determined_tpu.analysis`.
 """
 
 from __future__ import annotations
@@ -46,6 +46,8 @@ SPAN_SOURCES = [
     "determined_tpu/train/trainer.py",
     "determined_tpu/core/_checkpoint.py",
     "determined_tpu/serve/tracing.py",
+    "determined_tpu/serve/scheduler.py",
+    "determined_tpu/serve/engine.py",
 ]
 
 _STRING_RE = re.compile(r'"((?:[^"\\\n]|\\.)*)"')
@@ -53,7 +55,8 @@ _STRING_RE = re.compile(r'"((?:[^"\\\n]|\\.)*)"')
 _METRIC_TOKEN_RE = re.compile(r"(?<![.\w])det(?:_[a-z0-9]+)+\b")
 # Histogram series derive these at exposition time; strip before lookup.
 _HIST_SUFFIX_RE = re.compile(r"_(bucket|sum|count)$")
-_PY_SPAN_RE = re.compile(r'(?:\bspan|\bemit|_span)\(\s*"([a-z0-9_.]+)"')
+_PY_SPAN_RE = re.compile(
+    r'(?:\bspan|\bemit|_span|\bphase)\(\s*"([a-z0-9_.]+)"')
 _CC_SPAN_RE = re.compile(r'make_span\(\s*[^"]*?"([a-z0-9_.]+)"')
 
 

@@ -238,6 +238,55 @@ SPAN_NAMES: Dict[str, Tuple[str, str]] = {
         "master", "One rolling weight swap, update to last stale "
         "replica drained — from/to versions and replicas_swapped in "
         "attrs (docs/serving.md 'Model lifecycle')"),
+    # Step phases (common/trace.py phase(); docs/observability.md "Step
+    # phases"): per-iteration records of the two loops that drive the
+    # chip. They stay in the process's phase ring and in profiler
+    # captures; none is shipped to the master.
+    "serve.loop.admit": (
+        "serve", "Phase: one pass of the batcher's _admit that admitted "
+        ">= 1 request (every live lane stands still); admitted, ids, "
+        "live_lanes on entry and, where that was 0, live_from (when its "
+        "first request went live) in counts, iteration = decode steps so "
+        "far"),
+    "serve.admit.blocks": (
+        "serve", "Phase: KV blocks for one request (BlockManager.admit "
+        "with its prefix hashing, or allocate); request, cached_len"),
+    "serve.admit.cow": (
+        "serve", "Phase: the copy-on-write copy_block calls of one "
+        "admission; pairs"),
+    "serve.admit.prefill": (
+        "serve", "Phase: host side of one prefill up to and with its "
+        "enqueue; bucket, novel tokens, slot, request"),
+    "serve.admit.first_token": (
+        "serve", "Phase: fetch of the prefill's logits (the host waits "
+        "for the device here) and sampling of the first token"),
+    "serve.loop.step": (
+        "serve", "Phase: one decode step of the batcher; lanes, slots "
+        "and gaps_ms (each live lane's wait since its previous token), "
+        "iteration = the step's number"),
+    "serve.step.dispatch": (
+        "serve", "Phase: enqueue of decode + sample inside engine.decode"),
+    "serve.step.fetch": (
+        "serve", "Phase: np.asarray of the sampled tokens (the host waits "
+        "for the device here)"),
+    "serve.step.retire": (
+        "serve", "Phase: per-lane bookkeeping after the fetch, retiring "
+        "included; ids of the requests retired"),
+    "serve.loop.idle": (
+        "serve", "Phase: the batcher waiting for work with no live lane"),
+    "harness.step": (
+        "harness", "Phase: one training step of fit's loop on the host "
+        "(input, dispatch, and the report when one is due); step"),
+    "harness.step.input": (
+        "harness", "Phase: next(data_iter), the wait for the prefetcher"),
+    "harness.step.dispatch": (
+        "harness", "Phase: rng split + enqueue of the jitted train step"),
+    "harness.flush.fetch": (
+        "harness", "Phase: device_get of the newest step's metrics (the "
+        "host waits for the device here)"),
+    "harness.flush.report": (
+        "harness", "Phase: report_training_metrics + tracer.flush (in a "
+        "managed trial an HTTP POST with the chip idle)"),
 }
 
 _METRIC_RE = re.compile(r"^det(_[a-z0-9]+)+$")

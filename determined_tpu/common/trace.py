@@ -8,22 +8,32 @@ trace_id) at trial submit and propagates the trace id to every container as
 nested under an enclosing `span()` context.
 
 Always-on cheap: `span()`/`emit()` append to an in-memory buffer — no I/O,
-no locks on the step critical path (span emission happens at phase
-boundaries, never per step). The buffer is flushed alongside the metrics
+no locks on the step critical path (spans mark phase boundaries and ship).
+The buffer is flushed alongside the metrics
 flush via `flush()`, POSTing one idempotency-keyed batch to
 `POST /api/v1/trials/{id}/spans`. A lost span sink must never hurt the
 trial: flush failures log and drop (the `trace.span.drop` fault point
 proves that path deterministically, docs/chaos.md).
 
-Span names are registered in common/metric_names.py (SPAN_NAMES); the
-metric/span lint keeps emitters and registry in sync.
+Per-step work is timed by `phase()`: the batcher loop and the fit loop open
+one around each part of an iteration. A phase is a
+`jax.profiler.TraceAnnotation` (so it sits in the host plane of any profiler
+session, on the clock of the device's `XLA Ops` line) and one record in a
+bounded process-wide ring on `time.monotonic()`, read back by `phase_log()`.
+Phases never ship: at 10-50 records a second a replica they must not reach
+the master's span table. `Tracer.span()` opens the same annotation.
+
+Span and phase names are registered in common/metric_names.py (SPAN_NAMES);
+the metric/span lint keeps emitters and registry in sync.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import logging
 import os
+import sys
 import threading
 import time
 import uuid
@@ -34,6 +44,144 @@ from determined_tpu.common import faultpoint
 logger = logging.getLogger("determined_tpu.common")
 
 FAULT_SPAN_DROP = "trace.span.drop"
+
+
+# Records the phase ring holds before the oldest is dropped: a 30 s window
+# and its set-up at ~200 records a second (a 20 ms decode step leaves four),
+# with room over.
+PHASE_RING = 32768
+
+
+def _switched_off() -> bool:
+    return os.environ.get("DET_TRACE_OFF", "") in ("1", "true")
+
+
+# Read once: phases sit on the step path, and a look at the environment
+# costs more than the rest of a phase.
+_PHASES_ON = not _switched_off()
+_ring: "collections.deque[Phase]" = collections.deque(maxlen=PHASE_RING)
+_open = threading.local()    # .top: this thread's innermost open phase
+_annotation_cls: Any = None  # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _annotation(name: str):
+    """A profiler annotation, or None in a process that has not loaded jax
+    (a plain-Python trial has no profiler to sit in, and importing jax for
+    the sake of a span would cost it seconds)."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        if "jax" not in sys.modules:
+            return None
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            return None
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls(name)
+
+
+class Phase:
+    """One timed part of a loop iteration; see `phase()`."""
+
+    __slots__ = ("name", "start", "end", "parent", "iteration", "thread",
+                 "counts", "_annotation", "_outer", "_keep")
+    live = True   # False on the stand-in that DET_TRACE_OFF=1 hands out
+
+    def __init__(self, name: str, iteration: Optional[int],
+                 counts: Dict[str, Any]):
+        self.name, self.iteration, self.counts = name, iteration, counts
+        self.start = self.end = 0.0
+        self.parent: Optional[str] = None
+        self._keep = True
+
+    def __enter__(self) -> "Phase":
+        outer = self._outer = getattr(_open, "top", None)
+        if outer is not None:
+            self.parent = outer.name
+            if self.iteration is None:
+                self.iteration = outer.iteration
+        _open.top = self
+        self.thread = threading.get_ident()
+        self._annotation = _annotation(self.name)
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self.start = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.end = time.monotonic()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        _open.top = self._outer
+        if self._keep:
+            _ring.append(self)
+        return False
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
+
+    def set(self, **counts) -> None:
+        """Counts that are known only inside the phase."""
+        self.counts.update(counts)
+
+    def cancel(self) -> None:
+        """Leave no record: the phase turned out to hold no work."""
+        self._keep = False
+
+    def to_dict(self) -> Dict[str, Any]:
+        return {"name": self.name, "start": self.start, "end": self.end,
+                "parent": self.parent, "iteration": self.iteration,
+                "thread": self.thread, "counts": self.counts}
+
+
+class _NoPhase:
+    """What `phase()` hands out under DET_TRACE_OFF=1."""
+
+    live = False
+    seconds = 0.0
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def set(self, **counts) -> None:
+        pass
+
+    def cancel(self) -> None:
+        pass
+
+
+_NO_PHASE = _NoPhase()
+
+
+def phase(name: str, iteration: Optional[int] = None, **counts):
+    """Context manager timing one part of a loop iteration.
+
+    `iteration` is the loop's own number for this turn; a phase opened
+    inside another inherits it. Open phases only on the thread that runs
+    the loop (the batcher thread, the thread inside `fit`): a profiler
+    trace names a device gap by the latest host event over all threads.
+    """
+    if not _PHASES_ON:
+        return _NO_PHASE
+    return Phase(name, iteration, counts)
+
+
+def phase_log(since: Optional[float] = None) -> List[Dict[str, Any]]:
+    """The ring's records, oldest first, as `{name, start, end, parent,
+    iteration, thread, counts}` with times on `time.monotonic()`; with
+    `since`, those that ended at or after it."""
+    records = list(_ring)
+    if since is not None:
+        # appended at exit, so ends rise along the ring
+        first = len(records)
+        while first and records[first - 1].end >= since:
+            first -= 1
+        records = records[first:]
+    return [p.to_dict() for p in records]
 
 
 def now_us() -> int:
@@ -93,7 +241,7 @@ class Tracer:
         self.trace_id = trace_id or os.environ.get("DET_TRACE_ID") or \
             uuid.uuid4().hex[:16]
         if enabled is None:
-            enabled = os.environ.get("DET_TRACE_OFF", "") not in ("1", "true")
+            enabled = not _switched_off()
         self.enabled = enabled
         # The root span lives master-side with span_id == trace_id; local
         # mode has no master, so parentage still resolves to the trace id.
@@ -141,8 +289,10 @@ class Tracer:
         if stack is None:
             stack = self._tls.stack = []
         stack.append(sp.span_id)
+        annotation = _annotation(name) or contextlib.nullcontext()
         try:
-            yield sp
+            with annotation:
+                yield sp
         except BaseException as e:
             sp.attrs["error"] = type(e).__name__
             raise

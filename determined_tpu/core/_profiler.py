@@ -241,7 +241,13 @@ class ProfilerContext:
         started = False
         try:
             os.makedirs(self.tensorboard_dir, exist_ok=True)
-            jax.profiler.start_trace(self.tensorboard_dir)
+            # The Python tracer is off: it slows the very host code whose
+            # gaps a trace is read for, and the loops' phases
+            # (common/trace.py phase()) name that code already.
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(self.tensorboard_dir,
+                                     profiler_options=options)
             started = True
         except Exception:
             # Profiler unavailability must not fail training: log, run

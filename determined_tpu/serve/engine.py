@@ -25,6 +25,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from determined_tpu.common import trace
 from determined_tpu.models.gpt2 import Config
 from determined_tpu.parallel.sharding import LogicalRules
 from determined_tpu.serve import model as smodel
@@ -555,16 +556,29 @@ class ServingEngine:
     def prefill_request(self, slot: int, tokens: np.ndarray,
                         temperature: float = 0.0,
                         block_table: Optional[Sequence[int]] = None,
-                        cached_len: int = 0, adapter: int = 0) -> int:
+                        cached_len: int = 0, adapter: int = 0,
+                        request_id: Optional[str] = None) -> int:
         """Prefill `tokens` into the slot's cache; returns the first
         generated token. Compiled-bucket dispatch by NOVEL length: with
         `cached_len > 0` (prefix-cache hit) only the suffix
         `tokens[cached_len:]` runs through the model — the bucket, and
         therefore the prefill cost, shrinks to the novel part. `adapter`
         selects the slot's table from the adapter stack (0 = base); the
-        slot keeps it for every decode step until release."""
+        slot keeps it for every decode step until release. `request_id`
+        only labels the `serve.admit.prefill` phase."""
         if self._compiled_decode is None:
             self.compile()
+        with trace.phase("serve.admit.prefill", slot=slot,
+                         request=request_id) as ph:
+            logits = self._enqueue_prefill(
+                ph, slot, tokens, block_table, cached_len, adapter)
+        return self._sample_first(logits, temperature)
+
+    def _enqueue_prefill(self, ph, slot: int, tokens: np.ndarray,
+                         block_table: Optional[Sequence[int]],
+                         cached_len: int, adapter: int):
+        """Host work of a prefill up to and with its enqueue → the logits,
+        still on the device."""
         if adapter and not self.has_adapters:
             raise ValueError("engine has no adapters resident")
         self.set_slot_adapter(slot, adapter)
@@ -584,9 +598,10 @@ class ServingEngine:
                     np.int32(length), np.int32(slot)]
             if self.has_adapters:
                 args += [self._adapter_stack, np.int32(adapter)]
+            ph.set(bucket=bucket, novel=length)
             self._cache, logits = self._compiled_prefill[bucket](*args)
             self.prefills += 1
-            return self._sample_first(logits, temperature)
+            return logits
         if not 0 <= cached_len < length:
             raise ValueError(
                 f"cached_len {cached_len} must leave >= 1 novel token "
@@ -611,20 +626,23 @@ class ServingEngine:
                 np.int32(s_len), np.int32(cached_len), table]
         if self.has_adapters:
             args += [self._adapter_stack, np.int32(adapter)]
+        ph.set(bucket=bucket, novel=s_len)
         self._cache, logits = self._compiled_prefill[bucket](*args)
         self._tables[slot] = table
         self.prefills += 1
-        return self._sample_first(logits, temperature)
+        return logits
 
     def _sample_first(self, logits, temperature: float) -> int:
         """Sample via the slot-wide compiled sampler (slot 0 carries the
-        logits; the rest are padding lanes)."""
-        batch = np.zeros((self.slots, self.cfg.vocab_size), np.float32)
-        batch[0] = np.asarray(logits, np.float32)
-        temps = np.zeros((self.slots,), np.float32)
-        temps[0] = temperature
-        toks = self._compiled_sample(batch, temps, self._next_rng())
-        return int(np.asarray(toks)[0])
+        logits; the rest are padding lanes). Fetching the logits is where
+        the host waits for the prefill."""
+        with trace.phase("serve.admit.first_token"):
+            batch = np.zeros((self.slots, self.cfg.vocab_size), np.float32)
+            batch[0] = np.asarray(logits, np.float32)
+            temps = np.zeros((self.slots,), np.float32)
+            temps[0] = temperature
+            toks = self._compiled_sample(batch, temps, self._next_rng())
+            return int(np.asarray(toks)[0])
 
     def release_slot(self, slot: int) -> None:
         """Point a retired slot's table at the trash block so later
@@ -643,17 +661,20 @@ class ServingEngine:
         boundaries in the batcher thread)."""
         if self._compiled_decode is None:
             self.compile()
-        args = [self.params, self._cache, np.asarray(tokens, np.int32),
-                np.asarray(positions, np.int32)]
-        if self.paged:
-            args.append(self._tables)
-        if self.has_adapters:
-            args += [self._adapter_stack, self._slot_adapters.copy()]
-        self._cache, logits = self._compiled_decode(*args)
-        toks = self._compiled_sample(
-            logits, np.asarray(temperatures, np.float32), self._next_rng())
-        self.decode_steps += 1
-        return np.asarray(toks)
+        with trace.phase("serve.step.dispatch"):
+            args = [self.params, self._cache, np.asarray(tokens, np.int32),
+                    np.asarray(positions, np.int32)]
+            if self.paged:
+                args.append(self._tables)
+            if self.has_adapters:
+                args += [self._adapter_stack, self._slot_adapters.copy()]
+            self._cache, logits = self._compiled_decode(*args)
+            toks = self._compiled_sample(
+                logits, np.asarray(temperatures, np.float32),
+                self._next_rng())
+            self.decode_steps += 1
+        with trace.phase("serve.step.fetch"):
+            return np.asarray(toks)
 
     def stats(self) -> Dict[str, Any]:
         return {

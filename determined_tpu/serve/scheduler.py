@@ -29,8 +29,12 @@ records wall-clock phase timestamps (submitted → admitted → prefill →
 first token → finished) so retire can fold it into the token-latency
 histograms — TTFT, TPOT (inter-token), e2e, queue wait — and hand it to
 an attached RequestTracer (serve/tracing.py) for the per-request span
-tree. Both are retire-time work: the decode loop itself never touches a
-clock beyond the per-step timestamps it already takes.
+tree. Both are retire-time work. The loop's own time is kept by
+`trace.phase` (common/trace.py; docs/observability.md "Step phases"): one
+pass of `_admit`, one `_step` and one idle wait are phases with their parts
+inside them, in the process's phase ring and in any profiler capture. A
+step reads the clock once more, after the tokens arrive, for every live
+lane's gap since its previous token.
 """
 
 from __future__ import annotations
@@ -40,11 +44,11 @@ import itertools
 import logging
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from determined_tpu.common import faultpoint
+from determined_tpu.common import faultpoint, trace
 from determined_tpu.serve.kv_cache import BlockManager
 
 logger = logging.getLogger("determined_tpu.serve")
@@ -182,6 +186,14 @@ class Request:
         self.blocks_allocated = 0     # KV blocks charged at admission
         self.occupancy_at_admit = 0   # active slots when this one joined
         self.decode_steps = 0         # decode steps this request rode
+        # What a streaming client felt (monotonic; filled in by the batcher
+        # while phases are on): when the first token was sampled, the
+        # longest wait between two tokens, and how much of the request's
+        # decoding its lane stood still inside `serve.loop.admit` passes
+        # that prefilled other requests.
+        self.first_token_at: Optional[float] = None
+        self.itl_max_ms: Optional[float] = None
+        self.stalled_ms: Optional[float] = None
 
     @property
     def total_budget(self) -> int:
@@ -189,9 +201,9 @@ class Request:
         return int(self.tokens.size) + self.max_new_tokens
 
     def _finish(self, error: Optional[str] = None,
-                notify: bool = True) -> None:
+                notify: bool = True, at: Optional[float] = None) -> None:
         self.error = error
-        self.finished_at = time.monotonic()
+        self.finished_at = at if at is not None else time.monotonic()
         self.finished_us = now_us()
         # notify=False lets the batcher observe latency + spans BEFORE
         # waiters wake: by the time the HTTP response leaves, the
@@ -226,6 +238,9 @@ class Request:
                 out["tpot_ms"] = round(
                     (self.finished_us - self.first_token_us) / 1e3
                     / (len(self.out_tokens) - 1), 3)
+        if self.itl_max_ms is not None:
+            out["itl_max_ms"] = round(self.itl_max_ms, 3)
+            out["stalled_ms"] = round(self.stalled_ms, 3)
         return out
 
 
@@ -300,20 +315,28 @@ class AdmissionQueue:
 
 
 class _Slot:
-    __slots__ = ("req", "position", "last_token")
+    __slots__ = ("req", "position", "last_token", "last_token_at",
+                 "itl_max", "stall_mark")
 
-    def __init__(self, req: Request, position: int, last_token: int):
+    def __init__(self, req: Request, position: int, last_token: int,
+                 stall_mark: float):
         self.req = req
         self.position = position  # index the NEXT decode step writes at
         self.last_token = last_token
+        self.last_token_at = req.first_token_at
+        self.itl_max = 0.0
+        # `ContinuousBatcher._stall_s` less what this request should not be
+        # charged of the admit pass that is prefilling it: see _retire.
+        self.stall_mark = stall_mark
 
 
 class ContinuousBatcher:
     """The decode loop: admit → step → retire, forever.
 
     Owns the engine's host-side slot state and the KV block accounting.
-    `events` records (kind, request_id, step) tuples — ("admit"/"retire"
-    at the boundary they happened) — so tests can assert the
+    The phase records of `serve.loop.admit` and `serve.step.retire` carry
+    the ids of the requests that joined or left and, as their iteration,
+    the decode-step count at that boundary — so tests can assert the
     join-at-boundary / retire-without-drain ordering directly.
     """
 
@@ -350,8 +373,7 @@ class ContinuousBatcher:
         self._stop_evt = threading.Event()
         self._drained_evt = threading.Event()
         self._thread: Optional[threading.Thread] = None
-        self._lock = threading.Lock()  # events/counters only
-        self.events: List[Tuple[str, str, int]] = []
+        self._lock = threading.Lock()  # counters only
         self.steps = 0
         self.active_steps = 0      # steps with >= 1 active slot
         self.occupancy_sum = 0     # sum of active slots over active steps
@@ -376,6 +398,12 @@ class ContinuousBatcher:
         # Optional per-request span tracer (serve/tracing.py), attached by
         # the task entrypoint / tests; None = no request tracing.
         self.tracer = None
+        # For stats()'s `loop` entry and Request.stalled_ms (batcher thread
+        # writes): seconds inside admit passes so far, when the loop
+        # started, since when it has been waiting for work.
+        self._stall_s = 0.0
+        self._started_at = time.monotonic()
+        self._idle_since: Optional[float] = None
 
     # -- lifecycle -----------------------------------------------------
 
@@ -463,7 +491,7 @@ class ContinuousBatcher:
                         if self._stop_evt.wait(self._idle_wait):
                             return
                         continue
-                    self.queue.wait_nonempty(self._idle_wait)
+                    self._idle()
                     continue
                 self._drained_evt.clear()
                 self._step(active)
@@ -483,34 +511,66 @@ class ContinuousBatcher:
                 self.failed += 1
             self._drained_evt.set()
 
+    def _idle(self) -> None:
+        """No live lane: one phase for the whole wait, however many times
+        the queue's wait times out inside it."""
+        with trace.phase("serve.loop.idle", iteration=self.steps) as idle:
+            self._idle_since = idle.start if idle.live else None
+            while not self.queue.wait_nonempty(self._idle_wait):
+                if self._stop_evt.is_set() or self.queue.draining:
+                    break
+            self._idle_since = None
+
     def _admit(self) -> None:
-        """Join queued requests at this step boundary while a free slot
-        AND enough KV blocks exist (block exhaustion keeps the request
-        queued — backpressure, not failure).
+        """One pass over the queue at this step boundary; a pass with a
+        request to look at and a slot to give it is a `serve.loop.admit`
+        phase, kept when it admitted at least one."""
+        live = self.active_count()
+        if live == len(self._slots) or self.queue.peek() is None:
+            return
+        with trace.phase("serve.loop.admit", iteration=self.steps,
+                         live_lanes=live) as admit:
+            ids = self._admit_pass(admit, live)
+            if not ids:
+                admit.cancel()
+            admit.set(admitted=len(ids), ids=ids)
+        if ids:
+            self._stall_s += admit.seconds
+
+    def _admit_pass(self, admit, live: int) -> List[str]:
+        """Join queued requests while a free slot AND enough KV blocks
+        exist (block exhaustion keeps the request queued — backpressure,
+        not failure); returns the ids of those that got a first token.
+        A pass that found no live lane notes on `admit` from when it had
+        one (`live_from`): from there on its prefills stall somebody.
 
         Paged engines admit through BlockManager.admit: a prompt whose
         prefix is cached reuses those blocks (refcounted) and is charged
         only its novel suffix — prefill then runs only that suffix."""
         paged = getattr(self.engine, "paged", False)
+        ids: List[str] = []
         while True:
             free = [i for i, s in enumerate(self._slots) if s is None]
             if not free:
-                return
+                return ids
             req = self.queue.peek()
             if req is None:
-                return
+                return ids
             cached_len = 0
             cow_pairs = ()
-            if paged:
-                admitted = self.blocks.admit(
-                    req.id, req.tokens.tolist(), req.total_budget)
+            with trace.phase("serve.admit.blocks", request=req.id) as grant:
+                if paged:
+                    admitted = self.blocks.admit(
+                        req.id, req.tokens.tolist(), req.total_budget)
+                    if admitted is not None:
+                        table, cached_len, cow_pairs = admitted
+                        grant.set(cached_len=cached_len)
+                else:
+                    table = admitted = self.blocks.allocate(
+                        req.id, req.total_budget)
                 if admitted is None:
-                    return  # pool exhausted: wait for a retire
-                table, cached_len, cow_pairs = admitted
-            else:
-                table = self.blocks.allocate(req.id, req.total_budget)
-                if table is None:
-                    return  # pool exhausted: wait for a retire
+                    grant.cancel()
+                    return ids  # pool exhausted: wait for a retire
             popped = self.queue.pop()
             assert popped is req, "single-consumer queue invariant"
             slot_id = free[0]
@@ -532,17 +592,20 @@ class ContinuousBatcher:
                 adapter = self.engine.adapter_index(req.model)
                 # Device-side copy-on-write BEFORE any write can land in
                 # a block other sequences still reference.
-                for src, dst in cow_pairs:
-                    self.engine.copy_block(src, dst)
+                if cow_pairs:
+                    with trace.phase("serve.admit.cow",
+                                     pairs=len(cow_pairs)):
+                        for src, dst in cow_pairs:
+                            self.engine.copy_block(src, dst)
                 if paged:
                     first = self.engine.prefill_request(
                         slot_id, req.tokens, req.temperature,
                         block_table=table, cached_len=cached_len,
-                        adapter=adapter)
+                        adapter=adapter, request_id=req.id)
                 else:
                     first = self.engine.prefill_request(
                         slot_id, req.tokens, req.temperature,
-                        adapter=adapter)
+                        adapter=adapter, request_id=req.id)
             except Exception as e:
                 # discard=True: the blocks' K/V were never (fully)
                 # written; they must not linger in the prefix cache.
@@ -554,9 +617,10 @@ class ContinuousBatcher:
                 req._done.set()
                 continue
             req.prefill_end_us = req.first_token_us = now_us()
+            req.first_token_at = time.monotonic()
             req.out_tokens.append(first)
+            ids.append(req.id)
             with self._lock:
-                self.events.append(("admit", req.id, self.steps))
                 name = req.model or "base"
                 self.adapter_requests[name] = \
                     self.adapter_requests.get(name, 0) + 1
@@ -564,35 +628,65 @@ class ContinuousBatcher:
             if self._finished(req, first):
                 self._retire(slot_id, req, admitted_only=True)
                 continue
+            if not live:
+                live = 1
+                admit.set(live_from=req.first_token_at)
+            # Of this pass, the request is stalled only by what follows
+            # its own first token.
             self._slots[slot_id] = _Slot(
-                req, position=int(req.tokens.size), last_token=first)
+                req, position=int(req.tokens.size), last_token=first,
+                stall_mark=self._stall_s
+                + (req.first_token_at - admit.start if admit.live else 0.0))
 
     def _step(self, active: List[int]) -> None:
-        slots = self.engine.slots
-        tokens = np.zeros((slots,), np.int32)
-        positions = np.zeros((slots,), np.int32)
-        temps = np.zeros((slots,), np.float32)
+        with trace.phase("serve.loop.step", iteration=self.steps + 1,
+                         lanes=len(active)) as step:
+            slots = self.engine.slots
+            tokens = np.zeros((slots,), np.int32)
+            positions = np.zeros((slots,), np.int32)
+            temps = np.zeros((slots,), np.float32)
+            for i in active:
+                s = self._slots[i]
+                tokens[i] = s.last_token
+                positions[i] = s.position
+                temps[i] = s.req.temperature
+            next_tokens = self.engine.decode(tokens, positions, temps)
+            now = time.monotonic()   # the step's tokens are on the host
+            if step.live:
+                step.set(slots=active,
+                         gaps_ms=self._token_gaps(active, now))
+            with self._lock:
+                self.steps += 1
+                self.active_steps += 1
+                self.occupancy_sum += len(active)
+                self.max_occupancy = max(self.max_occupancy, len(active))
+            with trace.phase("serve.step.retire") as retire:
+                retired = []
+                for i in active:
+                    s = self._slots[i]
+                    tok = int(next_tokens[i])
+                    s.req.out_tokens.append(tok)
+                    s.req.decode_steps += 1
+                    self.generated_tokens += 1
+                    s.position += 1
+                    s.last_token = tok
+                    if self._finished(s.req, tok):
+                        retired.append(s.req.id)
+                        self._retire(i, s.req, at=now)
+                retire.set(ids=retired)
+
+    def _token_gaps(self, active: List[int], now: float) -> List[float]:
+        """Every live lane's wait, in ms, from its previous token to the
+        tokens that arrived at `now`."""
+        gaps = []
         for i in active:
             s = self._slots[i]
-            tokens[i] = s.last_token
-            positions[i] = s.position
-            temps[i] = s.req.temperature
-        next_tokens = self.engine.decode(tokens, positions, temps)
-        with self._lock:
-            self.steps += 1
-            self.active_steps += 1
-            self.occupancy_sum += len(active)
-            self.max_occupancy = max(self.max_occupancy, len(active))
-        for i in active:
-            s = self._slots[i]
-            tok = int(next_tokens[i])
-            s.req.out_tokens.append(tok)
-            s.req.decode_steps += 1
-            self.generated_tokens += 1
-            s.position += 1
-            s.last_token = tok
-            if self._finished(s.req, tok):
-                self._retire(i, s.req)
+            gap = (now - s.last_token_at) * 1e3
+            s.last_token_at = now
+            if gap > s.itl_max:
+                s.itl_max = gap
+            gaps.append(gap)
+        return gaps
 
     @staticmethod
     def _finished(req: Request, token: int) -> bool:
@@ -600,10 +694,16 @@ class ContinuousBatcher:
                 or (req.eos_id is not None and token == req.eos_id))
 
     def _retire(self, slot_id: int, req: Request,
-                admitted_only: bool = False) -> None:
+                admitted_only: bool = False,
+                at: Optional[float] = None) -> None:
         """Free the slot + KV blocks and complete the request — the rest
-        of the batch keeps decoding (no drain)."""
+        of the batch keeps decoding (no drain). `at`: when its last token
+        reached the host."""
         if not admitted_only:
+            slot = self._slots[slot_id]
+            if slot.itl_max:   # gaps are taken only while phases are on
+                req.itl_max_ms = slot.itl_max
+                req.stalled_ms = (self._stall_s - slot.stall_mark) * 1e3
             self._slots[slot_id] = None
         # Paged: the retired slot keeps riding the decode batch as an
         # inactive lane (position 0); its table must point at the trash
@@ -613,9 +713,8 @@ class ContinuousBatcher:
         if release is not None:
             release(slot_id)
         self.blocks.free(req.id)
-        req._finish(notify=False)
+        req._finish(notify=False, at=at)
         with self._lock:
-            self.events.append(("retire", req.id, self.steps))
             self.completed += 1
             if req.admitted_at is not None:
                 service_s = max(0.0, req.finished_at - req.admitted_at)
@@ -666,7 +765,42 @@ class ContinuousBatcher:
         est = depth * service / max(1, self.engine.slots)
         return max(1, min(60, int(est + 0.999)))
 
+    def _loop_stats(self) -> Dict[str, Any]:
+        """Where the batcher thread's last minute went, from its phase
+        records: the shares inside admit passes (every live lane stands
+        still there), decode steps and waiting for work, and the host's
+        part of a step (the step less its wait for the tokens). Empty
+        while there is nothing to read, as under DET_TRACE_OFF=1."""
+        thread = self._thread
+        if thread is None:
+            return {}
+        now = time.monotonic()
+        lo = max(now - 60.0, self._started_at)
+        spent = {"serve.loop.admit": 0.0, "serve.loop.step": 0.0,
+                 "serve.loop.idle": 0.0, "serve.step.fetch": 0.0}
+        steps = 0
+        for rec in trace.phase_log(since=lo):
+            if rec["thread"] == thread.ident and rec["name"] in spent:
+                spent[rec["name"]] += rec["end"] - max(rec["start"], lo)
+                steps += rec["name"] == "serve.loop.step"
+        idle_since = self._idle_since
+        if idle_since is not None:
+            spent["serve.loop.idle"] += now - max(idle_since, lo)
+        if not any(spent.values()):
+            return {}
+        window = max(now - lo, 1e-9)
+        out = {"window_s": round(now - lo, 3), "steps": steps,
+               "admit_share": round(spent["serve.loop.admit"] / window, 4),
+               "step_share": round(spent["serve.loop.step"] / window, 4),
+               "idle_share": round(spent["serve.loop.idle"] / window, 4)}
+        if steps:
+            out["host_ms_per_step"] = round(
+                (spent["serve.loop.step"] - spent["serve.step.fetch"])
+                / steps * 1e3, 3)
+        return out
+
     def stats(self) -> Dict[str, Any]:
+        loop = self._loop_stats()
         with self._lock:
             occ = (self.occupancy_sum / self.active_steps
                    if self.active_steps else 0.0)
@@ -693,6 +827,7 @@ class ContinuousBatcher:
                     "e2e": self.e2e_hist.summary(),
                     "queue_wait": self.queue_wait_hist.summary(),
                 },
+                "loop": loop,
             }
 
     def heartbeat_stats(self) -> Dict[str, Any]:

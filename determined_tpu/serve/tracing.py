@@ -9,7 +9,9 @@ trial.lifecycle convention):
   serve.request                      submit → finish (root, replica-side)
   ├── serve.queue_wait               submit → admission
   ├── serve.prefill                  bucket/suffix/prefix-hit/blocks attrs
-  └── serve.decode                   tokens/steps/occupancy attrs
+  └── serve.decode                   tokens/steps/occupancy attrs, and
+                                     itl_max_ms/stalled_ms from the
+                                     batcher's step phases
 
 The master-side `serve.router.dispatch` span (replica chosen, retries,
 breaker state) is recorded directly by the router into the same trace —
@@ -156,6 +158,11 @@ class RequestTracer:
                     "tokens": len(req.out_tokens),
                     "steps": req.decode_steps,
                     "occupancy_at_admit": req.occupancy_at_admit,
+                    # why a gap was long: the longest, and the part of
+                    # the decoding spent behind other requests' prefills
+                    **({"itl_max_ms": round(req.itl_max_ms, 3),
+                        "stalled_ms": round(req.stalled_ms, 3)}
+                       if req.itl_max_ms is not None else {}),
                 }).to_dict())
         return out
 

@@ -392,6 +392,10 @@ class Trainer:
             )
             core.profiler.on()
 
+        # For `host_ms` (_flush_metrics): seconds and count of the
+        # harness.step phases that have ended since the last report, and
+        # the running step's wait in harness.flush.fetch.
+        self._host_s, self._host_steps, self._fetch_s = 0.0, 0, 0.0
         self._pf_cfg = self._prefetch_config(core)
         health = self._health_cfg = self._health_config(core)
         self._preempt_cfg = self._preemption_config(core)
@@ -500,19 +504,36 @@ class Trainer:
                 for op in core.searcher.operations():
                     while True:
                         while step < op.length and not preempted:
-                            # Chaos (docs/chaos.md): a delay-mode arm here
-                            # models a wedged host/collective — exactly what
-                            # the watchdog exists to catch.
-                            faultpoint.fire("step.hang")
-                            batch = next(data_iter)
-                            rng, step_rng = jax.random.split(rng)
-                            self.state, metrics = self._train_step(self.state, batch, step_rng)
-                            step += 1
-                            n_report += 1
-                            last = (step, metrics)
+                            # The step's own work is one phase with its
+                            # parts inside (docs/observability.md "Step
+                            # phases"); validation, checkpoints and the
+                            # preemption poll below have their spans.
+                            with trace_mod.phase(
+                                    "harness.step", iteration=step + 1,
+                                    step=step + 1) as ph:
+                                # Chaos (docs/chaos.md): a delay-mode arm
+                                # here models a wedged host/collective —
+                                # exactly what the watchdog exists to catch.
+                                faultpoint.fire("step.hang")
+                                with trace_mod.phase("harness.step.input"):
+                                    batch = next(data_iter)
+                                with trace_mod.phase("harness.step.dispatch"):
+                                    rng, step_rng = jax.random.split(rng)
+                                    self.state, metrics = self._train_step(
+                                        self.state, batch, step_rng)
+                                step += 1
+                                n_report += 1
+                                last = (step, metrics)
+                                reported = bool(report_period) \
+                                    and step % report_period == 0
+                                if reported:
+                                    host = flush()
+                            if ph.live:
+                                self._host_s += ph.seconds - self._fetch_s
+                                self._host_steps += 1
+                            self._fetch_s = 0.0
 
-                            if report_period and step % report_period == 0:
-                                host = flush()
+                            if reported:
                                 core.profiler.set_step(step)
                                 if diverged(host) and handle_divergence():
                                     continue  # rolled back: step rewound
@@ -610,8 +631,18 @@ class Trainer:
         last_step, last_metrics = last
         # One device_get for the whole metrics tree: per-key fetches would
         # pay the host round-trip once per metric instead of once per flush.
-        host = {k: np.asarray(v)
-                for k, v in jax.device_get(last_metrics).items()}
+        with trace_mod.phase("harness.flush.fetch") as fetch:
+            host = {k: np.asarray(v)
+                    for k, v in jax.device_get(last_metrics).items()}
+        self._fetch_s += fetch.seconds
+        if self._host_steps:
+            # The loop's serial time on the host, over the steps that have
+            # ended since the last report: a step less its wait for the
+            # device in the fetch above. Where the reports are far apart
+            # and the dispatch blocks on a full device queue, an upper
+            # bound.
+            host["host_ms"] = self._host_s / self._host_steps * 1e3
+            self._host_s, self._host_steps = 0.0, 0
         dt = time.time() - t_start
         if n_steps and dt > 0:
             host["steps_per_second"] = n_steps / dt
@@ -640,10 +671,12 @@ class Trainer:
         # exactly where the loss went bad (train/health.py).
         if float(host.get("all_finite", 1.0)) < 1.0:
             host["divergence"] = 1.0
-        core.train.report_training_metrics(last_step, host)
-        # Span batches ride the metric-flush cadence (buffer appends are
-        # the only tracing cost on the step path; the POST happens here).
-        core.tracer.flush()
+        with trace_mod.phase("harness.flush.report"):
+            core.train.report_training_metrics(last_step, host)
+            # Span batches ride the metric-flush cadence (buffer appends
+            # are the only tracing cost on the step path; the POST happens
+            # here).
+            core.tracer.flush()
         return host
 
     def _validate(self, core, step: int) -> Dict[str, Any]:

@@ -385,7 +385,7 @@ class TestProfilerHardening:
         calls = {"start": 0, "stop": 0}
         monkeypatch.setattr(
             jax.profiler, "start_trace",
-            lambda d: calls.__setitem__("start", calls["start"] + 1))
+            lambda d, **kw: calls.__setitem__("start", calls["start"] + 1))
         monkeypatch.setattr(
             jax.profiler, "stop_trace",
             lambda: calls.__setitem__("stop", calls["stop"] + 1))
@@ -403,7 +403,7 @@ class TestProfilerHardening:
     def test_trace_start_failure_logs_not_raises(self, monkeypatch):
         import jax
 
-        def boom(d):
+        def boom(d, **kw):
             raise RuntimeError("profiler unavailable")
 
         stopped = []
@@ -421,7 +421,8 @@ class TestProfilerHardening:
     def test_trace_stop_failure_clears_active_flag(self, monkeypatch):
         import jax
 
-        monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+        monkeypatch.setattr(jax.profiler, "start_trace",
+                            lambda d, **kw: None)
 
         def boom():
             raise RuntimeError("wedged")

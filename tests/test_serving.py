@@ -337,6 +337,8 @@ def test_join_at_boundary_retire_without_drain(tiny_params):
     """With 2 slots and 3 requests, the 3rd joins at the step boundary
     where the 1st retires, while the 2nd keeps decoding — the batch
     NEVER drains to refill."""
+    from determined_tpu.common import trace
+
     eng = make_engine(tiny_params, slots=2)
     b = make_batcher(eng).start()
     try:
@@ -345,14 +347,19 @@ def test_join_at_boundary_retire_without_drain(tiny_params):
         r3 = b.submit(_req(n_prompt=3, max_new=2))
         for r in (r1, r2, r3):
             r.result(timeout=60)
-        ev = {(kind, rid): step for kind, rid, step in b.events}
-        # r1 and r2 joined before r3 (only 2 slots).
-        assert ev[("admit", r3.id)] >= ev[("retire", r1.id)]
-        # retire-without-drain: r2 was still mid-decode when r3 joined —
-        # its retirement happened strictly after r3's admission.
-        assert ev[("retire", r2.id)] > ev[("admit", r3.id)]
     finally:
-        b.stop()
+        b.stop()   # joins the batcher thread: its last phase has closed
+    # The step count at which each request joined and left, from the
+    # batcher's phase records (their iteration is that count).
+    kinds = {"serve.loop.admit": "admit", "serve.step.retire": "retire"}
+    ev = {(kinds[rec["name"]], rid): rec["iteration"]
+          for rec in trace.phase_log(since=r1.submitted_at)
+          if rec["name"] in kinds for rid in rec["counts"]["ids"]}
+    # r1 and r2 joined before r3 (only 2 slots).
+    assert ev[("admit", r3.id)] >= ev[("retire", r1.id)]
+    # retire-without-drain: r2 was still mid-decode when r3 joined —
+    # its retirement happened strictly after r3's admission.
+    assert ev[("retire", r2.id)] > ev[("admit", r3.id)]
 
 
 def test_kv_blocks_gate_admission(tiny_params):
