@@ -171,18 +171,19 @@ def phase_kernels(args, out):
               f"bwd {bwd:.4f} (tol {BWD_TOL})")
 
     # paged decode: the serve phase's geometry, ragged positions, one
-    # inactive (all-trash) slot.
+    # inactive (all-trash) slot, the second of three layers of a pool.
     slots, bs, mb = 8, 16, 1024 // 16
     rng = np.random.default_rng(1)
-    pool = slots * mb + 1
+    pool = (3, slots * mb + 1, bs, h * d)
     q = jnp.asarray(rng.normal(size=(slots, h, d)), jnp.bfloat16)
-    kp = jnp.asarray(rng.normal(size=(pool, bs, h, d)), jnp.bfloat16)
-    vp = jnp.asarray(rng.normal(size=(pool, bs, h, d)), jnp.bfloat16)
+    kp = jnp.asarray(rng.normal(size=pool), jnp.bfloat16)
+    vp = jnp.asarray(rng.normal(size=pool), jnp.bfloat16)
     tbl = rng.permutation(slots * mb).reshape(slots, mb).astype(np.int32)
     tbl[-1] = slots * mb
     pos = np.array([0, 5, 15, 16, 300, 777, 1023, 0], np.int32)
-    got = jax.jit(paged_attention_pallas)(q, kp, vp, tbl, pos)
-    want = jax.jit(paged_attention_reference)(q, kp, vp, tbl, pos)
+    layer = jnp.int32(1)
+    got = jax.jit(paged_attention_pallas)(q, kp, vp, layer, tbl, pos)
+    want = jax.jit(paged_attention_reference)(q, kp, vp, layer, tbl, pos)
     err = rel_err(got[:-1], want[:-1])
     out["paged_decode"] = {"rel_err": round(err, 5)}
     check(err <= FWD_TOL,

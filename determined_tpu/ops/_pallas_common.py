@@ -68,8 +68,8 @@ def softmax_scratch(rows: Union[int, Tuple[int, ...]], d: int):
     """VMEM scratch for one online-softmax accumulator: [acc, m, l].
 
     `rows` is the per-program row shape (query rows for the training
-    kernel; `(heads, 1)` for the decode kernel, whose matmuls are batched
-    over heads); `d` the output feature depth.
+    kernel; `(groups, heads per group)` for the decode kernel, whose
+    matmuls are batched over lane groups); `d` the output feature depth.
     All three are fp32 regardless of the i/o dtype — the running
     statistics are the one place bf16 is never acceptable (exp/sum
     cancellation), which is also why they live in dedicated scratch
@@ -121,5 +121,5 @@ def finish_softmax_scratch(o_ref, acc_ref, l_ref, idx=...) -> None:
     """Normalize the accumulator out to the output block's dtype.
 
     `idx` addresses the output block when it carries a leading unit dim
-    (the decode kernel's (1, H, 1, Dh) slot block passes idx=0)."""
+    (the decode kernel's (1, G, per, W) slot block passes idx=0)."""
     o_ref[idx] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)

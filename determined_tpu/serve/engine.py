@@ -111,17 +111,33 @@ def _restore_raw(checkpoint_ctx, storage_id: str) -> Any:
     return ckptr.restore(state_dir, restore_args=as_numpy)
 
 
-def resolve_attention_impl(impl: str) -> str:
+def resolve_attention_impl(impl: str, cfg: Config) -> str:
     """serving.attention_impl → the engine's concrete path.
 
     "auto" picks the Pallas kernel on TPU and the jnp gather reference
     elsewhere (both paged); "pallas"/"reference"/"dense" force a path —
     a forced "pallas" off-TPU compiles only under a test's
-    `pltpu.force_tpu_interpret_mode()`."""
+    `pltpu.force_tpu_interpret_mode()`. A head geometry the kernel cannot
+    take (ops/paged_attention.kernel_refusal) sends "auto" to the
+    reference, said in the log, and makes an explicit "pallas" raise."""
+    from determined_tpu.ops.paged_attention import kernel_refusal
     from determined_tpu.parallel.mesh import on_tpu
 
+    why_not = kernel_refusal(cfg.n_head, cfg.head_dim)
     if impl == "auto":
-        return "pallas" if on_tpu() else "reference"
+        if not on_tpu():
+            return "reference"
+        if why_not:
+            logger.warning(
+                "serving.attention_impl auto: reference path on a TPU (%s)",
+                why_not)
+            return "reference"
+        return "pallas"
+    if impl == "pallas" and why_not:
+        raise ValueError(
+            f"serving.attention_impl: pallas cannot serve this model: "
+            f"{why_not}; use auto (which takes the reference path for "
+            "it) or reference")
     if impl in ("pallas", "reference", "dense"):
         return impl
     raise ValueError(
@@ -205,7 +221,7 @@ class ServingEngine:
                     jnp.asarray(wte, base_wte.dtype), device))
             self._adapter_stack = jnp.stack(tables)
             self._slot_adapters = np.zeros((slots,), np.int32)
-        self.attention_impl = resolve_attention_impl(attention_impl)
+        self.attention_impl = resolve_attention_impl(attention_impl, cfg)
         self.paged = self.attention_impl != "dense"
         self.block_size = int(kv_block_size)
         self.num_blocks = int(kv_num_blocks) if kv_num_blocks else 0

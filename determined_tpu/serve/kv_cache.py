@@ -1,7 +1,7 @@
 """KV-cache block manager — paged admission control + prefix caching.
 
 The device-side KV cache is a paged block pool (`serve/model.py`
-`init_paged_cache`: `[L, num_blocks + 1, block_size, H, Dh]`, the last
+`init_paged_cache`: `[L, num_blocks + 1, block_size, H*Dh]`, the last
 block being the trash block the manager never hands out). This manager
 owns the pool's HOST-side truth, vLLM style:
 

@@ -13,17 +13,20 @@ standard way:
     composition; the continuous batcher joins/retires sequences purely by
     editing host-side slot state.
 
-Two cache layouts share this module (both stacked over layers exactly
-like the training params, so every path `lax.scan`s the same block
-structure):
+Two cache layouts share this module (every path `lax.scan`s the layers'
+stacked params):
 
   - **paged** (the default; `init_paged_cache`/`paged_prefill`/
     `paged_decode_step`): a block pool `[L, num_blocks + 1, block_size,
-    H, Dh]` addressed through per-sequence block tables — admission
+    H*Dh]` addressed through per-sequence block tables — admission
     bounds real HBM and prompt prefixes can be shared (docs/serving.md
-    "Paged KV & prefix caching");
+    "Paged KV & prefix caching"). The pool is the scan's CARRY, one
+    buffer for the whole call, written in place by token-sized scatters
+    and read through the tables; it is never a per-layer input or a
+    stacked output of the scan, which made the compiler keep a second
+    pool and copy it back (PERF.md, PR 26);
   - **slot-dense** (legacy, kept for A/B): `[L, slots, max_seq, H, Dh]`,
-    one private lane per slot.
+    one private lane per slot, scanned per layer the old way.
 
 Positions beyond a sequence's current length hold stale bytes; the
 decode mask (`index <= position`) never admits a stale index before the
@@ -300,7 +303,7 @@ def decode_step(
 # ---------------------------------------------------------------- paged
 #
 # vLLM-style paged layout (docs/serving.md "Paged KV & prefix caching"):
-# the cache is a block pool `[L, pool_blocks, block_size, H, Dh]` and each
+# the cache is a block pool `[L, pool_blocks, block_size, H*Dh]` and each
 # sequence owns an ordered block table mapping logical block i → a pool
 # block. The LAST pool block is the trash block: padded/inactive writes
 # land there so they can never corrupt an owned block, and inactive slots
@@ -312,12 +315,16 @@ def decode_step(
 def init_paged_cache(
     cfg: Config, pool_blocks: int, block_size: int, dtype: Any = None
 ) -> Dict[str, jax.Array]:
-    """Zeroed paged KV pool: {"k","v"}: [L, pool_blocks, bs, H, Dh].
+    """Zeroed paged KV pool: {"k","v"}: [L, pool_blocks, bs, H*Dh].
 
-    `pool_blocks` INCLUDES the trailing trash block (callers size it as
+    A token's heads lie side by side in one row: with `bs` a multiple of
+    16 (bf16) and `H*Dh` of 128 the row-major form is dense under the
+    TPU's (8, 128) tiling, so the scatter that writes a token and the
+    kernel that reads a block use the buffer as it rests. `pool_blocks`
+    INCLUDES the trailing trash block (callers size it as
     `num_blocks + 1`)."""
     dt = dtype or cfg.dtype
-    shape = (cfg.n_layer, pool_blocks, block_size, cfg.n_head, cfg.head_dim)
+    shape = (cfg.n_layer, pool_blocks, block_size, cfg.n_head * cfg.head_dim)
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
 
@@ -327,6 +334,19 @@ def paged_cache_bytes(cfg: Config, pool_blocks: int, block_size: int,
     dt = jnp.dtype(dtype or cfg.dtype)
     per = cfg.n_layer * pool_blocks * block_size * cfg.n_head * cfg.head_dim
     return 2 * per * dt.itemsize
+
+
+def _write_rows(pool, layer, blk, off, k, v):
+    """Write tokens' K/V (`[T, H, Dh]`) at `[layer, blk[t], off[t], :]`:
+    one scatter of `[T, H*Dh]` rows each into the carried pool, which the
+    compiler performs in place."""
+    rows = (k.shape[0], -1)
+    return {
+        "k": pool["k"].at[layer, blk, off].set(
+            k.reshape(rows).astype(pool["k"].dtype)),
+        "v": pool["v"].at[layer, blk, off].set(
+            v.reshape(rows).astype(pool["v"].dtype)),
+    }
 
 
 def paged_prefill(
@@ -376,17 +396,15 @@ def paged_prefill(
     scale = 1.0 / math.sqrt(cfg.head_dim)
 
     def body(carry, layer_in):
-        xx = carry
-        lp, k_pool, v_pool = layer_in
+        xx, pool = carry
+        lp, layer = layer_in
         y = _layer_norm(xx, lp["ln1"]["scale"], lp["ln1"]["bias"],
                         cfg.layer_norm_eps)
         q, k, v = _qkv(y, lp, cfg)  # [1, S, H, Dh]
-        k_pool = k_pool.at[dest_blk, dest_off].set(k[0].astype(k_pool.dtype))
-        v_pool = v_pool.at[dest_blk, dest_off].set(v[0].astype(v_pool.dtype))
-        k_lane = k_pool[block_table].reshape(mb * bs, cfg.n_head,
-                                             cfg.head_dim)
-        v_lane = v_pool[block_table].reshape(mb * bs, cfg.n_head,
-                                             cfg.head_dim)
+        pool = _write_rows(pool, layer, dest_blk, dest_off, k[0], v[0])
+        lane = (mb * bs, cfg.n_head, cfg.head_dim)
+        k_lane = pool["k"][layer, block_table].reshape(lane)
+        v_lane = pool["v"][layer, block_table].reshape(lane)
         logits = jnp.einsum("bshd,mhd->bhsm", q, k_lane).astype(jnp.float32)
         logits = jnp.where(mask[None, None], logits * scale,
                            jnp.finfo(jnp.float32).min)
@@ -401,10 +419,10 @@ def paged_prefill(
                         cfg.layer_norm_eps)
         xx = xx + _mlp(y, lp, cfg, rules)
         xx = shard_logical(xx, ("batch", "seq", "embed"), rules)
-        return xx, (k_pool, v_pool)
+        return (xx, pool), None
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["blocks"], cache["k"], cache["v"]))
+    (x, cache), _ = jax.lax.scan(
+        body, (x, cache), (params["blocks"], jnp.arange(cfg.n_layer)))
     if adapters is None:
         logits = _finish(params, x, cfg, rules)  # [1, S, V]
     else:
@@ -412,7 +430,7 @@ def paged_prefill(
                                  rules)
     last = jax.lax.dynamic_index_in_dim(
         logits[0], jnp.maximum(suffix_len - 1, 0), axis=0, keepdims=False)
-    return {"k": new_k, "v": new_v}, last.astype(jnp.float32)
+    return cache, last.astype(jnp.float32)
 
 
 def paged_decode_step(
@@ -454,15 +472,14 @@ def paged_decode_step(
     woff = positions % bs
 
     def body(carry, layer_in):
-        xx = carry  # [slots, 1, D]
-        lp, k_pool, v_pool = layer_in
+        xx, pool = carry  # [slots, 1, D], the whole pool
+        lp, layer = layer_in
         y = _layer_norm(xx, lp["ln1"]["scale"], lp["ln1"]["bias"],
                         cfg.layer_norm_eps)
         q, k, v = _qkv(y, lp, cfg)  # [slots, 1, H, Dh]
-        k_pool = k_pool.at[wblk, woff].set(k[:, 0].astype(k_pool.dtype))
-        v_pool = v_pool.at[wblk, woff].set(v[:, 0].astype(v_pool.dtype))
+        pool = _write_rows(pool, layer, wblk, woff, k[:, 0], v[:, 0])
         attn = paged_decode_attention(
-            q[:, 0], k_pool, v_pool, block_tables, positions,
+            q[:, 0], pool["k"], pool["v"], layer, block_tables, positions,
             impl=attention_impl)  # [slots, H, Dh]
         attn = attn.reshape(slots, 1, -1)
         attn = (jnp.einsum("bsd,de->bse", attn,
@@ -472,16 +489,16 @@ def paged_decode_step(
         y = _layer_norm(xx, lp["ln2"]["scale"], lp["ln2"]["bias"],
                         cfg.layer_norm_eps)
         xx = xx + _mlp(y, lp, cfg, rules)
-        return xx, (k_pool, v_pool)
+        return (xx, pool), None
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["blocks"], cache["k"], cache["v"]))
+    (x, cache), _ = jax.lax.scan(
+        body, (x, cache), (params["blocks"], jnp.arange(cfg.n_layer)))
     if adapters is None:
         logits = _finish(params, x, cfg, rules)  # [slots, 1, V]
     else:
         logits = _finish_adapter(params, x, adapters, slot_adapters, cfg,
                                  rules)
-    return {"k": new_k, "v": new_v}, logits[:, 0].astype(jnp.float32)
+    return cache, logits[:, 0].astype(jnp.float32)
 
 
 def copy_paged_block(

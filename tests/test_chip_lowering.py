@@ -12,9 +12,11 @@ the persistent compile cache lives (`enable_compilation_cache`).
 Budget: < 30 s for the file (tier-1 is time-boxed).
 """
 
+import collections
 import functools
 import importlib.util
 import os
+import re
 import subprocess
 import sys
 
@@ -86,17 +88,93 @@ def test_flash_fwd_bwd_lowers_through_mosaic(v5e, bf16):
                          ids=["bf16", "f32"])
 def test_paged_decode_lowers_through_mosaic(v5e, geometry, dtype):
     """The kernel Mosaic refused before PR 21 (a 2-D lhs with a batch dim
-    and no non-contracting dim), at the served geometry."""
+    and no non-contracting dim), at the served geometry: its operand is
+    the whole pool `[L, blocks, bs, H*Dh]` plus a layer index, and heads
+    are static 128-lane slices of a block's rows."""
     from determined_tpu.ops.paged_attention import paged_attention_pallas
 
     slots, heads, dh, bs, mb = geometry
     mesh = _mesh(v5e[:1], data=1)
-    pool = _sds(mesh, (slots * mb + 1, bs, heads, dh), dtype)
+    pool = _sds(mesh, (2, slots * mb + 1, bs, heads * dh), dtype)
     hlo = jax.jit(paged_attention_pallas).lower(
         _sds(mesh, (slots, heads, dh), dtype), pool, pool,
-        _sds(mesh, (slots, mb), jnp.int32),
+        _sds(mesh, (), jnp.int32), _sds(mesh, (slots, mb), jnp.int32),
         _sds(mesh, (slots,), jnp.int32)).compile().as_text()
     assert hlo.count("tpu_custom_call") == 1
+
+
+def _pool_shaped(hlo, cache):
+    """Instructions of a compiled module whose result is the whole pool or
+    one layer of it, by opcode → count (parameters, tuples and the loop
+    that carries the pool apart: they move nothing)."""
+    dims = ",".join(map(str, cache["k"].shape))
+    layer = ",".join(map(str, cache["k"].shape[1:]))
+    found = collections.Counter()
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.-]+ = (\S+) ([\w-]+)\(", line)
+        if m and re.search(rf"\[(?:1,)?(?:{dims}|{layer})\]", m.group(1)):
+            found[m.group(2)] += 1
+    for moves_nothing in ("parameter", "get-tuple-element", "tuple",
+                          "bitcast", "while"):
+        found.pop(moves_nothing, None)
+    return found
+
+
+@pytest.mark.parametrize("call", ["decode", "prefill"])
+def test_serving_calls_update_the_pool_in_place(v5e, call):
+    """No serving call copies or re-lays-out the KV pool (PERF.md PR 26):
+    at the served head shape, with the pool donated, the compiled decode
+    step and a prefill bucket alias both pool leaves onto their outputs,
+    hold no second pool in scratch, and touch pool-shaped buffers only
+    through the token-sized scatters."""
+    from determined_tpu.models import gpt2
+    from determined_tpu.serve import model as smodel
+
+    # The served pool's own 1281 blocks, four layers of them: nothing here
+    # is allocated, and a pool under the chip's 128 MiB of fast memory is
+    # prefetched there whole and copied back, which is another program.
+    cfg = gpt2.Config(vocab_size=512, n_positions=256, d_model=1280,
+                      n_layer=4, n_head=20, dtype=jnp.bfloat16, remat=False,
+                      attention_impl="dot")
+    slots, bs, mb, pool_blocks = 4, 16, 256 // 16, 1281
+    mesh = _mesh(v5e[:1], data=1)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: _sds(mesh, x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: gpt2.init(jax.random.PRNGKey(0), cfg)))
+    cache = jax.eval_shape(
+        lambda: smodel.init_paged_cache(cfg, pool_blocks, bs))
+    i32 = jnp.int32
+    if call == "decode":
+        fn = functools.partial(smodel.paged_decode_step, cfg=cfg,
+                               attention_impl="pallas")
+        args = (_sds(mesh, (slots,), i32), _sds(mesh, (slots,), i32),
+                _sds(mesh, (slots, mb), i32))
+    else:
+        fn = functools.partial(smodel.paged_prefill, cfg=cfg)
+        args = (_sds(mesh, (128,), i32), _sds(mesh, (), i32),
+                _sds(mesh, (), i32), _sds(mesh, (mb,), i32))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, on_chip(cache), *args).compile()
+    hlo = compiled.as_text()
+    pool_bytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree_util.tree_leaves(cache))
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == pool_bytes
+    header = hlo.split("\n", 1)[0]
+    assert header.count("may-alias") + header.count("must-alias") == 2, header
+    assert memory.temp_size_in_bytes < pool_bytes / 4, memory
+    moved = _pool_shaped(hlo, cache)
+    for opcode in ("copy", "dynamic-slice", "dynamic-update-slice",
+                   "AllocateBuffer", "custom-call", "transpose"):
+        assert opcode not in moved, (opcode, moved)
+    # What is left writes a token's rows: the K and the V scatter (each
+    # once as the fusion's root and once as the fusion).
+    assert set(moved) <= {"scatter", "fusion"}, moved
+    assert hlo.count("tpu_custom_call") == (1 if call == "decode" else 0)
 
 
 @pytest.mark.parametrize("axes", [

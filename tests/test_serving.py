@@ -12,6 +12,7 @@ submit → serve through the master proxy → spot-notice drain → replica
 reschedule onto the survivor.
 """
 
+import dataclasses
 import json
 import os
 import threading
@@ -55,9 +56,19 @@ def _clean_faults():
     faultpoint.disarm_all()
 
 
+# The decode kernel cuts a pool row into 128-lane groups, so its legs run
+# at the smallest width that has one: two heads of 64.
+KERNEL_TINY = dataclasses.replace(TINY, d_model=128)
+
+
 @pytest.fixture(scope="module")
 def tiny_params():
     return gpt2.init(jax.random.PRNGKey(0), TINY)
+
+
+@pytest.fixture(scope="module")
+def kernel_tiny_params():
+    return gpt2.init(jax.random.PRNGKey(0), KERNEL_TINY)
 
 
 def make_engine(params, slots=4, max_seq=32, buckets=(8, 16, 32)):
@@ -65,13 +76,13 @@ def make_engine(params, slots=4, max_seq=32, buckets=(8, 16, 32)):
                          prefill_buckets=list(buckets))
 
 
-def reference_greedy(params, prompt, n):
+def reference_greedy(params, prompt, n, cfg=TINY):
     """Full-forward greedy generation — the ground truth the KV-cached
     path must reproduce exactly."""
     ctx = [int(t) for t in prompt]
     out = []
     for _ in range(n):
-        logits = gpt2.apply(params, jnp.asarray([ctx], jnp.int32), TINY)
+        logits = gpt2.apply(params, jnp.asarray([ctx], jnp.int32), cfg)
         tok = int(jnp.argmax(logits[0, -1]))
         out.append(tok)
         ctx.append(tok)
@@ -444,10 +455,13 @@ def test_batcher_stop_fails_outstanding(tiny_params):
 
 
 @pytest.mark.parametrize("impl", ["reference", "pallas", "dense"])
-def test_attention_impl_greedy_equivalence(tiny_params, impl):
+def test_attention_impl_greedy_equivalence(tiny_params, kernel_tiny_params,
+                                           impl):
     from jax.experimental.pallas import tpu as pltpu
 
-    eng = ServingEngine(tiny_params, TINY, slots=2, max_seq_len=32,
+    cfg, params = ((KERNEL_TINY, kernel_tiny_params) if impl == "pallas"
+                   else (TINY, tiny_params))
+    eng = ServingEngine(params, cfg, slots=2, max_seq_len=32,
                         prefill_buckets=[8, 16, 32], attention_impl=impl)
     # The kernel leg compiles on the CPU only because this test asks for
     # the interpreter; the engine itself never does.
@@ -465,7 +479,7 @@ def test_attention_impl_greedy_equivalence(tiny_params, impl):
         last = int(eng.decode(tokens, positions, temps)[0])
         out.append(last)
         pos += 1
-    assert out == reference_greedy(tiny_params, prompt, 8)
+    assert out == reference_greedy(params, prompt, 8, cfg)
 
 
 def test_paged_reference_bitwise_matches_dense_decode(tiny_params):
@@ -499,29 +513,85 @@ def test_paged_reference_bitwise_matches_dense_decode(tiny_params):
     assert np.array_equal(np.asarray(dstep), np.asarray(pstep))
 
 
-def test_paged_attention_pallas_matches_reference(tiny_params):
+@pytest.mark.parametrize("nh,dh", [(2, 64), (8, 32), (2, 128), (20, 64)],
+                         ids=["2x64", "8x32", "2x128", "served-20x64"])
+def test_paged_attention_pallas_matches_reference(nh, dh):
     """Unit-level: the Pallas kernel (interpret mode on CPU) and the jnp
-    gather agree numerically on a random paged pool, including partially
-    filled blocks and an inactive (trash-table) slot."""
+    gather agree numerically on a random paged pool of several layers,
+    including partially filled blocks and an inactive (trash-table) slot,
+    whether a 128-lane group holds four heads, two or one."""
     import jax.numpy as jnp
 
     from determined_tpu.ops.paged_attention import (
         paged_attention_pallas, paged_attention_reference)
 
     rng = np.random.default_rng(7)
-    slots, mb, bs, nh, dh = 3, 4, 8, 2, 16
-    pool_blocks = slots * mb + 1
+    layers, slots, mb, bs = 3, 3, 4, 8
+    pool = (layers, slots * mb + 1, bs, nh * dh)
     q = jnp.asarray(rng.normal(size=(slots, nh, dh)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(pool_blocks, bs, nh, dh)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(pool_blocks, bs, nh, dh)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=pool), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=pool), jnp.float32)
     tbl = np.arange(slots * mb).reshape(slots, mb).astype(np.int32)
     tbl[2] = slots * mb  # inactive slot: all-trash table
     tbl = jnp.asarray(tbl)
     pos = jnp.asarray([5, 17, 0], jnp.int32)
-    ref = paged_attention_reference(q, kp, vp, tbl, pos)
-    out = paged_attention_pallas(q, kp, vp, tbl, pos, interpret=True)
-    np.testing.assert_allclose(np.asarray(out[:2]), np.asarray(ref[:2]),
-                               atol=1e-5)
+    for layer in (0, 2):
+        ref = paged_attention_reference(q, kp, vp, jnp.int32(layer), tbl, pos)
+        out = paged_attention_pallas(q, kp, vp, jnp.int32(layer), tbl, pos,
+                                     interpret=True)
+        np.testing.assert_allclose(np.asarray(out[:2]), np.asarray(ref[:2]),
+                                   atol=1e-5)
+    # Another layer's K/V give another answer: the layer index is read.
+    assert not np.allclose(
+        np.asarray(paged_attention_reference(q, kp, vp, jnp.int32(1), tbl,
+                                             pos)[:2]), np.asarray(ref[:2]))
+
+
+def test_kernel_geometry_auto_falls_back_and_explicit_pallas_raises(
+        tiny_params, monkeypatch):
+    """TINY's pool row is 2 x 16 = 32 lanes, which no 128-lane slice
+    tiles: `auto` serves it through the reference even on a TPU, and an
+    explicit `pallas` says why it cannot."""
+    from determined_tpu.ops.paged_attention import kernel_refusal
+    from determined_tpu.parallel import mesh
+    from determined_tpu.serve.engine import resolve_attention_impl
+
+    assert kernel_refusal(20, 64) is None and kernel_refusal(2, 128) is None
+    assert "multiple of 128" in kernel_refusal(TINY.n_head, TINY.head_dim)
+    assert "head dim 96" in kernel_refusal(4, 96)
+    monkeypatch.setattr(mesh, "on_tpu", lambda *a, **k: True)
+    assert resolve_attention_impl("auto", KERNEL_TINY) == "pallas"
+    assert resolve_attention_impl("auto", TINY) == "reference"
+    with pytest.raises(ValueError, match="32 lanes"):
+        ServingEngine(tiny_params, TINY, slots=2, max_seq_len=32,
+                      prefill_buckets=[8], attention_impl="pallas")
+    monkeypatch.setattr(mesh, "on_tpu", lambda *a, **k: False)
+    assert resolve_attention_impl("auto", KERNEL_TINY) == "reference"
+
+
+def test_paged_decode_across_admissions_matches_dense(tiny_params):
+    """The suite's oracle over the batcher's whole life: greedy requests
+    admitted and retired in waves through two slots give the same tokens
+    from the carried, in-place pool as from the slot-dense cache."""
+    prompts = [np.arange(1, 1 + n, dtype=np.int32) * 3 % 120 + 1
+               for n in (3, 9, 5, 12, 4, 7)]
+    lengths = [6, 3, 8, 4, 5, 2]
+    served = {}
+    for impl in ("reference", "dense"):
+        eng = ServingEngine(tiny_params, TINY, slots=2, max_seq_len=32,
+                            prefill_buckets=[8, 16], attention_impl=impl,
+                            kv_block_size=8)
+        b = make_batcher(eng).start()
+        try:
+            reqs = [b.submit(Request(p, max_new_tokens=n))
+                    for p, n in zip(prompts, lengths)]
+            served[impl] = [r.result(timeout=60)["tokens"] for r in reqs]
+        finally:
+            b.stop()
+    assert served["reference"] == served["dense"]
+    assert [len(t) for t in served["dense"]] == lengths
+    assert served["dense"][3] == reference_greedy(
+        tiny_params, prompts[3], lengths[3])
 
 
 # ---------------------------------------------------------------------------
