@@ -1,13 +1,15 @@
 """Finding a cell's files by the names in `BENCHMARK.json`.
 
-The harness is driven by data: a configuration, a traffic mix, a metric
-reader and a cell's limits are each a file of their own under one of the
-manifest's `paths`, found by name. A later PR adds files and entries and
-edits nothing here.
+The harness is driven by data: a configuration, its architecture's adapter
+(`models/<model_type>.py`; the contract is `models/__init__.py`), a traffic
+mix, a metric reader and a cell's limits are each a file of their own under
+one of the manifest's `paths`, found by name. A later PR adds files and
+entries and edits nothing here — also for a model of another architecture.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -44,11 +46,29 @@ def load_json(path: str, tiny: bool) -> Dict[str, Any]:
     return merged(data, data.get("tiny", {})) if tiny else data
 
 
+@functools.lru_cache(maxsize=None)
+def load_module(path: str):
+    """A file of the benchmark's or the program's, loaded by path — once a
+    process, so that what it jits compiles once."""
+    name = "bench_" + os.path.basename(path)[:-3].replace(
+        ".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def load_model(root: str, manifest: Dict[str, Any], config: Dict[str, Any]):
+    """The adapter of the configuration's architecture."""
+    return load_module(find(root, manifest,
+                            f"models/{config['model_type']}.py"))
+
+
 def resolve(root: str, manifest: Dict[str, Any], workload: str,
             tiny: bool = False) -> Dict[str, Any]:
     """The cell named `workload`: its entry, its configuration and traffic
-    as run (the test-only tiny overrides applied when asked for), and its
-    limits."""
+    as run (the test-only tiny overrides applied when asked for), its
+    architecture's adapter, and its limits."""
     entry = next((w for w in manifest["workloads"] if w["name"] == workload),
                  None)
     if entry is None:
@@ -63,9 +83,9 @@ def resolve(root: str, manifest: Dict[str, Any], workload: str,
                                 f"limits/{workload}.json"), tiny)
     except FileNotFoundError:
         limits = {}
+    config = load_json(os.path.join(root, config_entry["file"]), tiny)
     return {"name": workload, "chips": int(entry["chips"]), "tiny": tiny,
-            "config": load_json(os.path.join(root, config_entry["file"]),
-                                tiny),
+            "config": config, "model": load_model(root, manifest, config),
             "traffic": spec, "limits": limits, "peak": None}
 
 
@@ -102,12 +122,7 @@ def metrics_for(manifest: Dict[str, Any], workload: str,
 def load_reader(root: str, manifest: Dict[str, Any],
                 name: str) -> Callable[[Dict[str, Any]], Optional[float]]:
     """`read(run)` of `metrics/<name>.py`, loaded by path."""
-    path = find(root, manifest, f"metrics/{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return load_module(find(root, manifest, f"metrics/{name}.py")).read
 
 
 def read_metrics(root: str, manifest: Dict[str, Any], workload: str,

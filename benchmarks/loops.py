@@ -21,7 +21,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from benchmarks import reference, traffic, trace_reduce
+from benchmarks import cells, reference, traffic, trace_reduce
 
 TRACE_DIR = ".bench_trace"      # inside the checkout, git-ignored
 TRACE_SECONDS = 3.0             # serving: how much of the window is traced
@@ -44,16 +44,6 @@ def memory_peak_bytes(devices) -> int:
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
              for d in devices]
     return int(max(peaks)) if peaks else 0
-
-
-def model_dims(config: Dict[str, Any]) -> Dict[str, int]:
-    """The reference's sizes, from the configuration file's published
-    keys."""
-    return {"vocab_size": config["vocab_size"],
-            "n_positions": config["n_positions"],
-            "d_model": config["n_embd"], "n_layer": config["n_layer"],
-            "n_head": config["n_head"],
-            "d_ff": config.get("n_inner") or 4 * config["n_embd"]}
 
 
 @contextlib.contextmanager
@@ -188,23 +178,20 @@ class FitSink:
 
 def train_steps(cell, seed: int, seconds: float, trace: bool, root: str,
                 t0: float, devices) -> Dict[str, Any]:
-    import jax
-
-    sys.path.insert(0, os.path.join(root, "examples", "gpt2"))
-    from model_def import GPT2Trial
-
     from determined_tpu import core
     from determined_tpu.train import Trainer
     from determined_tpu.train.trial import TrialContext
 
     config, spec = cell["config"], cell["traffic"]
     tr, opt = config["train"], config["optimizer"]
+    file, _, name = tr["trial"].partition(" ")
+    trial_class = getattr(cells.load_module(os.path.join(root, file)), name)
     n = len(devices)
     seq_len, batch = int(spec["seq_len"]), int(tr["batch_per_chip"]) * n
     vocab = config["vocab_size"]
 
-    class SeededTrial(GPT2Trial):
-        """The example's trial with its synthetic rows drawn from --seed."""
+    class SeededTrial(trial_class):
+        """The configuration's trial with its rows drawn from --seed."""
 
         def build_training_data(self):
             for step in itertools.count():
@@ -212,7 +199,7 @@ def train_steps(cell, seed: int, seconds: float, trace: bool, root: str,
                     seed, step, batch, seq_len, vocab)}
 
     hparams = {
-        "model_size": config["model_size"], "seq_len": seq_len,
+        **cell["model"].hparams(config), "seq_len": seq_len,
         "global_batch_size": batch, "attention_impl": tr["attention_impl"],
         "remat": tr["remat"], "scan_unroll": tr["scan_unroll"],
         "mesh": {k: v for k, v in tr["mesh"].items() if n > 1},
@@ -272,14 +259,14 @@ def train_standin(cell, seed: int, devices,
     what a step without the exchange between chips computes."""
     import jax
 
-    config, spec = cell["config"], cell["traffic"]
+    config, spec, model = cell["config"], cell["traffic"], cell["model"]
     batch = int(config["train"]["batch_per_chip"]) * len(devices)
     rows = [traffic.train_rows(seed, step, batch, int(spec["seq_len"]),
                                config["vocab_size"]) for step in range(3)]
     if keep_rows < 1.0:
         rows = [r[:max(1, int(batch * keep_rows))] for r in rows]
     return reference.train_three_steps(
-        jax.random.PRNGKey(seed31(seed)), model_dims(config),
+        model, jax.random.PRNGKey(seed31(seed)), model.dims(config),
         config["optimizer"], rows, seed31(seed), quant=quant,
         rows=int(config["train"].get("reference_rows", 2)),
         devices=list(devices))
@@ -324,19 +311,17 @@ class EngineSpans:
         engine.decode, engine.prefill_request = timed_decode, timed_prefill
 
 
-def make_replica(config: Dict[str, Any], serve: Dict[str, Any], params):
+def make_replica(serving: Dict[str, Any], serve: Dict[str, Any], params):
     """`serve.task.build_replica`'s objects, wired the same way, with the
-    weights handed in from the device instead of a checkpoint."""
+    weights handed in from the device instead of a checkpoint; `serving`
+    is the adapter's mapping for the program's `build_model`."""
     from determined_tpu.serve.engine import ServingEngine
     from determined_tpu.serve.kv_cache import BlockManager
     from determined_tpu.serve.scheduler import (AdmissionQueue,
                                                 ContinuousBatcher)
     from determined_tpu.serve.task import build_model
 
-    model_config = {"model_size": config["model_size"],
-                    "seq_len": int(serve["max_seq_len"]),
-                    "dtype": serve["dtype"]}
-    cfg = build_model({"model": "gpt2", "model_config": model_config})
+    cfg = build_model(serving)
     engine = ServingEngine(
         params, cfg, slots=int(serve["max_batch_size"]),
         max_seq_len=int(serve["max_seq_len"]),
@@ -434,13 +419,13 @@ class ClosedLoop:
             t.join(max(0.0, deadline - time.monotonic()))
 
 
-def serve_params(seed: int, config: Dict[str, Any]):
+def serve_params(cell, seed: int):
     """The served weights: float32, on the device, in one jitted call."""
     import jax
 
-    return jax.jit(reference.init_params, static_argnames=("dims",))(
-        jax.random.PRNGKey(seed31(seed)),
-        dims=reference._freeze(model_dims(config)))
+    return reference.draw_params(
+        cell["model"], jax.random.PRNGKey(seed31(seed)),
+        cell["model"].dims(cell["config"]))
 
 
 def closed_loop(cell, seed: int, seconds: float, trace: bool, root: str,
@@ -449,7 +434,8 @@ def closed_loop(cell, seed: int, seconds: float, trace: bool, root: str,
 
     config, spec = cell["config"], cell["traffic"]
     serve = config["serve"]
-    engine, batcher = make_replica(config, serve, serve_params(seed, config))
+    engine, batcher = make_replica(cell["model"].serving(config, serve),
+                                   serve, serve_params(cell, seed))
     spans = EngineSpans(engine)
     batcher.start()                       # AOT-compiles before admitting
     loop = ClosedLoop(batcher, spec, seed, config["vocab_size"])
@@ -556,13 +542,12 @@ def serve_gaps(cell, seed: int, sample, control: Optional[str] = None):
     """Replay the sample through the plain reference (fresh weights from
     the seed) and return each served token's gap below the reference's
     best — or, for the control, the lower precision's first choice's."""
-    config, spec = cell["config"], cell["traffic"]
+    config, spec, model = cell["config"], cell["traffic"], cell["model"]
     shapes = spec["shapes"]
     width = max(p + n for p, n in shapes)
     width = -(-width // 128) * 128 if width > 128 else width
-    params = serve_params(seed, config)
     return reference.replay_gaps(
-        params, model_dims(config),
+        model, serve_params(cell, seed), model.dims(config),
         [(r["prompt"], np.asarray(r["tokens"], np.int32)) for r in sample],
         width=width, max_new=max(n for _, n in shapes),
         rows=int(config["serve"].get("reference_rows", 4)), control=control)

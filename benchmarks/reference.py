@@ -1,15 +1,14 @@
-"""The plain reference: GPT-2 in float32 `jax.numpy`, nothing else.
+"""What every model's plain reference shares, and nothing of any model.
 
-No kernel, no cache, no batching, no sharding rule, and no import of the
-program: this file is the yardstick `correct` is decided against, so it
-may not move when the program does. It follows the published model
-(Radford et al. 2019; `openai-community/gpt2*` `config.json`): learned
-token and position embeddings, pre-LayerNorm blocks (eps 1e-5) of causal
-multi-head attention (q, k, v are the thirds of one projection, heads
-contiguous) and a 4x MLP with the tanh GELU, a final LayerNorm, and the
-output head tied to the token embedding. Every matrix multiplication runs
-under `jax.default_matmul_precision("highest")`: on a TPU a float32
-matmul is otherwise computed in bfloat16 passes.
+An architecture's own float32 forward, loss and weights live in its adapter
+(`benchmarks/models/<model_type>.py`; the contract is `models/__init__.py`)
+and come in here as the argument `model`. This file holds the rest of the
+yardstick `correct` is decided against — the matmul both forwards go
+through and its two controls, AdamW and its schedule, the norms and
+sketches a training cell compares, the three followed steps, the replay of
+served tokens — and imports nothing of the program. Every function of an
+adapter is traced under `jax.default_matmul_precision("highest")`: on a TPU
+a float32 matmul is otherwise computed in bfloat16 passes.
 
 `quant="int8"` (or `"fp8"`, float8 e4m3) is the control, not a feature:
 the same mathematics with both operands of every matmul rounded to 8 bits
@@ -21,7 +20,6 @@ the float32 reference decides nothing.
 from __future__ import annotations
 
 import functools
-import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -31,58 +29,7 @@ import numpy as np
 Params = Dict[str, Any]
 
 
-# ------------------------------------------------------------------ weights
-
-
-def init_params(key: jax.Array, dims: Dict[str, int]) -> Params:
-    """GPT-2's initialisation from one key: N(0, 0.02), residual
-    projections scaled by 1/sqrt(2L), biases 0, LayerNorm scale 1; the
-    blocks stacked along a leading layer axis. The key is split 8 ways
-    (token table, position table, blocks) and the blocks' key 4 ways
-    (qkv, attention out, MLP up, MLP down), which is also how the
-    program's trainer draws its weights — so the training reference can
-    start from the same numbers without being handed them."""
-    d, v, p, n_layer = (dims["d_model"], dims["vocab_size"],
-                        dims["n_positions"], dims["n_layer"])
-    f = dims.get("d_ff") or 4 * d
-    std, res_std = 0.02, 0.02 / math.sqrt(2 * n_layer)
-    keys = jax.random.split(key, 8)
-    ks = jax.random.split(keys[2], 4)
-
-    def normal(k, shape, s):
-        return (jax.random.normal(k, shape) * s).astype(jnp.float32)
-
-    ones = functools.partial(jnp.ones, dtype=jnp.float32)
-    zeros = functools.partial(jnp.zeros, dtype=jnp.float32)
-    return {
-        "wte": normal(keys[0], (v, d), std),
-        "wpe": normal(keys[1], (p, d), std),
-        "blocks": {
-            "ln1": {"scale": ones((n_layer, d)), "bias": zeros((n_layer, d))},
-            "qkv": {"kernel": normal(ks[0], (n_layer, d, 3 * d), std),
-                    "bias": zeros((n_layer, 3 * d))},
-            "attn_out": {"kernel": normal(ks[1], (n_layer, d, d), res_std),
-                         "bias": zeros((n_layer, d))},
-            "ln2": {"scale": ones((n_layer, d)), "bias": zeros((n_layer, d))},
-            "mlp_up": {"kernel": normal(ks[2], (n_layer, d, f), std),
-                       "bias": zeros((n_layer, f))},
-            "mlp_down": {"kernel": normal(ks[3], (n_layer, f, d), res_std),
-                         "bias": zeros((n_layer, d))},
-        },
-        "ln_f": {"scale": ones((d,)), "bias": zeros((d,))},
-    }
-
-
-def param_count(dims: Dict[str, int]) -> int:
-    d, v, p, n_layer = (dims["d_model"], dims["vocab_size"],
-                        dims["n_positions"], dims["n_layer"])
-    f = dims.get("d_ff") or 4 * d
-    per_layer = (3 * d * d + 3 * d) + (d * d + d) + (d * f + f) \
-        + (f * d + d) + 4 * d
-    return v * d + p * d + n_layer * per_layer + 2 * d
-
-
-# ------------------------------------------------------------------ forward
+# ------------------------------------------------------------------- matmul
 
 
 def _fake_int8(x: jax.Array, axis: Optional[int]) -> jax.Array:
@@ -103,104 +50,35 @@ def _fake_fp8(x: jax.Array, axis: Optional[int]) -> jax.Array:
     return x + jax.lax.stop_gradient(q - x)
 
 
-_ROUND = {"int8": _fake_int8, "fp8": _fake_fp8}
+ROUND = {"int8": _fake_int8, "fp8": _fake_fp8}
 
 
-def _matmul(x: jax.Array, w: jax.Array, quant: Optional[str]) -> jax.Array:
+def matmul(x: jax.Array, w: jax.Array, quant: Optional[str]) -> jax.Array:
     """x [..., k] @ w [k, n] in float32 — or on an 8-bit grid."""
     if quant is not None:
-        x, w = _ROUND[quant](x, -1), _ROUND[quant](w, None)
+        x, w = ROUND[quant](x, -1), ROUND[quant](w, None)
     return jnp.matmul(x, w)
 
 
-def _layer_norm(x, scale, bias, eps=1e-5):
-    mu = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
-    return (x - mu) / jnp.sqrt(var + eps) * scale + bias
+@functools.lru_cache(maxsize=None)
+def at_highest(fn, *static: str):
+    """An adapter's function, jitted (the named arguments static) and
+    traced under `highest` matmul precision. Cached by the function, so
+    that every seed of one process shares a compilation."""
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+
+    return jax.jit(traced, static_argnames=static)
 
 
-def _gelu(x):
-    return 0.5 * x * (1.0 + jnp.tanh(
-        math.sqrt(2.0 / math.pi) * (x + 0.044715 * x ** 3)))
-
-
-def _block(x, lp, n_head: int, quant: Optional[str]):
-    b, s, d = x.shape
-    dh = d // n_head
-    y = _layer_norm(x, lp["ln1"]["scale"], lp["ln1"]["bias"])
-    qkv = _matmul(y, lp["qkv"]["kernel"], quant) + lp["qkv"]["bias"]
-    q, k, v = (t.reshape(b, s, n_head, dh).transpose(0, 2, 1, 3)
-               for t in jnp.split(qkv, 3, axis=-1))
-    if quant is not None:
-        q, k, v = (_ROUND[quant](t, -1) for t in (q, k, v))
-    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(dh)
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    scores = jnp.where(causal, scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    if quant is not None:
-        probs = _ROUND[quant](probs, -1)
-    ctx = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
-    ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, d)
-    x = x + _matmul(ctx, lp["attn_out"]["kernel"], quant) \
-        + lp["attn_out"]["bias"]
-    y = _layer_norm(x, lp["ln2"]["scale"], lp["ln2"]["bias"])
-    up = _gelu(_matmul(y, lp["mlp_up"]["kernel"], quant)
-               + lp["mlp_up"]["bias"])
-    return x + _matmul(up, lp["mlp_down"]["kernel"], quant) \
-        + lp["mlp_down"]["bias"]
-
-
-def hidden(params: Params, tokens: jax.Array, n_head: int,
-           quant: Optional[str] = None) -> jax.Array:
-    """tokens [B, S] → the final LayerNorm's output [B, S, d]. The layers
-    run under `lax.scan` with each block rematerialised in the backward
-    pass: the same numbers as a plain loop, in a fraction of the memory."""
-    s = tokens.shape[1]
-    x = params["wte"][tokens] + params["wpe"][:s][None]
-    block = jax.checkpoint(
-        lambda xx, lp: _block(xx, lp, n_head, quant))
-    x, _ = jax.lax.scan(lambda xx, lp: (block(xx, lp), None), x,
-                        params["blocks"])
-    return _layer_norm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
-
-
-def head(params: Params, h: jax.Array, quant: Optional[str] = None):
-    """The tied output head: hidden [..., d] → logits [..., V]."""
-    return _matmul(h, params["wte"].T, quant)
+def draw_params(model, key: jax.Array, dims) -> Params:
+    """The adapter's float32 weights, on the device, in one jitted call."""
+    return at_highest(model.init_params, "dims")(key, dims=dims)
 
 
 # ----------------------------------------------------------------- training
-
-
-def _nll_sum(params, tokens, n_head, quant):
-    """Sum over every position of the next-token negative log-likelihood
-    of rows [B, S+1]."""
-    logits = head(params, hidden(params, tokens[:, :-1], n_head, quant),
-                  quant)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    tgt = jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)
-    return -jnp.sum(tgt)
-
-
-@functools.partial(jax.jit, static_argnames=("n_head", "quant", "rows"))
-def loss_and_grads(params: Params, tokens: jax.Array, *, n_head: int,
-                   quant: Optional[str] = None, rows: int = 2):
-    """Mean loss of the batch [B, S+1] and its gradient, `rows` rows at a
-    time so that the float32 activations fit beside the state."""
-    b = tokens.shape[0]
-    rows = math.gcd(b, rows)
-    blocks = tokens.reshape(b // rows, rows, tokens.shape[1])
-    vg = jax.value_and_grad(_nll_sum)
-
-    def body(acc, block):
-        loss, grads = vg(params, block, n_head, quant)
-        return (acc[0] + loss, jax.tree.map(jnp.add, acc[1], grads)), None
-
-    zero = (jnp.zeros((), jnp.float32), jax.tree.map(jnp.zeros_like, params))
-    with jax.default_matmul_precision("highest"):
-        (loss, grads), _ = jax.lax.scan(body, zero, blocks)
-    n = b * (tokens.shape[1] - 1)
-    return loss / n, jax.tree.map(lambda g: g / n, grads)
 
 
 def learning_rate(opt: Dict[str, float], count) -> jax.Array:
@@ -316,26 +194,25 @@ def spread(tree, devices):
     return jax.tree.map(place, tree)
 
 
-def train_three_steps(key: jax.Array, dims: Dict[str, int],
-                      opt: Dict[str, float], batches, sketch_seed: int,
-                      quant: Optional[str] = None, rows: int = 2,
-                      devices=None):
-    """Follow the first three optimizer steps from the initial weights.
+def train_three_steps(model, key: jax.Array, dims, opt: Dict[str, float],
+                      batches, sketch_seed: int, quant: Optional[str] = None,
+                      rows: int = 2, devices=None):
+    """Follow the first three optimizer steps of the architecture `model`
+    (its adapter) from its initial weights.
 
     Returns the three losses; of the first gradient as the optimizer gets
     it (clipped) the leaf norms, the column norms and the sketch; and the
     sketches of the parameters after step 1 and after step 3 (the warm-up
     schedule's first learning rate is 0, so step 1 moves nothing and the
     change between the two is steps 2 and 3's)."""
-    params = spread(jax.jit(init_params, static_argnames=("dims",))(
-        key, dims=_freeze(dims)), devices)
+    params = spread(draw_params(model, key, dims), devices)
+    loss_and_grads = at_highest(model.loss_and_grads, "dims", "quant", "rows")
     mu = jax.tree.map(jnp.zeros_like, params)
     nu = jax.tree.map(jnp.zeros_like, params)
     out: Dict[str, Any] = {"losses": []}
     for i, tokens in enumerate(batches[:3]):
-        loss, grads = loss_and_grads(params, jnp.asarray(tokens),
-                                     n_head=dims["n_head"], quant=quant,
-                                     rows=rows)
+        loss, grads = loss_and_grads(params, jnp.asarray(tokens), dims=dims,
+                                     quant=quant, rows=rows)
         out["losses"].append(float(loss))
         params, clipped, mu, nu = adamw_step(
             params, grads, mu, nu, i, opt=tuple(sorted(opt.items())))
@@ -349,31 +226,14 @@ def train_three_steps(key: jax.Array, dims: Dict[str, int],
     return out
 
 
-class _freeze(dict):
-    """A dict that can be a static jit argument."""
-
-    def __hash__(self):
-        return hash(tuple(sorted(self.items())))
-
-
 # ------------------------------------------------------------------ serving
 
 
-@functools.partial(jax.jit, static_argnames=("n_head", "quant"))
-def _replay_logits(params, rows, gather, *, n_head, quant=None):
-    """rows [R, T] tokens (prompt then served tokens, right-padded) →
-    logits [R, G, V] at the positions `gather` [R, G]."""
-    with jax.default_matmul_precision("highest"):
-        h = hidden(params, rows, n_head, quant)
-        h = jnp.take_along_axis(h, gather[:, :, None], axis=1)
-        return head(params, h, quant)
-
-
-def replay_gaps(params: Params, dims: Dict[str, int], requests, *,
-                width: int, max_new: int, rows: int = 4,
-                control: Optional[str] = None):
+def replay_gaps(model, params: Params, dims, requests, *, width: int,
+                max_new: int, rows: int = 4, control: Optional[str] = None):
     """For every served token of every request, how far its float32
-    reference logit lies below the reference's best at that position.
+    reference logit (the adapter `model`'s) lies below the reference's
+    best at that position.
 
     `requests` is a list of (prompt ids, served ids). One full forward
     pass over prompt + served tokens gives, at position len(prompt)+i-1,
@@ -382,6 +242,7 @@ def replay_gaps(params: Params, dims: Dict[str, int], requests, *,
     read is that of the token the lower precision puts first at the same
     position, in place of the served one. Returns one gap per token, in
     request order."""
+    logits = at_highest(model.logits, "dims", "quant")
     gaps = []
     for start in range(0, len(requests), rows):
         chunk = requests[start:start + rows]
@@ -392,7 +253,7 @@ def replay_gaps(params: Params, dims: Dict[str, int], requests, *,
             toks[r, :len(seq)] = seq
             n = len(served)
             gather[r, :n] = len(prompt) - 1 + np.arange(n)
-        ref = _replay_logits(params, toks, gather, n_head=dims["n_head"])
+        ref = logits(params, toks, gather, dims=dims)
         best = jnp.max(ref, axis=-1)
         if control is None:
             chosen = np.zeros((rows, max_new), np.int32)
@@ -400,8 +261,7 @@ def replay_gaps(params: Params, dims: Dict[str, int], requests, *,
                 chosen[r, :len(served)] = served
             chosen = jnp.asarray(chosen)
         else:
-            low = _replay_logits(params, toks, gather,
-                                 n_head=dims["n_head"], quant=control)
+            low = logits(params, toks, gather, dims=dims, quant=control)
             chosen = jnp.argmax(low, axis=-1)
         gap = np.asarray(best - jnp.take_along_axis(
             ref, chosen[:, :, None], axis=-1)[:, :, 0])

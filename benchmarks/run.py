@@ -38,10 +38,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     from benchmarks import cells, check, loops
 
     t0 = T0 if t0 is None else t0
-    manifest = cells.load_manifest(root)
-    cell = cells.resolve(root, manifest, workload, tiny)
     if tiny:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    manifest = cells.load_manifest(root)
+    # the cell's adapter imports jax: the platform is chosen before it
+    cell = cells.resolve(root, manifest, workload, tiny)
     import jax
 
     found = jax.devices()

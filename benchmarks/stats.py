@@ -42,7 +42,8 @@ def traced_steps(run: Dict[str, Any]) -> Optional[int]:
     return None
 
 
-def dims(run: Dict[str, Any]) -> Dict[str, int]:
-    from benchmarks.loops import model_dims
-
-    return model_dims(run["cell"]["config"])
+def work(run: Dict[str, Any]) -> Dict[str, int]:
+    """What the cell's architecture gives the readers to count with: its
+    adapter's `work` (models/__init__.py)."""
+    cell = run["cell"]
+    return cell["model"].work(cell["model"].dims(cell["config"]))

@@ -29,6 +29,9 @@ COLLECTIVE = re.compile(
     r"all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute"
     r"|collective-broadcast", re.I)
 CONTAINERS = ("while", "conditional", "call")
+# The program names its phases and spans in lowercase segments joined by
+# dots (`serve.step.fetch`); the runtime's own host events are not so named.
+PHASE = re.compile(r"^[a-z0-9_]+(\.[a-z0-9_]+)+$")
 Interval = Tuple[int, int]
 
 
@@ -168,21 +171,23 @@ def reduce(profile) -> Optional[Dict[str, Any]]:
     # not hidden behind anything, so a collective's time on it (a
     # synchronous one, or the wait in an asynchronous one's `-done`) is
     # exposed time.
-    busy_ns, by_name, kernel_ns, coll_ns = [], {}, [], []
+    busy_ns, by_name, kernels, coll_ns = [], {}, {}, []
     first = min(devices)
     for dev, ops in sorted(devices.items()):
         ops = [(max(s, lo), min(e, hi), text) for s, e, text in ops
                if min(e, hi) > max(s, lo)]
         busy = union((s, e) for s, e, _ in ops)
         busy_ns.append(total(busy))
-        kinds: Dict[str, Tuple[bool, bool]] = {}
-        kernel = coll = 0
+        kinds: Dict[str, Tuple[Optional[str], bool]] = {}
+        coll = 0
         for s, e, text in ops:
             if text not in kinds:
-                kinds[text] = (is_kernel(text), is_collective(text))
-            kernel += (e - s) if kinds[text][0] else 0
-            coll += (e - s) if kinds[text][1] else 0
-        kernel_ns.append(kernel)
+                kinds[text] = (stable_name(text) if is_kernel(text) else None,
+                               is_collective(text))
+            kernel, collective = kinds[text]
+            if kernel is not None:
+                kernels[kernel] = kernels.get(kernel, 0) + (e - s)
+            coll += (e - s) if collective else 0
         coll_ns.append(coll)
         if dev == first:
             first_busy = busy
@@ -213,7 +218,8 @@ def reduce(profile) -> Optional[Dict[str, Any]]:
         "window_s": window_ns / 1e9,
         "busy_s": sum(busy_ns) / n / 1e9,
         "chips": n,
-        "kernel_s": sum(kernel_ns) / n / 1e9,
+        "kernel_s": sum(kernels.values()) / n / 1e9,
+        "kernels": {k: v / n / 1e9 for k, v in kernels.items()},
         "collective_exposed_s": sum(coll_ns) / n / 1e9,
         "ops": {k: v / 1e9 for k, v in by_name.items()},
         "breakdown": {"device_ops": top(by_name), "idle_gaps": top(idle_by)},
@@ -222,23 +228,21 @@ def reduce(profile) -> Optional[Dict[str, Any]]:
 
 def _host_span_at(starts, keys, t: int) -> str:
     """What the host was doing at time t: the benchmark's own span open
-    then, and the innermost other host event open on that same thread."""
+    then, and inside it, on that same thread, the innermost of the
+    program's phases (failing that, the innermost event of any kind: the
+    runtime's own calls sit inside the phases and say less). With no span
+    of the benchmark's open, the same choice over every thread."""
     i = bisect.bisect_right(keys, t)
-    bench = inner = None
-    for s, e, name, tid in reversed(starts[max(0, i - 4000):i]):
-        if e <= t:
-            continue
-        if name.startswith("bench."):
-            if bench is None or s > bench[0]:
-                bench = (s, name, tid)
-        elif inner is None or s > inner[0]:
-            inner = (s, name, tid)
-    if bench is None:
-        return "no_bench_span" if inner is None else \
-            "no_bench_span___" + _clean(inner[1])
-    if inner is not None and inner[2] == bench[2] and inner[0] >= bench[0]:
-        return bench[1] + "___" + _clean(inner[1])
-    return bench[1]
+    open_then = [(s, name, tid) for s, e, name, tid
+                 in starts[max(0, i - 4000):i] if e > t]
+    bench = max((ev for ev in open_then if ev[1].startswith("bench.")),
+                default=None)
+    inside = [ev for ev in open_then if not ev[1].startswith("bench.")
+              and (bench is None or (ev[2] == bench[2] and ev[0] >= bench[0]))]
+    inner = max((ev for ev in inside if PHASE.match(ev[1])),
+                default=max(inside, default=None))
+    where = "no_bench_span" if bench is None else bench[1]
+    return where if inner is None else where + "___" + _clean(inner[1])
 
 
 def _clean(name: str) -> str:

@@ -1,7 +1,7 @@
 """The harness end to end at the test-only tiny size on the CPU: every
 cell's last line, the refusals, the manifest's own rules, that a new
-configuration / traffic mix / metric needs only new files, and that a
-broken timed path comes out as not correct."""
+configuration / traffic mix / metric / architecture needs only new files,
+and that a broken timed path comes out as not correct."""
 
 import json
 import os
@@ -183,50 +183,166 @@ def test_every_name_in_the_manifest_resolves_to_its_file():
         "bf16_flops_per_s"] == 197e12
 
 
-def test_a_new_config_traffic_and_metric_need_only_new_files(tmp_path):
+TOY_ADAPTER = '''"""A fixture architecture: its configuration spells its sizes with keys
+of its own, and it maps them onto the program's GPT-2."""
+from benchmarks.models import Dims, gpt2
+
+init_params, logits, loss_and_grads, work = (
+    gpt2.init_params, gpt2.logits, gpt2.loss_and_grads, gpt2.work)
+
+
+def dims(config):
+    heads, width = config["num_attention_heads"], config["head_dim"]
+    if (config["num_key_value_heads"], heads * width) != (
+            heads, config["hidden_size"]):
+        raise ValueError("the program's GPT-2 has no such attention")
+    return Dims(vocab_size=config["vocab_size"],
+                n_positions=config["max_position_embeddings"],
+                d_model=config["hidden_size"],
+                n_layer=config["num_hidden_layers"], n_head=heads,
+                d_ff=config["intermediate_size"])
+
+
+def serving(config, serve):
+    d = dims(config)
+    return {"model": "gpt2", "model_config": {
+        "vocab_size": d["vocab_size"], "n_positions": d["n_positions"],
+        "d_model": d["d_model"], "n_layer": d["n_layer"],
+        "n_head": d["n_head"], "seq_len": int(serve["max_seq_len"]),
+        "dtype": serve["dtype"]}}
+
+
+def hparams(config):
+    return {"model_size": "tiny"}     # the preset of exactly these sizes
+'''
+
+
+def _snapshot(directory):
+    """Every file under `directory` with its size and time of change."""
+    out = {}
+    for base, _, files in os.walk(directory):
+        if "__pycache__" in base:
+            continue
+        for name in files:
+            stat = os.stat(os.path.join(base, name))
+            out[os.path.join(base, name)] = (stat.st_size, stat.st_mtime_ns)
+    return out
+
+
+@pytest.fixture()
+def extra_root(tmp_path):
+    """A checkout whose manifest has one more directory, `extra/`, with a
+    second GPT-2 configuration, a traffic mix, a metric reader, and a
+    configuration of an architecture of its own (`model_type` toy) with
+    its adapter — all of them files added, none edited."""
     for name in ("benchmarks", "tests", "examples"):
         os.symlink(os.path.join(ROOT, name), tmp_path / name)
     extra = tmp_path / "extra"
-    for sub in ("configs", "traffic", "metrics", "limits"):
+    for sub in ("configs", "traffic", "metrics", "limits", "models"):
         (extra / sub).mkdir(parents=True)
     with open(os.path.join(ROOT, "benchmarks/configs/gpt2-large.json")) as f:
         config = json.load(f)
     config["tiny"]["serve"]["max_batch_size"] = 2
     (extra / "configs/dummy.json").write_text(json.dumps(config))
+    tiny = cells.merged(config, config["tiny"])
+    (extra / "configs/toy.json").write_text(json.dumps({
+        "model_type": "toy", "vocab_size": 512,
+        "max_position_embeddings": 128, "hidden_size": 64,
+        "num_hidden_layers": 2, "num_attention_heads": 4,
+        "num_key_value_heads": 4, "head_dim": 16, "intermediate_size": 256,
+        "serve": tiny["serve"], "train": tiny["train"],
+        "optimizer": tiny["optimizer"]}))
+    (extra / "models/toy.py").write_text(TOY_ADAPTER)
+    # a peak for the CPU's device kind, so that a share of a peak has
+    # something to be a share of; `extra` comes first among the paths
+    (extra / "peaks.json").write_text(json.dumps({"cpu": {
+        "bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11,
+        "source": "a test"}}))
     (extra / "traffic/dummy-mix.json").write_text(json.dumps({
         "kind": "closed_loop", "callers": 2, "temperature": 0.0,
         "shapes": [[5, 2], [9, 3], [7, 2]], "check_requests": 2,
         "why": "a dummy", "who": "a test"}))
     (extra / "metrics/dummy_requests.py").write_text(
         "def read(run):\n    return float(len(run['requests']))\n")
-    (extra / "limits/dummy-cell.json").write_text(json.dumps(
-        {"token_gap": 0.05, "tokens_missing": 0, "never_answered": 0}))
+    serve_limits = {"token_gap": 0.05, "tokens_missing": 0,
+                    "never_answered": 0}
+    (extra / "limits/dummy-cell.json").write_text(json.dumps(serve_limits))
+    (extra / "limits/toy-serve.json").write_text(json.dumps(serve_limits))
+    shutil.copy(os.path.join(ROOT, "benchmarks/limits/train-medium-1chip.json"),
+                extra / "limits/toy-train.json")
     manifest = json.loads(json.dumps(MANIFEST))
-    manifest["paths"].append("extra")
-    manifest["configs"].append({
-        "name": "dummy", "source": "https://example.org/dummy",
-        "file": "extra/configs/dummy.json", "reduced": [], "why": "a dummy"})
-    manifest["workloads"].append({
-        "name": "dummy-cell", "config": "dummy", "traffic": "dummy-mix",
-        "chips": 1, "why": "a dummy"})
-    for metric in manifest["end_to_end"]:
-        if metric["name"] == "serve_tokens_per_s":
-            metric["workloads"].append("dummy-cell")
+    manifest["paths"].insert(0, "extra")
+    manifest["configs"] += [
+        {"name": "dummy", "source": "https://example.org/dummy",
+         "file": "extra/configs/dummy.json", "reduced": [], "why": "a dummy"},
+        {"name": "toy", "source": "https://example.org/toy",
+         "file": "extra/configs/toy.json", "reduced": [], "why": "a toy"}]
+    manifest["workloads"] += [
+        {"name": "dummy-cell", "config": "dummy", "traffic": "dummy-mix",
+         "chips": 1, "why": "a dummy"},
+        {"name": "toy-serve", "config": "toy", "traffic": "dummy-mix",
+         "chips": 1, "why": "a toy, served"},
+        {"name": "toy-train", "config": "toy", "traffic": "fit-steady",
+         "chips": 1, "why": "a toy, trained"}]
+    added = {"serve_tokens_per_s": ["dummy-cell", "toy-serve"],
+             "serve_step_mfu": ["toy-serve"],
+             "train_tokens_per_s_per_chip": ["toy-train"],
+             "train_step_mfu": ["toy-train"]}
+    for metric in manifest["end_to_end"] + manifest["per_layer"]:
+        metric.get("workloads", []).extend(added.get(metric["name"], []))
     manifest["per_layer"].append({
         "name": "dummy_requests", "unit": "requests", "better": "higher",
         "source": "program_counter", "layer": "serve front",
         "moves": "serve_tokens_per_s", "workloads": ["dummy-cell"]})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(manifest))
+    return str(tmp_path)
 
+
+def test_a_new_config_traffic_and_metric_need_only_new_files(extra_root):
     line, run = run_cell("dummy-cell", seed=1, seconds=0.5, trace=True,
-                         tiny=True, root=str(tmp_path))
+                         tiny=True, root=extra_root)
     assert line["correct"] is True
     assert line["metrics"]["dummy_requests"] == {
         "value": float(len(run["requests"])), "unit": "requests"}
     assert run["cell"]["config"]["serve"]["max_batch_size"] == 2
     line, _ = run_cell("dummy-cell", seed=1, seconds=0.5, trace=False,
-                       tiny=True, root=str(tmp_path))
+                       tiny=True, root=extra_root)
     assert set(line["metrics"]) == {"setup_s", "serve_tokens_per_s"}
+
+
+@pytest.mark.parametrize("workload,mfu", [
+    ("toy-serve", "serve_step_mfu"), ("toy-train", "train_step_mfu")])
+def test_a_new_architecture_needs_only_new_files(extra_root, workload, mfu):
+    before = _snapshot(os.path.join(ROOT, "benchmarks"))
+    line, run = run_cell(workload, seed=2 ** 31 + 5, seconds=0.5, trace=True,
+                         tiny=True, root=extra_root)
+    assert run["cell"]["model"].__file__ == os.path.join(
+        extra_root, "extra", "models", "toy.py")
+    assert line["correct"] is True, line["compared"]
+    assert line["attempted"] > 0 and line["failed"] == 0
+    for value, limit in line["compared"].values():
+        assert limit is not None and value <= limit
+    # with a peak for this device the whole step's share of it is there,
+    # counted on what the toy's own adapter says a token multiplies
+    assert line["metrics"][mfu]["value"] > 0
+    assert not [n for n in line["metrics"] if n.endswith("_roofline")]
+    assert _snapshot(os.path.join(ROOT, "benchmarks")) == before
+
+
+def test_no_file_of_the_harness_names_an_architecture():
+    """Outside `models/` (the adapters) and `configs/` (the published
+    keys), nothing under `benchmarks/` knows a model."""
+    named = re.compile(r"gpt2|GPT2|n_embd|n_head|model_size")
+    base = os.path.join(ROOT, "benchmarks")
+    found = []
+    for path in _snapshot(base):
+        relative = os.path.relpath(path, base)
+        if relative.split(os.sep)[0] in ("models", "configs"):
+            continue
+        with open(path, errors="replace") as f:
+            found += [f"{relative}:{n}" for n, text in enumerate(f, 1)
+                      if named.search(text)]
+    assert not found, found
 
 
 # ----------------------------------------------------- a broken timed path
@@ -241,10 +357,14 @@ def _state_left_unchanged(monkeypatch):
             self.replace(step=self.step + 1))
 
 
-def _half_of_the_batch_left_out(monkeypatch):
-    sys.path.insert(0, os.path.join(ROOT, "examples", "gpt2"))
-    import model_def
+def _trial_class():
+    """The class the configurations' `train.trial` names, as the harness
+    loads it."""
+    return cells.load_module(
+        os.path.join(ROOT, "examples/gpt2/model_def.py")).GPT2Trial
 
+
+def _half_of_the_batch_left_out(monkeypatch):
     from determined_tpu.models import gpt2
 
     def loss(self, params, batch, rng):
@@ -252,15 +372,12 @@ def _half_of_the_batch_left_out(monkeypatch):
         return gpt2.loss_fn(params, {"tokens": rows[:rows.shape[0] // 2]},
                             self.cfg, self.sharding_rules())
 
-    monkeypatch.setattr(model_def.GPT2Trial, "loss", loss)
+    monkeypatch.setattr(_trial_class(), "loss", loss)
 
 
 def _exchange_between_chips_left_out(monkeypatch):
     """Without the exchange a chip steps on the gradient of its own rows:
     with four chips, of the first quarter of the batch."""
-    sys.path.insert(0, os.path.join(ROOT, "examples", "gpt2"))
-    import model_def
-
     from determined_tpu.models import gpt2
 
     def loss(self, params, batch, rng):
@@ -268,7 +385,7 @@ def _exchange_between_chips_left_out(monkeypatch):
         return gpt2.loss_fn(params, {"tokens": rows[:rows.shape[0] // 4]},
                             self.cfg, self.sharding_rules())
 
-    monkeypatch.setattr(model_def.GPT2Trial, "loss", loss)
+    monkeypatch.setattr(_trial_class(), "loss", loss)
 
 
 def _token_altered_where_it_is_produced(monkeypatch):

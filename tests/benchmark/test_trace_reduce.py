@@ -19,6 +19,24 @@ def reduced():
     return tr.reduce(tr.load(FIXTURE))
 
 
+class _Named:
+    def __init__(self, name, **fields):
+        self.name = name
+        self.__dict__.update(fields)
+
+
+def _profile(planes):
+    """A profile by hand: {plane: {line: [(start_ns, end_ns, name)]}} as
+    `jax.profiler.ProfileData` shows one."""
+    return _Named("profile", planes=[
+        _Named(plane, lines=[
+            _Named(line, events=[
+                _Named(name, start_ns=s, duration_ns=e - s)
+                for s, e, name in events])
+            for line, events in lines.items()])
+        for plane, lines in planes.items()])
+
+
 def test_busy_and_idle_share_of_the_known_trace(reduced):
     assert reduced["chips"] == 1
     # 20 fusions of ~10 us each inside a window of five 2 ms sleeps
@@ -46,6 +64,56 @@ def test_idle_gaps_go_to_the_host_span_open_then(reduced):
         reduced["window_s"] - reduced["busy_s"])
     assert sum(gaps.values()) == pytest.approx(
         reduced["window_s"] - reduced["busy_s"], rel=1e-6)
+
+
+def test_kernels_by_name_sum_to_kernel_s(reduced):
+    # the recorded trace has fusions only: no kernel, and nothing made up
+    assert reduced["kernels"] == {} and reduced["kernel_s"] == 0
+    # two kernels on two chips, one of them in two calls; a fusion beside
+    kernel = ("%{0}.{1} = bf16[{2},128]{{1,0}} custom-call(bf16[8] %x), "
+              'custom_call_target="tpu_custom_call"')
+    ops = [(0, 300, kernel.format("paged", 1, 32)),
+           (400, 500, kernel.format("paged", 2, 32)),
+           (500, 1000, kernel.format("grouped", 7, 64)),
+           (1000, 1500, "%fusion.3 = bf16[8]{0} fusion(bf16[8] %p), "
+                        "kind=kLoop")]
+    profile = _profile({"/device:TPU:0": {"XLA Ops": ops},
+                        "/device:TPU:1": {"XLA Ops": ops[1:]},
+                        "/host:CPU": {"main": [(0, 1500, "bench.window")]}})
+    out = tr.reduce(profile)
+    assert out["kernels"] == pytest.approx({
+        "paged_bf16_32_128_": (400 + 100) / 2 / 1e9,
+        "grouped_bf16_64_128_": (500 + 500) / 2 / 1e9})
+    assert sum(out["kernels"].values()) == pytest.approx(out["kernel_s"])
+    assert out["kernel_s"] == pytest.approx(0.75e-6)
+    assert "fusion_bf16_8_" in out["ops"] and out["chips"] == 2
+
+
+def test_idle_gaps_take_the_phase_on_the_bench_spans_own_thread():
+    """Two host threads: the batcher's, with the benchmark's span and the
+    program's phases inside it, and a caller's, whose events start later
+    and are nothing the device waits for."""
+    busy = "%fusion.1 = bf16[8]{0} fusion(bf16[8] %p), kind=kLoop"
+    profile = _profile({
+        "/device:TPU:0": {"XLA Ops": [(0, 100, busy), (400, 500, busy),
+                                      (800, 1000, busy)]},
+        "/host:CPU": {
+            "batcher": [(0, 1000, "bench.window"), (50, 450, "bench.decode"),
+                        (60, 440, "serve.step.fetch"),
+                        (70, 430, "np.asarray(jax.Array)"),
+                        (460, 990, "serve.loop.admit"),
+                        (470, 980, "PjitFunction(prefill)")],
+            "caller-3": [(90, 700, "$threading.py:wait")]}})
+    gaps = dict(tr.reduce(profile)["breakdown"]["idle_gaps"])
+    assert gaps == pytest.approx({
+        # the gap at 100..400 opens inside bench.decode: the caller's wait
+        # started later than the phase and is on another thread, and the
+        # runtime's copy inside the phase says less than the phase
+        "bench.decode___serve.step.fetch": 300e-9,
+        # at 500..800 no span of the benchmark's is open: the program's
+        # phase names the gap, not the runtime's call inside it nor the
+        # caller's wait
+        "no_bench_span___serve.loop.admit": 300e-9})
 
 
 def test_no_device_plane_reads_as_nothing():
@@ -124,3 +192,13 @@ def test_paged_decode_work_by_hand():
         16_547_840 / 819e9)
     assert kernel_work.train_flops_per_token(1000, 2, 8, 16) == \
         6000 + 12 * 2 * 8 * 16
+    # four K/V heads under the 20 query heads: the same matmuls over a
+    # fifth of the keys and values; q and the output are as wide as before
+    grouped = kernel_work.paged_decode_work([100] * 32, 20, 64, kv_heads=4)
+    assert grouped["flops"] == work["flops"]
+    assert grouped["bytes"] == 32 * (200 * 4 + 2 * 20) * 64 * 2 < work["bytes"]
+    assert kernel_work.paged_decode_work([100] * 32, 20, 64, kv_heads=20) \
+        == work
+    flash = kernel_work.flash_attention_work(8, 1024, 16, 64, kv_heads=4)
+    assert flash["flops"] == 51_539_607_552
+    assert flash["bytes"] == 6 * 8 * 1024 * (16 + 4) * 64 * 2
