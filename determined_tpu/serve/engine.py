@@ -6,6 +6,9 @@ Owns everything device-side for one serve replica:
     (manifest + COMMIT verified before a single byte is trusted; a corrupt
     latest checkpoint falls back through the COMPLETED lineage exactly
     like `Trainer._restore`),
+  - keeps the weights resident in the serving dtype: what is wider is
+    narrowed once, when the engine is built (`serve/model.py
+    resident_params`), so no call casts a weight again,
   - AOT-compiles the decode step once and the prefill step per prompt
     bucket (`jit(...).lower(...).compile()`), so no request ever pays a
     trace — the serving analogue of the trial preflight discipline:
@@ -111,6 +114,13 @@ def _restore_raw(checkpoint_ctx, storage_id: str) -> Any:
     return ckptr.restore(state_dir, restore_args=as_numpy)
 
 
+def _tree_bytes(tree) -> int:
+    import jax
+
+    return sum(x.size * x.dtype.itemsize
+               for x in jax.tree_util.tree_leaves(tree))
+
+
 def resolve_attention_impl(impl: str, cfg: Config) -> str:
     """serving.attention_impl → the engine's concrete path.
 
@@ -190,7 +200,21 @@ class ServingEngine:
         # One replica, one device: everything the executables take lives
         # on it (params may arrive as numpy or sharded over a mesh).
         device = jax.local_devices()[0]
-        self.params = jax.device_put(params, device)
+        placed = jax.device_put(params, device)
+        # Compute-ready once, here, and not again in every call
+        # (smodel.resident_params): one jitted cast where a leaf is wider
+        # than the serving dtype, else the tree as placed. Nothing is
+        # donated: the caller keeps what it handed in, and self.params is
+        # the only copy the engine holds.
+        def narrow(p):
+            return smodel.resident_params(p, cfg)
+
+        self.weights_hbm_bytes = _tree_bytes(jax.eval_shape(narrow, placed))
+        self.weights_narrowed_bytes = (
+            _tree_bytes(placed) - self.weights_hbm_bytes)
+        self.params = (jax.jit(narrow)(placed)
+                       if self.weights_narrowed_bytes else placed)
+        del placed
         # Multi-adapter serving (docs/serving.md "Model lifecycle"):
         # adapter name → params tree of a head-tuned fine-tune. Only the
         # (tied) embedding/LM-head table participates: the stack
@@ -234,7 +258,10 @@ class ServingEngine:
         self._compiled_prefill: Dict[int, Any] = {}
         self._compiled_sample = None
         self._compiled_copy_block = None
-        self.compile_stats: Dict[str, float] = {}
+        self.compile_stats: Dict[str, float] = {
+            "weights_hbm_bytes": self.weights_hbm_bytes,
+            "weights_narrowed_bytes": self.weights_narrowed_bytes,
+        }
         # Warm-AOT provenance (docs/serving.md "Scale to zero"): how this
         # engine got its executables — "deserialize" when every piece came
         # from the compile-farm artifact store (a scale-from-zero cold
@@ -703,6 +730,8 @@ class ServingEngine:
             "kv_block_size": self.block_size if self.paged else None,
             "kv_num_blocks": self.num_blocks if self.paged else None,
             "cache_hbm_bytes": self.cache_hbm_bytes(),
+            "weights_hbm_bytes": self.weights_hbm_bytes,
+            "weights_narrowed_bytes": self.weights_narrowed_bytes,
             "decode_steps": self.decode_steps,
             "prefills": self.prefills,
             "block_copies": self.block_copies,

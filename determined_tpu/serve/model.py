@@ -72,6 +72,41 @@ def cache_bytes(cfg: Config, slots: int, max_seq: int,
     return 2 * per * dt.itemsize
 
 
+# Block leaves the step functions read only through `.astype(cfg.dtype)`
+# (kernel and bias alike). Layer-norm leaves are not among them:
+# `_layer_norm` multiplies them in float32. A `moe` subtree is read by
+# ops/moe.py, which has its own casts.
+_COMPUTE_DTYPE_BLOCK_LEAVES = ("qkv", "attn_out", "mlp_up", "mlp_down")
+
+
+def resident_params(params: Dict[str, Any], cfg: Config) -> Dict[str, Any]:
+    """The tree the step functions are to be called with, call after call.
+
+    Every leaf they read only through `.astype(cfg.dtype)` — the block
+    matrices and their biases, `wte`, `wpe` — is cast to `cfg.dtype` where
+    it is wider; every other leaf, and a leaf already that narrow, comes
+    back as it came. `bf16(w)` is what each call multiplied by anyway: a
+    float32 tree left as it is has the cast of every `[L, ...]` stack
+    hoisted out of the layer scan and run again in every call (PERF.md,
+    PR 29). The `.astype` in the step functions is a no-op on this tree
+    and keeps direct callers with a float32 tree working."""
+    dt = jnp.dtype(cfg.dtype)
+
+    def narrow(x):
+        wider = (jnp.issubdtype(x.dtype, jnp.floating)
+                 and jnp.dtype(x.dtype).itemsize > dt.itemsize)
+        return x.astype(dt) if wider else x
+
+    out = dict(params)
+    for name in ("wte", "wpe"):
+        out[name] = narrow(params[name])
+    out["blocks"] = {
+        name: (jax.tree_util.tree_map(narrow, leaf)
+               if name in _COMPUTE_DTYPE_BLOCK_LEAVES else leaf)
+        for name, leaf in params["blocks"].items()}
+    return out
+
+
 def _qkv(x, lp, cfg: Config):
     """x: [B, S, D] → q, k, v: [B, S, H, Dh]."""
     b, s, _ = x.shape

@@ -135,12 +135,16 @@ def build_model(serving: Dict[str, Any]):
     )
 
 
-def serving_signature(serving: Dict[str, Any]) -> str:
+def serving_signature(serving: Dict[str, Any],
+                      params: Optional[Dict[str, Any]] = None) -> str:
     """Compile-farm signature for a serving config: every shape-affecting
     knob (model geometry, slots, buckets, paged-KV layout) plus the
     runtime tag, so two replicas of the same deployment — or a respawn
     after scale-to-zero — address the same AOT artifacts, and a config
-    change can never load a stale executable."""
+    change can never load a stale executable. `params` is the engine's
+    resident tree: the executables take its leaves as arguments, so its
+    dtypes and shapes (a float32 or a bfloat16 checkpoint, and what the
+    engine narrowed at load) are part of the key."""
     import hashlib
 
     from determined_tpu.compile.signature import runtime_tag
@@ -150,6 +154,11 @@ def serving_signature(serving: Dict[str, Any]) -> str:
                   "attention_impl", "seed", "adapters")
     key = {k: serving.get(k) for k in shape_keys}
     key["runtime_tag"] = runtime_tag()
+    if params is not None:
+        import jax
+
+        key["resident_avals"] = jax.tree_util.tree_map(
+            lambda x: f"{x.dtype}{list(x.shape)}", params)
     blob = json.dumps(key, sort_keys=True, default=str).encode()
     return "serve-" + hashlib.sha256(blob).hexdigest()[:32]
 
@@ -224,7 +233,8 @@ def build_replica(config: Dict[str, Any], session=None):
         from determined_tpu.compile.runtime import FarmClient
 
         engine.farm = FarmClient(
-            session=session, signature=serving_signature(serving))
+            session=session,
+            signature=serving_signature(serving, engine.params))
     if engine.paged:
         # The device pool IS the budget: the manager mirrors it exactly.
         blocks = BlockManager(

@@ -27,6 +27,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from determined_tpu.parallel.mesh import AXIS_ORDER, MeshConfig, on_tpu
+from determined_tpu.serve.engine import _tree_bytes
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -103,30 +104,35 @@ def test_paged_decode_lowers_through_mosaic(v5e, geometry, dtype):
     assert hlo.count("tpu_custom_call") == 1
 
 
-def _pool_shaped(hlo, cache):
-    """Instructions of a compiled module whose result is the whole pool or
-    one layer of it, by opcode → count (parameters, tuples and the loop
-    that carries the pool apart: they move nothing)."""
-    dims = ",".join(map(str, cache["k"].shape))
-    layer = ",".join(map(str, cache["k"].shape[1:]))
+def _opcodes_with_result(hlo, shapes):
+    """Instructions of a compiled module (fused computations' bodies too)
+    whose result has one of `shapes`, by opcode → count."""
+    dims = "|".join(",".join(map(str, shape)) for shape in shapes)
     found = collections.Counter()
     for line in hlo.splitlines():
         m = re.match(r"\s*(?:ROOT )?%?[\w.-]+ = (\S+) ([\w-]+)\(", line)
-        if m and re.search(rf"\[(?:1,)?(?:{dims}|{layer})\]", m.group(1)):
+        if m and re.search(rf"\[(?:1,)?(?:{dims})\]", m.group(1)):
             found[m.group(2)] += 1
+    return found
+
+
+def _pool_shaped(hlo, cache):
+    """Instructions whose result is the whole pool or one layer of it
+    (parameters, tuples and the loop that carries the pool apart: they
+    move nothing)."""
+    found = _opcodes_with_result(
+        hlo, [cache["k"].shape, cache["k"].shape[1:]])
     for moves_nothing in ("parameter", "get-tuple-element", "tuple",
                           "bitcast", "while"):
         found.pop(moves_nothing, None)
     return found
 
 
-@pytest.mark.parametrize("call", ["decode", "prefill"])
-def test_serving_calls_update_the_pool_in_place(v5e, call):
-    """No serving call copies or re-lays-out the KV pool (PERF.md PR 26):
-    at the served head shape, with the pool donated, the compiled decode
-    step and a prefill bucket alias both pool leaves onto their outputs,
-    hold no second pool in scratch, and touch pool-shaped buffers only
-    through the token-sized scatters."""
+def _compiled_serving_call(v5e, call, resident):
+    """The decode step or a prefill bucket compiled for one v5e chip at
+    the served head shape, pool donated → (compiled, params, cache); the
+    parameters as a float32 checkpoint gives them, or as the engine keeps
+    them (`smodel.resident_params`)."""
     from determined_tpu.models import gpt2
     from determined_tpu.serve import model as smodel
 
@@ -143,8 +149,10 @@ def test_serving_calls_update_the_pool_in_place(v5e, call):
         return jax.tree_util.tree_map(
             lambda x: _sds(mesh, x.shape, x.dtype), tree)
 
-    params = on_chip(jax.eval_shape(
-        lambda: gpt2.init(jax.random.PRNGKey(0), cfg)))
+    params = jax.eval_shape(lambda: gpt2.init(jax.random.PRNGKey(0), cfg))
+    if resident:
+        params = jax.eval_shape(
+            lambda p: smodel.resident_params(p, cfg), params)
     cache = jax.eval_shape(
         lambda: smodel.init_paged_cache(cfg, pool_blocks, bs))
     i32 = jnp.int32
@@ -158,10 +166,20 @@ def test_serving_calls_update_the_pool_in_place(v5e, call):
         args = (_sds(mesh, (128,), i32), _sds(mesh, (), i32),
                 _sds(mesh, (), i32), _sds(mesh, (mb,), i32))
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
-        params, on_chip(cache), *args).compile()
+        on_chip(params), on_chip(cache), *args).compile()
+    return compiled, params, cache
+
+
+@pytest.mark.parametrize("call", ["decode", "prefill"])
+def test_serving_calls_update_the_pool_in_place(v5e, call):
+    """No serving call copies or re-lays-out the KV pool (PERF.md PR 26):
+    at the served head shape, with the pool donated, the compiled decode
+    step and a prefill bucket alias both pool leaves onto their outputs,
+    hold no second pool in scratch, and touch pool-shaped buffers only
+    through the token-sized scatters."""
+    compiled, _, cache = _compiled_serving_call(v5e, call, resident=False)
     hlo = compiled.as_text()
-    pool_bytes = sum(x.size * x.dtype.itemsize
-                     for x in jax.tree_util.tree_leaves(cache))
+    pool_bytes = _tree_bytes(cache)
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == pool_bytes
     header = hlo.split("\n", 1)[0]
@@ -175,6 +193,26 @@ def test_serving_calls_update_the_pool_in_place(v5e, call):
     # once as the fusion's root and once as the fusion).
     assert set(moved) <= {"scatter", "fusion"}, moved
     assert hlo.count("tpu_custom_call") == (1 if call == "decode" else 0)
+
+
+@pytest.mark.parametrize("call", ["decode", "prefill"])
+def test_serving_calls_cast_no_weight_stack(v5e, call):
+    """Against the tree the engine keeps resident (PERF.md PR 29), no call
+    converts a stack of weights and none holds a second copy of them.
+    Read here, against 159.4 MB of resident weights: 0.29 MB of temp for
+    the decode step and 1.0 MB for the 128 bucket; compiled against the
+    float32 tree they hold 52.8 and 53.7 MB, bfloat16 copies of weight
+    stacks made by six converts in every call. At gpt2-large's own size
+    (scratch compile, PR 29): 0.29 MB decode, 0.29 / 2.2 / 2.3 MB the
+    256 / 512 / 1024 buckets, against 1.55 GB."""
+    compiled, params, _ = _compiled_serving_call(v5e, call, resident=True)
+    blocks = params["blocks"]
+    stacks = [blocks[name]["kernel"].shape
+              for name in ("mlp_up", "mlp_down", "qkv", "attn_out")]
+    stacks += [params["wte"].shape, params["wpe"].shape]
+    assert "convert" not in _opcodes_with_result(compiled.as_text(), stacks)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < _tree_bytes(params) / 4, memory
 
 
 @pytest.mark.parametrize("axes", [

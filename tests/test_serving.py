@@ -39,6 +39,7 @@ from determined_tpu.serve import (
     ServingEngine,
     load_checkpoint_params,
 )
+from determined_tpu.serve.engine import _tree_bytes
 from determined_tpu.serve.scheduler import FAULT_POINT_DROP
 
 # Tiny f32 config: CPU-fast, and float32 keeps the cached-decode vs
@@ -223,6 +224,131 @@ def test_cached_decode_matches_full_forward(tiny_params):
     assert out == reference_greedy(tiny_params, prompt, 8)
 
 
+# Weights resident in the serving dtype (PERF.md PR 29): bfloat16 compute
+# over the float32 tree of `tiny_params`.
+TINY_BF16 = dataclasses.replace(TINY, dtype=jnp.bfloat16)
+
+_NARROWED = ("qkv", "attn_out", "mlp_up", "mlp_down")
+
+
+def _small_engine(params, cfg, attention_impl="auto"):
+    return ServingEngine(params, cfg, slots=2, max_seq_len=16,
+                         prefill_buckets=[8], kv_block_size=8,
+                         attention_impl=attention_impl)
+
+
+def test_engine_narrows_weights_to_the_serving_dtype_once(tiny_params):
+    """A float32 checkpoint served in bfloat16 is cast when the engine is
+    built, not in every call: each leaf the step functions read only
+    through `.astype(cfg.dtype)` rests in bfloat16, the layer norms (which
+    multiply in float32) keep the checkpoint's dtype, and the counters say
+    what was saved."""
+    eng = _small_engine(tiny_params, TINY_BF16)
+    blocks = eng.params["blocks"]
+    for name in _NARROWED:
+        assert blocks[name]["kernel"].dtype == jnp.bfloat16, name
+        assert blocks[name]["bias"].dtype == jnp.bfloat16, name
+    assert eng.params["wte"].dtype == jnp.bfloat16
+    assert eng.params["wpe"].dtype == jnp.bfloat16
+    for ln in (blocks["ln1"], blocks["ln2"], eng.params["ln_f"]):
+        assert ln["scale"].dtype == ln["bias"].dtype == jnp.float32
+    stats = eng.stats()
+    saved = _tree_bytes(tiny_params) - _tree_bytes(eng.params)
+    narrowed = sum(_tree_bytes(tiny_params["blocks"][n]) for n in _NARROWED)
+    narrowed += _tree_bytes([tiny_params["wte"], tiny_params["wpe"]])
+    assert saved == narrowed // 2 > 0
+    assert stats["weights_narrowed_bytes"] == saved
+    assert stats["weights_hbm_bytes"] == _tree_bytes(eng.params)
+    assert eng.compile_stats["weights_narrowed_bytes"] == saved
+    # bf16(w) is the value every call cast to anyway.
+    np.testing.assert_array_equal(
+        np.asarray(eng.params["wte"]),
+        np.asarray(tiny_params["wte"].astype(jnp.bfloat16)))
+    # Nothing was donated: the tree handed in serves a second engine.
+    assert not any(x.is_deleted()
+                   for x in jax.tree_util.tree_leaves(tiny_params))
+    again = _small_engine(tiny_params, TINY_BF16)
+    prompt = np.array([5, 9, 17, 3], np.int32)
+    assert again.prefill_request(0, prompt) == eng.prefill_request(0, prompt)
+
+
+@pytest.mark.parametrize("tree_dtype,cfg", [
+    (jnp.bfloat16, TINY_BF16), (jnp.float32, TINY),
+], ids=["bf16_tree", "f32_tree_f32_serving"])
+def test_engine_leaves_a_tree_no_wider_than_the_serving_dtype(
+        tiny_params, tree_dtype, cfg):
+    tree = jax.tree_util.tree_map(lambda x: x.astype(tree_dtype), tiny_params)
+    eng = _small_engine(tree, cfg)
+    assert eng.stats()["weights_narrowed_bytes"] == 0
+    assert eng.stats()["weights_hbm_bytes"] == _tree_bytes(tree)
+    before, after = (jax.tree_util.tree_leaves_with_path(t)
+                     for t in (tree, eng.params))
+    assert [p for p, _ in before] == [p for p, _ in after]
+    for (path, x), (_, y) in zip(before, after):
+        assert x.dtype == y.dtype, path
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("layout", ["paged", "dense"])
+def test_resident_weights_serve_bit_for_bit_what_the_f32_tree_does(
+        tiny_params, layout):
+    """The engine's calls on its narrowed tree give the very logits (and
+    so the tokens) of the step functions called with the float32 tree,
+    whose `.astype(cfg.dtype)` rounds the same values inside the call."""
+    from determined_tpu.serve import model as smodel
+
+    cfg = TINY_BF16
+    paged = layout == "paged"
+    eng = _small_engine(tiny_params, cfg,
+                        attention_impl="reference" if paged else "dense")
+    eng.compile()
+    prompt = np.array([5, 9, 17, 3], np.int32)
+    padded = np.zeros((8,), np.int32)
+    padded[:4] = prompt
+    length, slot = np.int32(4), np.int32(0)
+    table = jnp.asarray([0, 1], jnp.int32)
+    tables = jnp.asarray([[0, 1], [2, 2]], jnp.int32)
+    if paged:
+        cache = smodel.init_paged_cache(cfg, eng.num_blocks + 1, 8)
+        pf = jax.jit(lambda p, c, t: smodel.paged_prefill(
+            p, c, t, length, np.int32(0), table, cfg))
+        dec = jax.jit(lambda p, c, t, pos: smodel.paged_decode_step(
+            p, c, t, pos, tables, cfg))
+        eng_pf = (padded, length, np.int32(0), table)
+        eng_dec = (tables,)
+    else:
+        cache = smodel.init_cache(cfg, 2, 16)
+        pf = jax.jit(lambda p, c, t: smodel.prefill(
+            p, c, t, length, slot, cfg))
+        dec = jax.jit(lambda p, c, t, pos: smodel.decode_step(
+            p, c, t, pos, cfg))
+        eng_pf = (padded, length, slot)
+        eng_dec = ()
+
+    cache, want = pf(tiny_params, cache, padded)
+    eng._cache, got = eng._compiled_prefill[8](
+        eng.params, eng._cache, *eng_pf)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    tokens = np.zeros((2,), np.int32)
+    positions = np.zeros((2,), np.int32)
+    greedy = [int(np.argmax(np.asarray(want)))]
+    for step in range(4):
+        tokens[0], positions[0] = greedy[-1], 4 + step
+        cache, want = dec(tiny_params, cache, tokens, positions)
+        eng._cache, got = eng._compiled_decode(
+            eng.params, eng._cache, tokens, positions, *eng_dec)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        greedy.append(int(np.argmax(np.asarray(want)[0])))
+
+    # And through the engine's own entry points: the generated tokens.
+    served = [eng.prefill_request(0, prompt)]
+    for step in range(4):
+        tokens[0], positions[0] = served[-1], 4 + step
+        served.append(int(eng.decode(
+            tokens, positions, np.zeros((2,), np.float32))[0]))
+    assert served == greedy
+
+
 def test_engine_warm_aot_deserializes_on_second_boot(tiny_params, tmp_path):
     """The scale-to-zero cold-start contract (docs/serving.md "Scale to
     zero"): the FIRST engine for a serving signature traces and saves its
@@ -293,6 +419,24 @@ def test_serving_signature_stable_and_shape_sensitive():
     # Non-shape knobs (ports, sampling) don't fragment the cache.
     assert serving_signature(dict(base, port=9999)) == \
         serving_signature(base)
+
+
+def test_serving_signature_keys_the_resident_dtypes(tiny_params):
+    """The executables take the resident tree's leaves as arguments: an
+    artifact compiled for float32-resident weights must not be loaded by
+    an engine that narrowed them, so the two address different stores."""
+    from determined_tpu.serve.task import serving_signature
+
+    serving = {"model": "gpt2", "model_config": {"model_size": "tiny"},
+               "max_batch_size": 2, "max_seq_len": 16, "kv_block_size": 8}
+    f32 = _small_engine(tiny_params, TINY)
+    bf16 = _small_engine(tiny_params, TINY_BF16)
+    assert f32.params["wte"].dtype != bf16.params["wte"].dtype
+    sigs = [serving_signature(serving, e.params) for e in (f32, bf16)]
+    assert sigs[0] != sigs[1]
+    assert sigs[1] == serving_signature(
+        serving, _small_engine(tiny_params, TINY_BF16).params)
+    assert serving_signature(serving) not in sigs
 
 
 def test_bucket_selection(tiny_params):
