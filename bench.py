@@ -737,8 +737,8 @@ def serve_bench() -> None:
         d_model=base.d_model, n_layer=base.n_layer, n_head=base.n_head,
         remat=False, attention_impl="dot")
     slots, n_requests, max_new = 8, 32, 32
-    max_seq = min(192, base.n_positions)  # the engine clamps anyway; the
-    buckets = [64]                        # A/B HBM math must match it
+    max_seq = min(192, base.n_positions)
+    buckets = [64]
 
     rng = np.random.default_rng(0)
     prompts = [rng.integers(1, cfg.vocab_size,
@@ -842,131 +842,6 @@ def serve_bench() -> None:
         "unit": "ms request latency, p99 (lower is better)",
         "vs_baseline": round(seq["p99_ms"] / cont["p99_ms"], 3),
         "detail": {"sequential_p99_ms": round(seq["p99_ms"], 1)},
-    }))
-
-    # ---- paged vs dense at EQUAL HBM (ISSUE-11; docs/serving.md "Paged
-    # KV & prefix caching"). The dense layout's admission ceiling is its
-    # lane count (slots × max_seq tokens of KV reserved up front); the
-    # paged pool holds the SAME token capacity but decouples concurrency
-    # from it — admission charges each request's real block need, and
-    # prefix caching (on by default in the shipped config) additionally
-    # shares the burst's common system prompt. Three legs over ONE
-    # fleet-shaped burst (shared 48-token system prompt + mixed-length
-    # unique tails): dense → paged(prefix off) → paged(prefix on), so
-    # the packing win and the prefix win decompose cleanly. Each leg
-    # runs twice and keeps its best pass (order-debias: this host's
-    # first-leg timings run cold).
-    dense_slots = 4
-    ab_block_size = 8
-    equal_blocks = dense_slots * max_seq // ab_block_size
-    # Table rows are host-side (no HBM), so paged slots can exceed the
-    # dense lane count freely; 12 ≈ the pool's effective concurrency on
-    # this burst — more lanes would pad the decode batch past what
-    # admission can fill.
-    paged_slots = 12
-    ab_buckets = [32, 64, 96]
-    ab_max_new = 16
-
-    def run_ab(attention_impl, n_slots, prefix_cache, burst):
-        engine = ServingEngine(
-            loaded, cfg, slots=n_slots, max_seq_len=max_seq,
-            prefill_buckets=ab_buckets, attention_impl=attention_impl,
-            kv_block_size=ab_block_size,
-            kv_num_blocks=(equal_blocks if attention_impl != "dense"
-                           else None))
-        bm = BlockManager(
-            num_blocks=equal_blocks, block_size=ab_block_size,
-            prefix_cache=prefix_cache)
-        batcher = ContinuousBatcher(
-            engine, queue=AdmissionQueue(len(burst)), block_manager=bm,
-            idle_wait_s=0.002)
-        batcher.start()
-        try:
-            t0 = time.time()
-            reqs = [batcher.submit(Request(p, max_new_tokens=ab_max_new))
-                    for p in burst]
-            results = [r.result(timeout=1800) for r in reqs]
-            wall = time.time() - t0
-            lats = sorted(r["latency_ms"] for r in results)
-            stats = batcher.stats()
-            return {
-                "tokens_per_s": stats["generated_tokens"] / wall,
-                "p50_ms": lats[len(lats) // 2],
-                "p99_ms": lats[min(len(lats) - 1, int(len(lats) * 0.99))],
-                "max_occupancy": stats["max_occupancy"],
-                "mean_occupancy": stats["mean_occupancy"],
-                "kv": stats["kv_blocks"],
-                "hbm_bytes": engine.cache_hbm_bytes(),
-            }
-        finally:
-            batcher.stop()
-
-    def best_of(n, *args):
-        runs = [run_ab(*args) for _ in range(n)]
-        return max(runs, key=lambda r: r["tokens_per_s"])
-
-    rng2 = np.random.default_rng(1)
-    sys_prompt = rng2.integers(1, cfg.vocab_size, size=48).astype(np.int32)
-    shared_burst = [
-        np.concatenate([sys_prompt,
-                        rng2.integers(1, cfg.vocab_size,
-                                      size=int(rng2.integers(8, 33)))
-                        .astype(np.int32)])
-        for _ in range(n_requests)
-    ]
-    dense_ab = best_of(2, "dense", dense_slots, False, shared_burst)
-    pfx_off = best_of(2, "auto", paged_slots, False, shared_burst)
-    pfx_on = best_of(2, "auto", paged_slots, True, shared_burst)
-    conc_ratio = pfx_on["max_occupancy"] / max(1, dense_ab["max_occupancy"])
-    print(json.dumps({
-        "metric": "serve_paged_tokens_per_s",
-        "value": round(pfx_on["tokens_per_s"], 1),
-        "unit": f"tokens/s, shipped paged config vs dense at equal KV HBM "
-                f"({equal_blocks}x{ab_block_size}-token blocks vs "
-                f"{dense_slots}x{max_seq} lanes; 48-token shared prompt + "
-                f"8-32 unique, {ab_max_new} new)",
-        "vs_baseline": round(
-            pfx_on["tokens_per_s"] / dense_ab["tokens_per_s"], 3),
-        "detail": {
-            "dense_tokens_per_s": round(dense_ab["tokens_per_s"], 1),
-            "paged_prefix_off_tokens_per_s": round(
-                pfx_off["tokens_per_s"], 1),
-            "dense_p50_ms": round(dense_ab["p50_ms"], 1),
-            "dense_p99_ms": round(dense_ab["p99_ms"], 1),
-            "paged_p50_ms": round(pfx_on["p50_ms"], 1),
-            "paged_p99_ms": round(pfx_on["p99_ms"], 1),
-            "dense_hbm_bytes": dense_ab["hbm_bytes"],
-            "paged_hbm_bytes": pfx_on["hbm_bytes"],
-        },
-    }))
-    print(json.dumps({
-        "metric": "serve_paged_admitted_concurrency",
-        "value": pfx_on["max_occupancy"],
-        "unit": "peak concurrent sequences on the burst "
-                "(equal HBM; gate >= 2x dense)",
-        "vs_baseline": round(conc_ratio, 3),
-        "detail": {
-            "dense_max_occupancy": dense_ab["max_occupancy"],
-            "paged_mean_occupancy": pfx_on["mean_occupancy"],
-            "dense_mean_occupancy": dense_ab["mean_occupancy"],
-        },
-    }))
-    print(json.dumps({
-        "metric": "serve_prefix_cache_tokens_per_s",
-        "value": round(pfx_on["tokens_per_s"], 1),
-        "unit": "tokens/s on the shared-system-prompt burst "
-                "(prefix cache on vs off, both paged)",
-        "vs_baseline": round(
-            pfx_on["tokens_per_s"] / pfx_off["tokens_per_s"], 3),
-        "detail": {
-            "off_tokens_per_s": round(pfx_off["tokens_per_s"], 1),
-            "prefix_cache_hit_rate": pfx_on["kv"]["prefix_cache_hit_rate"],
-            "prefix_hit_tokens": pfx_on["kv"]["prefix_hit_tokens"],
-            "on_blocks_allocated": pfx_on["kv"]["total_allocated"],
-            "off_blocks_allocated": pfx_off["kv"]["total_allocated"],
-            "on_p99_ms": round(pfx_on["p99_ms"], 1),
-            "off_p99_ms": round(pfx_off["p99_ms"], 1),
-        },
     }))
 
     # ---- request tracing on/off A/B (ISSUE-12; docs/serving.md "Request

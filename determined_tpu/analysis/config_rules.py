@@ -174,12 +174,10 @@ def check_config(config: Dict[str, Any]) -> List[Diagnostic]:
         bs = serving.get("kv_block_size", 16)
         max_seq = serving.get("max_seq_len", 256)
         nb = serving.get("kv_num_blocks")
-        impl = serving.get("attention_impl", "auto")
-        paged = impl != "dense"
         ok_ints = (isinstance(bs, int) and not isinstance(bs, bool)
                    and bs > 0 and isinstance(max_seq, int)
                    and not isinstance(max_seq, bool) and max_seq > 0)
-        if paged and ok_ints:
+        if ok_ints:
             if max_seq % bs != 0:
                 diags.append(RULES["DTL206"].diag(
                     f"serving.kv_block_size={bs} does not divide "

@@ -488,11 +488,10 @@ def _validate_serving(block: Any, errors: List[str]) -> None:
     if wa is not None and not isinstance(wa, bool):
         errors.append("serving.warm_aot must be a boolean")
     impl = block.get("attention_impl")
-    if impl is not None and impl not in ("auto", "pallas", "reference",
-                                         "dense"):
+    if impl is not None and impl not in ("auto", "pallas", "reference"):
         errors.append(
             "serving.attention_impl must be one of: auto, pallas, "
-            "reference, dense")
+            "reference")
     for key in ("trial_id", "port", "seed"):
         v = block.get(key)
         if v is not None and (

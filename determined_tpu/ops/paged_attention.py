@@ -6,9 +6,9 @@ query token over K/V that live in a **block pool** — `[L, num_blocks,
 block_size, H*Dh]`, every layer in one buffer and a token's heads side by
 side in one row — addressed through a per-slot **block table** (`[slots,
 max_blocks]` int32, logical block i of the sequence → pool block
-`table[s, i]`). The dense layout's `slots × max_seq` lane reservation
-disappears: HBM holds exactly the blocks sequences actually own, and
-admission can pack many more sequences into the same budget.
+`table[s, i]`). No `slots × max_seq` lane is reserved: HBM holds exactly
+the blocks sequences actually own, and admission packs sequences into
+that budget.
 
 Both implementations take the WHOLE pool and a layer index: the serving
 step carries the pool through its loop over layers and writes it in
@@ -21,12 +21,10 @@ Two interchangeable implementations (selected by
 `serving.attention_impl`, asserted token-identical by tests/test_serving):
 
   - `paged_attention_reference` — pure-jnp gather (`pool[layer, table]`:
-    the lanes' blocks, never a layer) + the exact masked-softmax
-    arithmetic of the dense decode step. With
-    `block_size` dividing `max_seq` the gathered lane has the same
-    shape and element order as the dense lane, so greedy decode is
-    bit-identical to the dense path. Fast on CPU; the fallback anywhere
-    Pallas is unavailable.
+    the lanes' blocks, never a layer) + a masked softmax over the
+    gathered lane; in float32 its logits are the full forward's
+    (`gpt2.apply`) to a few ulps (tests/test_serving). Fast on CPU; the
+    fallback anywhere Pallas is unavailable.
 
   - `paged_attention_pallas` — the TPU kernel. Grid `(slots,
     max_blocks)`; the block table, positions and the layer ride
@@ -49,9 +47,8 @@ Two interchangeable implementations (selected by
     through Mosaic unless the caller passes `interpret=True` (tests).
 
 Inactive slots point every table entry at a reserved trash block and sit
-at position 0 — they compute garbage the batcher discards, exactly like
-the dense path's stale lanes, so the executable never depends on which
-slots are live.
+at position 0 — they compute garbage the batcher discards, so the
+executable never depends on which slots are live.
 """
 
 from __future__ import annotations
@@ -77,7 +74,7 @@ if HAVE_PALLAS:
 
 
 # ---------------------------------------------------------------------------
-# Reference implementation: gather + dense masked softmax.
+# Reference implementation: gather + masked softmax over the lane.
 # ---------------------------------------------------------------------------
 
 
@@ -93,10 +90,8 @@ def paged_attention_reference(
 
     Gathers each slot's lane (`pool[layer, table]` → `[max_blocks ×
     block_size, H, Dh]`: the lanes' blocks, never a layer of the pool) and
-    then runs the *identical* arithmetic of the dense decode step
-    (serve/model.decode_step): fp32 logits, `index <= position` mask, fp32
-    softmax, probs cast back to the compute dtype. Identical shapes +
-    identical op order ⇒ bit-identical greedy decode vs dense.
+    attends over it: fp32 logits, `index <= position` mask, fp32 softmax,
+    probs cast back to the compute dtype.
     """
     slots, mb = block_tables.shape
     _, nh, dh = q.shape

@@ -7,12 +7,13 @@ continuous token-level batching — sequences join at decode-step boundaries
 and retire without draining the batch, behind a bounded admission queue.
 
 Layout:
-  model.py      KV-cached GPT-2 prefill/decode steps (shape-static, AOT);
-                paged block-pool variants (paged_prefill/paged_decode_step)
+  model.py      KV-cached GPT-2 prefill/decode steps over the paged
+                block pool (paged_prefill/paged_decode_step; shape-static,
+                AOT)
   kv_cache.py   KV block manager: paged admission accounting, refcounted
                 prefix caching, copy-on-write
   engine.py     checkpoint loading + compiled executables + device state
-                (paged pool + block tables by default; dense kept for A/B)
+                (paged pool + block tables)
   scheduler.py  bounded admission queue + the continuous batcher
   http.py       HTTP front-end (generate/stats/health)
   task.py       cluster entrypoint (drain lifecycle, proxy registration)

@@ -13,8 +13,8 @@ Owns everything device-side for one serve replica:
     bucket (`jit(...).lower(...).compile()`), so no request ever pays a
     trace — the serving analogue of the trial preflight discipline:
     all compilation happens before the first request is admitted,
-  - holds the slot-dense KV cache (donated through every call: one copy
-    in HBM) and a step-folded sampling rng.
+  - holds the paged KV pool (donated through every call: one copy in
+    HBM), the per-slot block tables and a step-folded sampling rng.
 
 The engine is intentionally single-consumer: only the batcher thread
 (scheduler.py) calls prefill/decode; stats reads are lock-free counters.
@@ -125,7 +125,7 @@ def resolve_attention_impl(impl: str, cfg: Config) -> str:
     """serving.attention_impl → the engine's concrete path.
 
     "auto" picks the Pallas kernel on TPU and the jnp gather reference
-    elsewhere (both paged); "pallas"/"reference"/"dense" force a path —
+    elsewhere; "pallas"/"reference" force a path —
     a forced "pallas" off-TPU compiles only under a test's
     `pltpu.force_tpu_interpret_mode()`. A head geometry the kernel cannot
     take (ops/paged_attention.kernel_refusal) sends "auto" to the
@@ -148,25 +148,24 @@ def resolve_attention_impl(impl: str, cfg: Config) -> str:
             f"serving.attention_impl: pallas cannot serve this model: "
             f"{why_not}; use auto (which takes the reference path for "
             "it) or reference")
-    if impl in ("pallas", "reference", "dense"):
+    if impl in ("pallas", "reference"):
         return impl
     raise ValueError(
         f"unknown serving.attention_impl {impl!r}; "
-        "valid: auto, pallas, reference, dense")
+        "valid: auto, pallas, reference")
 
 
 class ServingEngine:
     """Compiled prefill/decode over a fixed slot batch + KV cache.
 
-    The cache is paged by default (docs/serving.md "Paged KV & prefix
-    caching"): a block pool `[L, num_blocks + 1, block_size, H, Dh]`
-    (the extra block is the trash block for padded/inactive writes) plus
-    per-slot block tables the batcher hands in at prefill. Every
-    executable takes the table as an input canonicalized to the full
+    The cache is paged (docs/serving.md "Paged KV & prefix caching"): a
+    block pool `[L, num_blocks + 1, block_size, H*Dh]` (the extra block
+    is the trash block for padded/inactive writes) plus per-slot block
+    tables the batcher hands in at prefill. Every executable takes the
+    table as an input canonicalized to the full
     `max_seq_len // block_size` length, so ONE decode executable and one
     prefill executable per token bucket cover every table — joining,
-    retiring and prefix sharing never recompile. `attention_impl:
-    dense` keeps the legacy slot-dense lane layout for A/B benching.
+    retiring and prefix sharing never recompile.
     """
 
     def __init__(
@@ -246,12 +245,11 @@ class ServingEngine:
             self._adapter_stack = jnp.stack(tables)
             self._slot_adapters = np.zeros((slots,), np.int32)
         self.attention_impl = resolve_attention_impl(attention_impl, cfg)
-        self.paged = self.attention_impl != "dense"
         self.block_size = int(kv_block_size)
         self.num_blocks = int(kv_num_blocks) if kv_num_blocks else 0
         self._check_geometry()
         self._cache = None  # materialized at compile() (geometry may move)
-        self._tables = None  # host [slots, max_blocks] int32, paged only
+        self._tables = None  # host [slots, max_blocks] int32
         self._rng = jax.random.PRNGKey(seed)
         self._step_counter = 0
         self._compiled_decode = None
@@ -276,8 +274,6 @@ class ServingEngine:
     # -- paged geometry ------------------------------------------------
 
     def _check_geometry(self) -> None:
-        if not self.paged:
-            return
         if self.max_seq_len % self.block_size != 0:
             raise ValueError(
                 f"kv_block_size {self.block_size} must divide max_seq_len "
@@ -331,8 +327,6 @@ class ServingEngine:
         """Sync the device pool to an external BlockManager's geometry
         (the batcher calls this before compile so the tables it hands
         out index the real pool)."""
-        if not self.paged:
-            return
         if (self._compiled_decode is not None
                 and (block_size != self.block_size
                      or num_blocks != self.num_blocks)):
@@ -346,10 +340,8 @@ class ServingEngine:
 
     def cache_hbm_bytes(self) -> int:
         """HBM the KV cache occupies (the admission budget's anchor)."""
-        if self.paged:
-            return smodel.paged_cache_bytes(
-                self.cfg, self.num_blocks + 1, self.block_size)
-        return smodel.cache_bytes(self.cfg, self.slots, self.max_seq_len)
+        return smodel.paged_cache_bytes(
+            self.cfg, self.num_blocks + 1, self.block_size)
 
     # -- compilation ---------------------------------------------------
 
@@ -401,15 +393,11 @@ class ServingEngine:
         t_all = time.monotonic()
         cfg, rules = self.cfg, self.rules
         if self._cache is None:
-            if self.paged:
-                self._cache = smodel.init_paged_cache(
-                    cfg, self.num_blocks + 1, self.block_size)
-                self._tables = np.full(
-                    (self.slots, self.max_blocks_per_seq),
-                    self.trash_block, np.int32)
-            else:
-                self._cache = smodel.init_cache(
-                    cfg, self.slots, self.max_seq_len)
+            self._cache = smodel.init_paged_cache(
+                cfg, self.num_blocks + 1, self.block_size)
+            self._tables = np.full(
+                (self.slots, self.max_blocks_per_seq),
+                self.trash_block, np.int32)
         sds = jax.ShapeDtypeStruct
         cache_sd = jax.tree_util.tree_map(
             lambda x: sds(x.shape, x.dtype), self._cache)
@@ -429,106 +417,66 @@ class ServingEngine:
                            self._adapter_stack.dtype)
 
         t0 = time.monotonic()
-        if self.paged:
-            def build_decode():
-                if stack_sd is not None:
-                    decode = jax.jit(
-                        lambda p, c, t, pos, tbl, ad, sa:
-                            smodel.paged_decode_step(
-                                p, c, t, pos, tbl, cfg, rules,
-                                attention_impl=impl, adapters=ad,
-                                slot_adapters=sa),
-                        donate_argnums=(1,))
-                    return decode.lower(
-                        params_sd, cache_sd, sds((self.slots,), i32),
-                        sds((self.slots,), i32), sds((self.slots, mb), i32),
-                        stack_sd, sds((self.slots,), i32)).compile()
+
+        def build_decode():
+            if stack_sd is not None:
                 decode = jax.jit(
-                    lambda p, c, t, pos, tbl: smodel.paged_decode_step(
-                        p, c, t, pos, tbl, cfg, rules, attention_impl=impl),
+                    lambda p, c, t, pos, tbl, ad, sa:
+                        smodel.paged_decode_step(
+                            p, c, t, pos, tbl, cfg, rules,
+                            attention_impl=impl, adapters=ad,
+                            slot_adapters=sa),
                     donate_argnums=(1,))
                 return decode.lower(
                     params_sd, cache_sd, sds((self.slots,), i32),
-                    sds((self.slots,), i32),
-                    sds((self.slots, mb), i32)).compile()
-        else:
-            def build_decode():
-                if stack_sd is not None:
-                    decode = jax.jit(
-                        lambda p, c, t, pos, ad, sa: smodel.decode_step(
-                            p, c, t, pos, cfg, rules, adapters=ad,
-                            slot_adapters=sa),
-                        donate_argnums=(1,))
-                    return decode.lower(
-                        params_sd, cache_sd, sds((self.slots,), i32),
-                        sds((self.slots,), i32), stack_sd,
-                        sds((self.slots,), i32)).compile()
-                decode = jax.jit(
-                    lambda p, c, t, pos: smodel.decode_step(
-                        p, c, t, pos, cfg, rules),
-                    donate_argnums=(1,))
-                return decode.lower(
-                    params_sd, cache_sd,
-                    sds((self.slots,), i32), sds((self.slots,), i32)).compile()
+                    sds((self.slots,), i32), sds((self.slots, mb), i32),
+                    stack_sd, sds((self.slots,), i32)).compile()
+            decode = jax.jit(
+                lambda p, c, t, pos, tbl: smodel.paged_decode_step(
+                    p, c, t, pos, tbl, cfg, rules, attention_impl=impl),
+                donate_argnums=(1,))
+            return decode.lower(
+                params_sd, cache_sd, sds((self.slots,), i32),
+                sds((self.slots,), i32),
+                sds((self.slots, mb), i32)).compile()
         self._compiled_decode = acquire("decode", build_decode)
         self.compile_stats["decode_s"] = round(time.monotonic() - t0, 3)
 
+        def build_prefill(bucket):
+            if stack_sd is not None:
+                pf = jax.jit(
+                    lambda p, c, t, ln, pfx, tbl, ad, sa:
+                        smodel.paged_prefill(
+                            p, c, t, ln, pfx, tbl, cfg, rules,
+                            adapters=ad, slot_adapter=sa),
+                    donate_argnums=(1,))
+                return pf.lower(
+                    params_sd, cache_sd, sds((bucket,), i32),
+                    sds((), i32), sds((), i32), sds((mb,), i32),
+                    stack_sd, sds((), i32)).compile()
+            pf = jax.jit(
+                lambda p, c, t, ln, pfx, tbl: smodel.paged_prefill(
+                    p, c, t, ln, pfx, tbl, cfg, rules),
+                donate_argnums=(1,))
+            return pf.lower(
+                params_sd, cache_sd, sds((bucket,), i32),
+                sds((), i32), sds((), i32), sds((mb,), i32)).compile()
+
         for bucket in self.prefill_buckets:
             t0 = time.monotonic()
-            if self.paged:
-                def build_prefill(bucket=bucket):
-                    if stack_sd is not None:
-                        pf = jax.jit(
-                            lambda p, c, t, ln, pfx, tbl, ad, sa:
-                                smodel.paged_prefill(
-                                    p, c, t, ln, pfx, tbl, cfg, rules,
-                                    adapters=ad, slot_adapter=sa),
-                            donate_argnums=(1,))
-                        return pf.lower(
-                            params_sd, cache_sd, sds((bucket,), i32),
-                            sds((), i32), sds((), i32), sds((mb,), i32),
-                            stack_sd, sds((), i32)).compile()
-                    pf = jax.jit(
-                        lambda p, c, t, ln, pfx, tbl: smodel.paged_prefill(
-                            p, c, t, ln, pfx, tbl, cfg, rules),
-                        donate_argnums=(1,))
-                    return pf.lower(
-                        params_sd, cache_sd, sds((bucket,), i32),
-                        sds((), i32), sds((), i32), sds((mb,), i32)).compile()
-            else:
-                def build_prefill(bucket=bucket):
-                    if stack_sd is not None:
-                        pf = jax.jit(
-                            lambda p, c, t, ln, sl, ad, sa: smodel.prefill(
-                                p, c, t, ln, sl, cfg, rules, adapters=ad,
-                                slot_adapter=sa),
-                            donate_argnums=(1,))
-                        return pf.lower(
-                            params_sd, cache_sd, sds((bucket,), i32),
-                            sds((), i32), sds((), i32), stack_sd,
-                            sds((), i32)).compile()
-                    pf = jax.jit(
-                        lambda p, c, t, ln, sl: smodel.prefill(
-                            p, c, t, ln, sl, cfg, rules),
-                        donate_argnums=(1,))
-                    return pf.lower(
-                        params_sd, cache_sd, sds((bucket,), i32),
-                        sds((), i32), sds((), i32)).compile()
             self._compiled_prefill[bucket] = acquire(
-                f"prefill_{bucket}", build_prefill)
+                f"prefill_{bucket}", lambda: build_prefill(bucket))
             self.compile_stats[f"prefill_{bucket}_s"] = round(
                 time.monotonic() - t0, 3)
 
-        if self.paged:
-            t0 = time.monotonic()
+        t0 = time.monotonic()
 
-            def build_copy():
-                cp = jax.jit(smodel.copy_paged_block, donate_argnums=(0,))
-                return cp.lower(
-                    cache_sd, sds((), i32), sds((), i32)).compile()
-            self._compiled_copy_block = acquire("copy_block", build_copy)
-            self.compile_stats["copy_block_s"] = round(
-                time.monotonic() - t0, 3)
+        def build_copy():
+            cp = jax.jit(smodel.copy_paged_block, donate_argnums=(0,))
+            return cp.lower(
+                cache_sd, sds((), i32), sds((), i32)).compile()
+        self._compiled_copy_block = acquire("copy_block", build_copy)
+        self.compile_stats["copy_block_s"] = round(time.monotonic() - t0, 3)
 
         t0 = time.monotonic()
 
@@ -588,8 +536,6 @@ class ServingEngine:
         """Copy-on-write device copy: pool block `src` → `dst` across all
         layers (both K and V). The BlockManager decides WHEN (a shared
         block is about to be written); this mirrors it on-device."""
-        if not self.paged:
-            raise RuntimeError("copy_block requires the paged cache")
         if self._compiled_decode is None:
             self.compile()
         self._cache = self._compiled_copy_block(
@@ -626,25 +572,6 @@ class ServingEngine:
             raise ValueError("engine has no adapters resident")
         self.set_slot_adapter(slot, adapter)
         length = int(tokens.shape[0])
-        if not self.paged:
-            if cached_len:
-                raise ValueError(
-                    "prefix caching requires the paged cache layout")
-            bucket = self.bucket_for(length)
-            if bucket is None:
-                raise ValueError(
-                    f"prompt length {length} exceeds the largest prefill "
-                    f"bucket ({self.prefill_buckets[-1]})")
-            padded = np.zeros((bucket,), np.int32)
-            padded[:length] = tokens
-            args = [self.params, self._cache, padded,
-                    np.int32(length), np.int32(slot)]
-            if self.has_adapters:
-                args += [self._adapter_stack, np.int32(adapter)]
-            ph.set(bucket=bucket, novel=length)
-            self._cache, logits = self._compiled_prefill[bucket](*args)
-            self.prefills += 1
-            return logits
         if not 0 <= cached_len < length:
             raise ValueError(
                 f"cached_len {cached_len} must leave >= 1 novel token "
@@ -691,7 +618,7 @@ class ServingEngine:
         """Point a retired slot's table at the trash block so later
         decode steps can never touch its (possibly reallocated) blocks,
         and hand the lane's adapter back to base."""
-        if self.paged and self._tables is not None:
+        if self._tables is not None:
             self._tables[slot] = self.trash_block
         self.set_slot_adapter(slot, 0)
 
@@ -699,16 +626,14 @@ class ServingEngine:
                temperatures: np.ndarray) -> np.ndarray:
         """One decode step for all slots → sampled next tokens [slots].
 
-        Paged mode feeds the per-slot block tables recorded at prefill
-        (they only change at admission/CoW, both of which happen at step
+        Feeds the per-slot block tables recorded at prefill (they only
+        change at admission/CoW, both of which happen at step
         boundaries in the batcher thread)."""
         if self._compiled_decode is None:
             self.compile()
         with trace.phase("serve.step.dispatch"):
             args = [self.params, self._cache, np.asarray(tokens, np.int32),
-                    np.asarray(positions, np.int32)]
-            if self.paged:
-                args.append(self._tables)
+                    np.asarray(positions, np.int32), self._tables]
             if self.has_adapters:
                 args += [self._adapter_stack, self._slot_adapters.copy()]
             self._cache, logits = self._compiled_decode(*args)
@@ -726,9 +651,9 @@ class ServingEngine:
             "max_seq_len": self.max_seq_len,
             "prefill_buckets": list(self.prefill_buckets),
             "attention_impl": self.attention_impl,
-            "kv_layout": "paged" if self.paged else "dense",
-            "kv_block_size": self.block_size if self.paged else None,
-            "kv_num_blocks": self.num_blocks if self.paged else None,
+            "kv_layout": "paged",
+            "kv_block_size": self.block_size,
+            "kv_num_blocks": self.num_blocks,
             "cache_hbm_bytes": self.cache_hbm_bytes(),
             "weights_hbm_bytes": self.weights_hbm_bytes,
             "weights_narrowed_bytes": self.weights_narrowed_bytes,

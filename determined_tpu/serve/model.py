@@ -13,25 +13,20 @@ standard way:
     composition; the continuous batcher joins/retires sequences purely by
     editing host-side slot state.
 
-Two cache layouts share this module (every path `lax.scan`s the layers'
-stacked params):
-
-  - **paged** (the default; `init_paged_cache`/`paged_prefill`/
-    `paged_decode_step`): a block pool `[L, num_blocks + 1, block_size,
-    H*Dh]` addressed through per-sequence block tables — admission
-    bounds real HBM and prompt prefixes can be shared (docs/serving.md
-    "Paged KV & prefix caching"). The pool is the scan's CARRY, one
-    buffer for the whole call, written in place by token-sized scatters
-    and read through the tables; it is never a per-layer input or a
-    stacked output of the scan, which made the compiler keep a second
-    pool and copy it back (PERF.md, PR 26);
-  - **slot-dense** (legacy, kept for A/B): `[L, slots, max_seq, H, Dh]`,
-    one private lane per slot, scanned per layer the old way.
+The cache is a block pool `[L, num_blocks + 1, block_size, H*Dh]`
+addressed through per-sequence block tables (`init_paged_cache` /
+`paged_prefill` / `paged_decode_step`): admission bounds real HBM and
+prompt prefixes can be shared (docs/serving.md "Paged KV & prefix
+caching"). Both steps `lax.scan` the layers' stacked params with the pool
+as the scan's CARRY, one buffer for the whole call, written in place by
+token-sized scatters and read through the tables; it is never a per-layer
+input or a stacked output of the scan, which made the compiler keep a
+second pool and copy it back (PERF.md, PR 26).
 
 Positions beyond a sequence's current length hold stale bytes; the
 decode mask (`index <= position`) never admits a stale index before the
-step that overwrites it, and paged padded/inactive writes land in a
-dedicated trash block.
+step that overwrites it, and padded/inactive writes land in a dedicated
+trash block.
 
 Works for dense and MoE blocks (the MoE FFN routes per token, so a
 1-token decode step reuses ops/moe.moe_block unchanged). All functions are
@@ -49,27 +44,6 @@ import jax.numpy as jnp
 
 from determined_tpu.models.gpt2 import Config, _embed_tokens, _layer_norm
 from determined_tpu.parallel.sharding import LogicalRules, shard_logical
-
-
-def init_cache(
-    cfg: Config, slots: int, max_seq: int, dtype: Any = None
-) -> Dict[str, jax.Array]:
-    """Zeroed KV cache: {"k","v"}: [L, slots, max_seq, H, Dh]."""
-    if max_seq > cfg.n_positions:
-        raise ValueError(
-            f"max_seq {max_seq} exceeds the model's position table "
-            f"({cfg.n_positions})")
-    dt = dtype or cfg.dtype
-    shape = (cfg.n_layer, slots, max_seq, cfg.n_head, cfg.head_dim)
-    return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
-
-
-def cache_bytes(cfg: Config, slots: int, max_seq: int,
-                dtype: Any = None) -> int:
-    """HBM footprint of the cache (both K and V) — admission budgeting."""
-    dt = jnp.dtype(dtype or cfg.dtype)
-    per = cfg.n_layer * slots * max_seq * cfg.n_head * cfg.head_dim
-    return 2 * per * dt.itemsize
 
 
 # Block leaves the step functions read only through `.astype(cfg.dtype)`
@@ -190,149 +164,6 @@ def _finish_adapter(params, x, adapters: jax.Array, idx: jax.Array,
     else:
         logits = jnp.einsum("sqd,svd->sqv", x, sel)
     return shard_logical(logits, ("batch", "seq", "vocab"), rules)
-
-
-# ---------------------------------------------------------------- prefill
-
-
-def prefill(
-    params: Dict[str, Any],
-    cache: Dict[str, jax.Array],
-    tokens: jax.Array,   # [bucket] int32, right-padded to the bucket size
-    length: jax.Array,   # scalar int32: real prompt length (<= bucket)
-    slot: jax.Array,     # scalar int32: cache lane to fill
-    cfg: Config,
-    rules: Optional[LogicalRules] = None,
-    adapters: Optional[jax.Array] = None,   # [A+1, V, D] stack
-    slot_adapter: Optional[jax.Array] = None,  # scalar int32 stack index
-) -> Tuple[Dict[str, jax.Array], jax.Array]:
-    """Run the prompt through the model, filling cache lane `slot`.
-
-    Returns (cache', next_token_logits [vocab]). Padded positions compute
-    garbage K/V but the decode mask never reads an index the decode loop
-    has not since overwritten (module docstring).
-    """
-    s = tokens.shape[0]
-    dt = cfg.dtype
-    if adapters is None:
-        x = _embed_tokens(params, tokens[None], cfg, rules, dt)
-    else:
-        x = _embed_adapter(adapters, slot_adapter, tokens, dt)[None]
-    x = x + params["wpe"].astype(dt)[:s][None]
-    x = shard_logical(x, ("batch", "seq", "embed"), rules)
-    causal = jnp.tril(jnp.ones((s, s), jnp.bool_))
-    valid = jnp.arange(s)[None, :] < length  # [1, S] key-side padding mask
-    mask = causal & valid
-
-    def body(carry, layer_in):
-        xx = carry
-        lp, k_lane, v_lane = layer_in
-        y = _layer_norm(xx, lp["ln1"]["scale"], lp["ln1"]["bias"],
-                        cfg.layer_norm_eps)
-        q, k, v = _qkv(y, lp, cfg)
-        # Write this layer's K/V for the whole prompt into the slot's lane.
-        k_lane = jax.lax.dynamic_update_slice(
-            k_lane, k.astype(k_lane.dtype), (slot, 0, 0, 0))
-        v_lane = jax.lax.dynamic_update_slice(
-            v_lane, v.astype(v_lane.dtype), (slot, 0, 0, 0))
-        scale = 1.0 / math.sqrt(cfg.head_dim)
-        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
-        logits = jnp.where(mask[None, None], logits * scale,
-                           jnp.finfo(jnp.float32).min)
-        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-        attn = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-        attn = attn.reshape(xx.shape)
-        attn = (jnp.einsum("bsd,de->bse", attn,
-                           lp["attn_out"]["kernel"].astype(dt))
-                + lp["attn_out"]["bias"].astype(dt))
-        xx = xx + attn
-        y = _layer_norm(xx, lp["ln2"]["scale"], lp["ln2"]["bias"],
-                        cfg.layer_norm_eps)
-        xx = xx + _mlp(y, lp, cfg, rules)
-        xx = shard_logical(xx, ("batch", "seq", "embed"), rules)
-        return xx, (k_lane, v_lane)
-
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["blocks"], cache["k"], cache["v"]))
-    if adapters is None:
-        logits = _finish(params, x, cfg, rules)  # [1, S, V]
-    else:
-        logits = _finish_adapter(params, x, adapters, slot_adapter, cfg,
-                                 rules)
-    last = jax.lax.dynamic_index_in_dim(
-        logits[0], jnp.maximum(length - 1, 0), axis=0, keepdims=False)
-    return {"k": new_k, "v": new_v}, last.astype(jnp.float32)
-
-
-# ---------------------------------------------------------------- decode
-
-
-def decode_step(
-    params: Dict[str, Any],
-    cache: Dict[str, jax.Array],
-    tokens: jax.Array,     # [slots] int32: last emitted token per slot
-    positions: jax.Array,  # [slots] int32: index this step writes/attends at
-    cfg: Config,
-    rules: Optional[LogicalRules] = None,
-    adapters: Optional[jax.Array] = None,      # [A+1, V, D] stack
-    slot_adapters: Optional[jax.Array] = None,  # [slots] int32 stack index
-) -> Tuple[Dict[str, jax.Array], jax.Array]:
-    """One decode step for every slot → (cache', logits [slots, vocab]).
-
-    Inactive slots simply ride along (position 0, result discarded by the
-    batcher) — the executable never depends on which lanes are live, so
-    joining and retiring sequences costs zero recompiles.
-    """
-    slots = tokens.shape[0]
-    max_seq = cache["k"].shape[2]
-    dt = cfg.dtype
-    if adapters is None:
-        x = _embed_tokens(params, tokens[:, None], cfg, rules, dt)
-    else:
-        x = _embed_adapter(adapters, slot_adapters, tokens, dt)[:, None]
-    pos_emb = jnp.take(params["wpe"].astype(dt), positions, axis=0)
-    x = x + pos_emb[:, None]
-    x = shard_logical(x, ("batch", "seq", "embed"), rules)
-    lane = jnp.arange(slots)
-    # index <= position admits the prompt, every prior decode step, and the
-    # K/V this very step writes — never a stale lane byte.
-    mask = jnp.arange(max_seq)[None] <= positions[:, None]  # [slots, max_seq]
-
-    def body(carry, layer_in):
-        xx = carry  # [slots, 1, D]
-        lp, k_lane, v_lane = layer_in
-        y = _layer_norm(xx, lp["ln1"]["scale"], lp["ln1"]["bias"],
-                        cfg.layer_norm_eps)
-        q, k, v = _qkv(y, lp, cfg)  # [slots, 1, H, Dh]
-        k_lane = k_lane.at[lane, positions].set(
-            k[:, 0].astype(k_lane.dtype))
-        v_lane = v_lane.at[lane, positions].set(
-            v[:, 0].astype(v_lane.dtype))
-        scale = 1.0 / math.sqrt(cfg.head_dim)
-        logits = jnp.einsum(
-            "bhd,bmhd->bhm", q[:, 0], k_lane).astype(jnp.float32)
-        logits = jnp.where(mask[:, None], logits * scale,
-                           jnp.finfo(jnp.float32).min)
-        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-        attn = jnp.einsum("bhm,bmhd->bhd", probs, v_lane)
-        attn = attn.reshape(slots, 1, -1)
-        attn = (jnp.einsum("bsd,de->bse", attn,
-                           lp["attn_out"]["kernel"].astype(dt))
-                + lp["attn_out"]["bias"].astype(dt))
-        xx = xx + attn
-        y = _layer_norm(xx, lp["ln2"]["scale"], lp["ln2"]["bias"],
-                        cfg.layer_norm_eps)
-        xx = xx + _mlp(y, lp, cfg, rules)
-        return xx, (k_lane, v_lane)
-
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["blocks"], cache["k"], cache["v"]))
-    if adapters is None:
-        logits = _finish(params, x, cfg, rules)  # [slots, 1, V]
-    else:
-        logits = _finish_adapter(params, x, adapters, slot_adapters, cfg,
-                                 rules)
-    return {"k": new_k, "v": new_v}, logits[:, 0].astype(jnp.float32)
 
 
 # ---------------------------------------------------------------- paged
@@ -482,11 +313,12 @@ def paged_decode_step(
 ) -> Tuple[Dict[str, jax.Array], jax.Array]:
     """One paged decode step for every slot → (cache', logits [slots, V]).
 
-    The dense decode's lane write/attend becomes a block write (table
-    lookup of `position // block_size`) + a block-table-gathered
-    attention (ops/paged_attention). Inactive slots write the trash block
-    and attend garbage the batcher discards — zero recompiles to join or
-    retire, exactly like the dense path.
+    Each slot's new K/V is a block write (table lookup of
+    `position // block_size`), then a block-table-gathered attention
+    (ops/paged_attention). Inactive slots ride along: they write the
+    trash block and attend garbage the batcher discards, so the
+    executable never depends on which lanes are live and joining or
+    retiring a sequence costs zero recompiles.
     """
     from determined_tpu.ops.paged_attention import paged_decode_attention
 

@@ -350,20 +350,12 @@ class ContinuousBatcher:
         self.engine = engine
         self.queue = queue or AdmissionQueue()
         bm = block_manager
-        paged = getattr(engine, "paged", False)
         if bm is None:
-            if paged:
-                # Mirror the engine's device pool exactly: tables the
-                # manager hands out index real pool blocks.
-                bm = BlockManager(num_blocks=engine.num_blocks,
-                                  block_size=engine.block_size)
-            else:
-                # Dense layout: accounting-only pool sized to the cache
-                # (slots lanes of max_seq tokens).
-                bm = BlockManager(
-                    num_blocks=engine.slots * max(
-                        1, engine.max_seq_len // 16), block_size=16)
-        elif paged:
+            # Mirror the engine's device pool exactly: tables the
+            # manager hands out index real pool blocks.
+            bm = BlockManager(num_blocks=engine.num_blocks,
+                              block_size=engine.block_size)
+        else:
             # An external manager defines the geometry; sync the device
             # pool to it before compile() freezes the executables.
             engine.set_block_geometry(bm.block_size, bm.num_blocks)
@@ -544,10 +536,9 @@ class ContinuousBatcher:
         A pass that found no live lane notes on `admit` from when it had
         one (`live_from`): from there on its prefills stall somebody.
 
-        Paged engines admit through BlockManager.admit: a prompt whose
-        prefix is cached reuses those blocks (refcounted) and is charged
-        only its novel suffix — prefill then runs only that suffix."""
-        paged = getattr(self.engine, "paged", False)
+        Admission is BlockManager.admit: a prompt whose prefix is cached
+        reuses those blocks (refcounted) and is charged only its novel
+        suffix — prefill then runs only that suffix."""
         ids: List[str] = []
         while True:
             free = [i for i, s in enumerate(self._slots) if s is None]
@@ -556,21 +547,14 @@ class ContinuousBatcher:
             req = self.queue.peek()
             if req is None:
                 return ids
-            cached_len = 0
-            cow_pairs = ()
             with trace.phase("serve.admit.blocks", request=req.id) as grant:
-                if paged:
-                    admitted = self.blocks.admit(
-                        req.id, req.tokens.tolist(), req.total_budget)
-                    if admitted is not None:
-                        table, cached_len, cow_pairs = admitted
-                        grant.set(cached_len=cached_len)
-                else:
-                    table = admitted = self.blocks.allocate(
-                        req.id, req.total_budget)
+                admitted = self.blocks.admit(
+                    req.id, req.tokens.tolist(), req.total_budget)
                 if admitted is None:
                     grant.cancel()
                     return ids  # pool exhausted: wait for a retire
+                table, cached_len, cow_pairs = admitted
+                grant.set(cached_len=cached_len)
             popped = self.queue.pop()
             assert popped is req, "single-consumer queue invariant"
             slot_id = free[0]
@@ -578,9 +562,7 @@ class ContinuousBatcher:
             req.admitted_us = now_us()
             req.cached_len = cached_len
             req.occupancy_at_admit = self.engine.slots - len(free) + 1
-            req.blocks_allocated = (
-                len(table) if paged
-                else self.blocks.blocks_for_tokens(req.total_budget))
+            req.blocks_allocated = len(table)
             req.bucket = self.engine.bucket_for(
                 int(req.tokens.size) - cached_len) or 0
             req.prefill_start_us = req.admitted_us
@@ -597,15 +579,10 @@ class ContinuousBatcher:
                                      pairs=len(cow_pairs)):
                         for src, dst in cow_pairs:
                             self.engine.copy_block(src, dst)
-                if paged:
-                    first = self.engine.prefill_request(
-                        slot_id, req.tokens, req.temperature,
-                        block_table=table, cached_len=cached_len,
-                        adapter=adapter, request_id=req.id)
-                else:
-                    first = self.engine.prefill_request(
-                        slot_id, req.tokens, req.temperature,
-                        adapter=adapter, request_id=req.id)
+                first = self.engine.prefill_request(
+                    slot_id, req.tokens, req.temperature,
+                    block_table=table, cached_len=cached_len,
+                    adapter=adapter, request_id=req.id)
             except Exception as e:
                 # discard=True: the blocks' K/V were never (fully)
                 # written; they must not linger in the prefix cache.

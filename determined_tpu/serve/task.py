@@ -235,17 +235,10 @@ def build_replica(config: Dict[str, Any], session=None):
         engine.farm = FarmClient(
             session=session,
             signature=serving_signature(serving, engine.params))
-    if engine.paged:
-        # The device pool IS the budget: the manager mirrors it exactly.
-        blocks = BlockManager(
-            num_blocks=engine.num_blocks, block_size=engine.block_size,
-            prefix_cache=bool(serving.get("prefix_cache", True)))
-    else:
-        blocks = BlockManager(
-            num_blocks=slots * max(1, (engine.max_seq_len + block_size - 1)
-                                   // block_size),
-            block_size=block_size,
-        )
+    # The device pool IS the budget: the manager mirrors it exactly.
+    blocks = BlockManager(
+        num_blocks=engine.num_blocks, block_size=engine.block_size,
+        prefix_cache=bool(serving.get("prefix_cache", True)))
     queue = AdmissionQueue(maxsize=int(serving.get("queue_depth", 64)))
     batcher = ContinuousBatcher(engine, queue=queue, block_manager=blocks)
     return engine, batcher

@@ -324,8 +324,7 @@ Json preflight_config(const Json& config) {
     int64_t bs = serving["kv_block_size"].as_int(16);
     int64_t max_seq = serving["max_seq_len"].as_int(256);
     int64_t nb = serving["kv_num_blocks"].as_int(0);
-    const std::string impl = serving["attention_impl"].as_string("auto");
-    if (impl != "dense" && bs > 0 && max_seq > 0) {
+    if (bs > 0 && max_seq > 0) {
       if (max_seq % bs != 0) {
         out.push_back(diag(
             "DTL206", "error",

@@ -676,13 +676,16 @@ static void test_preflight_serving_kv_geometry() {
   CHECK_EQ(d.as_array().size(), static_cast<size_t>(1));
   CHECK_EQ(d.as_array()[0]["code"].as_string(), "DTL206");
 
-  // Enough blocks -> clean. Dense layout -> geometry rules moot.
+  // Enough blocks -> clean. No attention_impl exempts a geometry: the
+  // refused spelling of the deleted slot-dense layout fires like any.
   cfg["serving"]["kv_num_blocks"] = static_cast<int64_t>(16);  // 256
   CHECK(det::preflight_config(cfg).as_array().empty());
   cfg["serving"]["kv_num_blocks"] = static_cast<int64_t>(8);
   cfg["serving"]["kv_block_size"] = static_cast<int64_t>(24);
   cfg["serving"]["attention_impl"] = "dense";
-  CHECK(det::preflight_config(cfg).as_array().empty());
+  d = det::preflight_config(cfg);
+  CHECK_EQ(d.as_array().size(), static_cast<size_t>(1));
+  CHECK_EQ(d.as_array()[0]["code"].as_string(), "DTL206");
 
   // Defaults (no explicit keys) never fire: 16 divides 256.
   Json clean = Json::object();

@@ -14,7 +14,10 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("KERAS_BACKEND", "jax")  # Keras 3 on the JAX backend
 
 import atexit  # noqa: E402
+import contextlib  # noqa: E402
+import fcntl  # noqa: E402
 import shutil  # noqa: E402
+import subprocess  # noqa: E402
 import tempfile  # noqa: E402
 
 import jax  # noqa: E402
@@ -40,6 +43,34 @@ atexit.register(shutil.rmtree, _runtime.DEFAULT_CACHE_DIR, ignore_errors=True)
 def checkout_cache_dir():
     """What `DEFAULT_CACHE_DIR` is outside the test session."""
     return _CHECKOUT_CACHE_DIR
+
+
+NATIVE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native")
+
+
+@contextlib.contextmanager
+def native_build_lock():
+    """Hold the one lock every `make -C native` of a test session runs
+    under. Each xdist worker is a session of its own, so without it six
+    builds write `native/bin/` at once, each linker over binaries the
+    others already exec. The file lives in `native/bin/` (git-ignored,
+    removed by `make clean`); closing it releases the lock."""
+    os.makedirs(os.path.join(NATIVE, "bin"), exist_ok=True)
+    with open(os.path.join(NATIVE, "bin", ".build.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        yield
+
+
+@pytest.fixture(scope="session")
+def native_binaries():
+    """`native/bin/` with master, agent and searcher_sim built: one worker
+    compiles (by objects, on every core), the others find a no-op."""
+    with native_build_lock():
+        subprocess.run(
+            ["make", "-C", NATIVE, f"-j{os.cpu_count()}"], check=True,
+            capture_output=True)
+    return os.path.join(NATIVE, "bin")
 
 
 @pytest.fixture(scope="session")

@@ -13,15 +13,14 @@ import urllib.error
 
 import pytest
 
-from tests.test_platform_e2e import (  # noqa: F401
+from tests.test_platform_e2e import (
     Devcluster,
     _experiment_config,
-    native_binaries,
 )
 
 
 @pytest.fixture()
-def cluster(tmp_path, native_binaries):  # noqa: F811
+def cluster(tmp_path, native_binaries):
     # Master only — authz checks don't need a running agent.
     c = Devcluster(str(tmp_path), native_binaries)
     c.start_master()

@@ -5,7 +5,7 @@ pytorch/dsat/_dsat_search_method.py workflow)."""
 import pytest
 
 from determined_tpu.autotune import BatchSizeSearchMethod
-from tests.test_platform_e2e import Devcluster, native_binaries  # noqa: F401
+from tests.test_platform_e2e import Devcluster
 
 
 class TestSearchLogic:
@@ -92,7 +92,7 @@ class TestSearchLogic:
 
 
 @pytest.fixture()
-def cluster(tmp_path, native_binaries):  # noqa: F811
+def cluster(tmp_path, native_binaries):
     c = Devcluster(str(tmp_path), native_binaries)
     c.start_master()
     c.start_agent()

@@ -20,10 +20,9 @@ import numpy as np
 import optax
 import pytest
 
-from test_platform_e2e import (  # noqa: F401  (fixture re-export)
+from test_platform_e2e import (
     Devcluster,
     _wait_experiment,
-    native_binaries,
 )
 
 import jax

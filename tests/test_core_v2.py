@@ -8,11 +8,11 @@ import numpy as np
 import pytest
 
 from determined_tpu.experimental import core_v2
-from tests.test_platform_e2e import Devcluster, native_binaries  # noqa: F401
+from tests.test_platform_e2e import Devcluster
 
 
 @pytest.fixture()
-def cluster(tmp_path, native_binaries):  # noqa: F811
+def cluster(tmp_path, native_binaries):
     c = Devcluster(str(tmp_path), native_binaries)
     c.start_master()  # NOTE: no agent — unmanaged runs need none
     yield c

@@ -10,8 +10,6 @@ import time
 import urllib.error
 import urllib.request
 
-from tests.test_platform_e2e import native_binaries  # noqa: F401
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -35,7 +33,7 @@ def _cli(home, *args, timeout=120):
         capture_output=True, text=True, env=env, timeout=timeout)
 
 
-def test_deploy_local_tls_lifecycle(tmp_path, native_binaries):  # noqa: F811
+def test_deploy_local_tls_lifecycle(tmp_path, native_binaries):
     home = tmp_path / "home"
     home.mkdir()
     port = _free_port()

@@ -29,9 +29,8 @@ import urllib.request
 
 import pytest
 
-from tests.test_platform_e2e import (  # noqa: F401  (fixture re-export)
+from tests.test_platform_e2e import (
     Devcluster,
-    native_binaries,
 )
 
 from determined_tpu import expconf
@@ -205,7 +204,7 @@ def _dep_config(min_r=1, max_r=4, target=2, heartbeat_s=0.3, **rep_extra):
 
 
 @pytest.fixture()
-def master_only(tmp_path, native_binaries):  # noqa: F811
+def master_only(tmp_path, native_binaries):
     c = Devcluster(str(tmp_path), native_binaries)
     c.start_master()
     yield c
@@ -213,7 +212,7 @@ def master_only(tmp_path, native_binaries):  # noqa: F811
 
 
 @pytest.fixture()
-def fleet(tmp_path, native_binaries):  # noqa: F811
+def fleet(tmp_path, native_binaries):
     c = Devcluster(str(tmp_path), native_binaries, slots=4)
     c.start_master()
     c.start_agent()
@@ -703,7 +702,7 @@ def test_breaker_ignores_starting_replica_refusals(fleet):
     assert status == 200, (status, body)
 
 
-def test_spot_placement_floor_and_drain_retarget(tmp_path, native_binaries):  # noqa: F811
+def test_spot_placement_floor_and_drain_retarget(tmp_path, native_binaries):
     """Spot-aware serving (docs/cluster-ops.md "Capacity loop"): the
     on_demand_floor replica lands on non-preemptible capacity, the
     surplus replica lands on the spot agent first; a PR-5 preemption
@@ -1376,7 +1375,7 @@ def test_lifecycle_expconf_and_create_gate(master_only):
 
 
 @pytest.mark.slow
-def test_deployment_lifecycle_real_replicas_e2e(tmp_path, native_binaries):  # noqa: F811
+def test_deployment_lifecycle_real_replicas_e2e(tmp_path, native_binaries):
     """Scale-up under real load, scale-down via drain, zero dropped — with
     real engines serving a real checkpoint through the router."""
     import jax

@@ -31,7 +31,6 @@ from test_platform_e2e import (  # noqa: F401  (fixture re-export)
     _create_experiment,
     _experiment_config,
     _wait_experiment,
-    native_binaries,
 )
 from test_preemption import (  # noqa: F401
     _ScriptedSession,

@@ -28,15 +28,6 @@ def cluster(tmp_path, native_binaries):
     c.stop()
 
 
-@pytest.fixture(scope="session")
-def native_binaries():
-    subprocess.run(
-        ["make", "-C", os.path.join(REPO, "native")], check=True,
-        capture_output=True,
-    )
-    return os.path.join(REPO, "native", "bin")
-
-
 def _cli(cluster, *args, timeout=300):
     env = dict(
         os.environ,

@@ -423,6 +423,14 @@ class TestServingBlock:
         errs = expconf.validate(self._config(kv_num_blocks=0))
         assert any("kv_num_blocks" in e for e in errs)
 
+    def test_serving_attention_impl_dense_is_refused(self):
+        """The slot-dense cache is deleted: its value is refused at
+        validation, with the three that exist named."""
+        errs = expconf.validate(self._config(attention_impl="dense"))
+        assert [e for e in errs if "attention_impl" in e] == [
+            "serving.attention_impl must be one of: auto, pallas, "
+            "reference"]
+
     def test_serving_must_be_mapping(self):
         errs = expconf.validate({"name": "x", "serving": "yes"})
         assert any("serving must be a mapping" in e for e in errs)

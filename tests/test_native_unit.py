@@ -23,16 +23,17 @@ import subprocess
 import tempfile
 
 import pytest
+from conftest import NATIVE, native_build_lock
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-NATIVE = os.path.join(REPO, "native")
+REPO = os.path.dirname(NATIVE)
 
 
-def _make(target: str, timeout: int = 600) -> subprocess.CompletedProcess:
-    return subprocess.run(
-        ["make", "-C", NATIVE, target],
-        capture_output=True, text=True, timeout=timeout,
-    )
+def _make(*args: str, timeout: int = 600) -> subprocess.CompletedProcess:
+    with native_build_lock():
+        return subprocess.run(
+            ["make", "-C", NATIVE, f"-j{os.cpu_count()}", *args],
+            capture_output=True, text=True, timeout=timeout,
+        )
 
 
 def _run(binary: str, env=None) -> subprocess.CompletedProcess:
@@ -63,6 +64,26 @@ def test_native_units():
     r = _make("test")
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
     assert "0 failures" in r.stdout
+
+
+def test_each_source_is_compiled_once_for_every_binary(native_binaries):
+    """The build is by objects: built, `make all` has nothing to do; a
+    touched `common/trace.cc` (which master and agent both hold) is one
+    compile, and the rest is links."""
+    r = _make("-q", "all")
+    assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
+    r = _make("-n", "-W", "common/trace.cc", "all")
+    assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
+    cxx = os.environ.get("CXX", "g++")
+    steps = [ln for ln in r.stdout.splitlines() if ln.startswith(cxx + " ")]
+    compiles = [ln for ln in steps if " -c " in ln]
+    assert len(compiles) == 1 and compiles[0].endswith(
+        " -c -o bin/obj/common/trace.o common/trace.cc"), r.stdout
+    links = [ln.split(" -o ")[1].split()[0] for ln in steps
+             if ln not in compiles]
+    assert sorted(links) == ["bin/determined-agent",
+                             "bin/determined-master"], r.stdout
+    assert not [ln for ln in steps if ".cc" in ln and ln not in compiles]
 
 
 def _sanitized_unit(flag: str, binary: str, env=None):

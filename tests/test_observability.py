@@ -21,9 +21,8 @@ from determined_tpu.common import metric_names
 from determined_tpu.common.trace import Tracer, now_us, render_waterfall
 from determined_tpu.core._profiler import PEAK_BF16_FLOPS, ProfilerContext
 from determined_tpu.core._train import TrainContext
-from tests.test_platform_e2e import (  # noqa: F401
+from tests.test_platform_e2e import (
     Devcluster,
-    native_binaries,
     _create_experiment,
     _experiment_config,
     _free_port,
@@ -100,7 +99,7 @@ class TestProfilerUtilization:
         ctx.close()
 
 
-def test_master_metrics_endpoint(tmp_path, native_binaries):  # noqa: F811
+def test_master_metrics_endpoint(tmp_path, native_binaries):
     c = Devcluster(str(tmp_path), native_binaries)
     c.start_master()
     try:
@@ -500,7 +499,7 @@ def test_trainer_fit_unchanged_with_tracing_off(tmp_path, monkeypatch):
 
 
 @pytest.fixture()
-def master_only(tmp_path, native_binaries):  # noqa: F811
+def master_only(tmp_path, native_binaries):
     c = Devcluster(str(tmp_path), native_binaries)
     c.start_master()
     yield c
@@ -639,7 +638,7 @@ def test_span_ingest_bumps_counter_and_replay_cache_metric(master_only):
     assert len(trace["spans"]) == 1
 
 
-def test_agent_metrics_endpoint(tmp_path, native_binaries):  # noqa: F811
+def test_agent_metrics_endpoint(tmp_path, native_binaries):
     """Every agent serves its own /metrics (docs/observability.md): task
     states, log backlog, drain state — parseable Prometheus text."""
     c = Devcluster(str(tmp_path), native_binaries)
@@ -690,7 +689,7 @@ def _span_map(trace):
 
 
 @pytest.mark.slow
-def test_trace_e2e_full_waterfall(tmp_path, native_binaries):  # noqa: F811
+def test_trace_e2e_full_waterfall(tmp_path, native_binaries):
     """A devcluster trial yields a complete waterfall: queue-wait,
     container-start, compile, ≥1 checkpoint commit — correct parentage,
     non-overlapping phase accounting — and `det trial trace` renders it."""
@@ -759,7 +758,7 @@ def test_trace_e2e_full_waterfall(tmp_path, native_binaries):  # noqa: F811
 
 
 @pytest.mark.slow
-def test_trace_e2e_emergency_span_under_drain(tmp_path, native_binaries):  # noqa: F811
+def test_trace_e2e_emergency_span_under_drain(tmp_path, native_binaries):
     """Under a notice-file drain the emergency-checkpoint span lands on
     the trace (flushed before the exit), and the restarted run adds a
     harness.restore span on the survivor."""

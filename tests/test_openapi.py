@@ -18,7 +18,7 @@ import urllib.request
 
 import pytest
 
-from tests.test_platform_e2e import Devcluster, native_binaries  # noqa: F401
+from tests.test_platform_e2e import Devcluster
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SPEC_PATH = os.path.join(REPO, "proto", "openapi.json")
@@ -31,7 +31,7 @@ def spec():
 
 
 @pytest.fixture()
-def cluster(tmp_path, native_binaries):  # noqa: F811
+def cluster(tmp_path, native_binaries):
     c = Devcluster(str(tmp_path), native_binaries)
     c.start_master()
     yield c

@@ -23,9 +23,8 @@ import urllib.request
 
 import pytest
 
-from test_platform_e2e import (  # noqa: F401  (fixture re-export)
+from test_platform_e2e import (
     Devcluster,
-    native_binaries,
 )
 
 

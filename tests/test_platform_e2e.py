@@ -19,7 +19,6 @@ import urllib.request
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-NATIVE_BIN = os.path.join(REPO, "native", "bin")
 FIXTURES = os.path.join(REPO, "tests", "fixtures", "platform")
 
 
@@ -38,15 +37,6 @@ def _wait_http(url: str, timeout: float = 20.0) -> None:
         except Exception:
             time.sleep(0.2)
     raise TimeoutError(f"server at {url} did not come up")
-
-
-@pytest.fixture(scope="session")
-def native_binaries():
-    subprocess.run(
-        ["make", "-C", os.path.join(REPO, "native")], check=True,
-        capture_output=True,
-    )
-    return NATIVE_BIN
 
 
 class Devcluster:

@@ -550,12 +550,8 @@ class TestConfigRules:
         assert check_config(c) == []
 
     def test_dtl206_negative(self):
-        # Defaults (16 | 256) are clean; dense layout is exempt — the
-        # dense cache has no block tables to tile.
+        # Defaults (16 | 256) are clean.
         assert check_config({"serving": {"checkpoint": "latest"}}) == []
-        c = {"serving": {"checkpoint": "latest", "kv_block_size": 24,
-                         "max_seq_len": 256, "attention_impl": "dense"}}
-        assert check_config(c) == []
         # Non-serving configs never fire it.
         assert "DTL206" not in codes(check_config(_config()))
 

@@ -18,13 +18,12 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 import pytest
 
-from tests.test_platform_e2e import (  # noqa: F401
+from tests.test_platform_e2e import (
     Devcluster,
     _create_experiment,
     _experiment_config,
     _wait_experiment,
     _wait_http,
-    native_binaries,
 )
 
 
@@ -134,7 +133,7 @@ def _wait(cond, timeout=45, what="condition"):
 
 
 @pytest.fixture()
-def prov_cluster(tmp_path, native_binaries):  # noqa: F811
+def prov_cluster(tmp_path, native_binaries):
     fake = FakeTpuApi()
     cfg = {
         # Must exceed the agent's 10s heartbeat period or live agents flap
@@ -299,7 +298,7 @@ def test_spot_interruption_fails_over(prov_cluster, tmp_path):
 
 
 def test_never_joined_node_cleaned_up_and_capacity_refired(
-        tmp_path, native_binaries):  # noqa: F811
+        tmp_path, native_binaries):
     """A created node whose agent never registers must stop suppressing
     scale-up after boot_grace_seconds and be deleted as broken — not
     starve the queue forever."""
@@ -375,7 +374,7 @@ def _prov_master(tmp_path, native_binaries, fake, prov_extra=None):
 
 
 def test_create_failure_storm_backs_off_and_recovers(
-        tmp_path, native_binaries):  # noqa: F811
+        tmp_path, native_binaries):
     """A 100%-node-create-failure storm must NOT busy-loop: attempts space
     out on the capped exponential backoff (base * 2^(n-1)), the failure
     counter climbs, and clearing the storm recovers — the next attempt
@@ -432,7 +431,7 @@ def test_create_failure_storm_backs_off_and_recovers(
         fake.stop()
 
 
-def test_create_fault_point_runtime_armed(tmp_path, native_binaries):  # noqa: F811
+def test_create_fault_point_runtime_armed(tmp_path, native_binaries):
     """`provisioner.create.fail` (DET_FAULTS / debug API): armed with a
     count, it eats exactly that many create attempts inside the master —
     the fake API never sees them — then auto-disarms and the pool
@@ -482,7 +481,7 @@ def test_create_fault_point_runtime_armed(tmp_path, native_binaries):  # noqa: F
 
 
 def test_deployment_deficit_drives_provisioning(
-        tmp_path, native_binaries):  # noqa: F811
+        tmp_path, native_binaries):
     """ROADMAP item 3 / the capacity loop: a deployment's replica deficit
     — NOT just queued training slots — summons nodes, labeled under
     demand source "serving"; when the deployment dies, the fleet shrinks
@@ -545,7 +544,7 @@ def test_deployment_deficit_drives_provisioning(
 
 
 def test_elastic_demand_counts_min_size_and_trial_starts_shrunk(
-        tmp_path, native_binaries):  # noqa: F811
+        tmp_path, native_binaries):
     """A queued elastic trial demands its MIN size, not its preferred
     size: the provisioner summons one min-sized node (not preferred/
     slots_per_node nodes), and the scheduler STARTS the trial shrunk onto
@@ -597,7 +596,7 @@ def test_elastic_demand_counts_min_size_and_trial_starts_shrunk(
         fake.stop()
 
 
-def test_master_restart_adopts_provisioned_nodes(tmp_path, native_binaries):  # noqa: F811
+def test_master_restart_adopts_provisioned_nodes(tmp_path, native_binaries):
     """Master restart must not orphan provisioned TPU-VMs: the reconcile
     pass adopts listed nodes with our prefix, so idle scale-down still
     happens and new launches can't collide with existing names."""

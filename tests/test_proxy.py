@@ -8,11 +8,11 @@ import urllib.request
 
 import pytest
 
-from tests.test_platform_e2e import Devcluster, native_binaries  # noqa: F401
+from tests.test_platform_e2e import Devcluster
 
 
 @pytest.fixture()
-def cluster(tmp_path, native_binaries):  # noqa: F811
+def cluster(tmp_path, native_binaries):
     c = Devcluster(str(tmp_path), native_binaries)
     c.start_master()
     c.start_agent()

@@ -20,14 +20,13 @@ from tests.test_platform_e2e import (  # noqa: F401
     _create_experiment,
     _experiment_config,
     _wait_experiment,
-    native_binaries,
 )
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture()
-def cluster(tmp_path, native_binaries):  # noqa: F811
+def cluster(tmp_path, native_binaries):
     c = Devcluster(str(tmp_path), native_binaries)
     c.start_master()
     c.start_agent()

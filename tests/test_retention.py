@@ -15,12 +15,11 @@ from tests.test_platform_e2e import (  # noqa: F401
     _create_experiment,
     _experiment_config,
     _wait_experiment,
-    native_binaries,
 )
 
 
 @pytest.fixture()
-def cluster(tmp_path, native_binaries):  # noqa: F811
+def cluster(tmp_path, native_binaries):
     c = Devcluster(str(tmp_path), native_binaries)
     c.start_master()
     c.start_agent()

@@ -17,10 +17,9 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 import pytest
 
-from tests.test_platform_e2e import (  # noqa: F401
+from tests.test_platform_e2e import (
     Devcluster,
     _wait_http,
-    native_binaries,
 )
 
 

@@ -22,12 +22,6 @@ def sim(native_binaries):
     return SIM
 
 
-@pytest.fixture(scope="session")
-def native_binaries():
-    subprocess.run(["make", "-C", os.path.join(REPO, "native")], check=True,
-                   capture_output=True)
-
-
 def run_sim(sim, searcher, hparams=None, seed=7, **kwargs):
     payload = {
         "searcher": searcher,

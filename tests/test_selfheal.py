@@ -617,19 +617,16 @@ def test_chaos_killed_mid_commit_falls_back(tmp_path):
 
 
 @pytest.mark.slow
-def test_chaos_step_hang_watchdog_restart_e2e(tmp_path):
+def test_chaos_step_hang_watchdog_restart_e2e(tmp_path, native_binaries):
     """Acceptance: an injected step.hang produces an all-thread stack dump
     in the task log, a distinct exit reason, and a scheduler-driven
     restart that completes the trial."""
     import sqlite3
 
     from test_platform_e2e import Devcluster, _create_experiment, \
-        _experiment_config, _wait_experiment, native_binaries  # noqa: F401
-    binaries = os.path.join(REPO, "native", "bin")
-    subprocess.run(["make", "-C", os.path.join(REPO, "native")], check=True,
-                   capture_output=True)
+        _experiment_config, _wait_experiment
 
-    c = Devcluster(str(tmp_path), binaries)
+    c = Devcluster(str(tmp_path), native_binaries)
     c.start_master()
     c.start_agent()
     try:

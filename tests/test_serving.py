@@ -289,45 +289,31 @@ def test_engine_leaves_a_tree_no_wider_than_the_serving_dtype(
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
-@pytest.mark.parametrize("layout", ["paged", "dense"])
 def test_resident_weights_serve_bit_for_bit_what_the_f32_tree_does(
-        tiny_params, layout):
+        tiny_params):
     """The engine's calls on its narrowed tree give the very logits (and
     so the tokens) of the step functions called with the float32 tree,
     whose `.astype(cfg.dtype)` rounds the same values inside the call."""
     from determined_tpu.serve import model as smodel
 
     cfg = TINY_BF16
-    paged = layout == "paged"
-    eng = _small_engine(tiny_params, cfg,
-                        attention_impl="reference" if paged else "dense")
+    eng = _small_engine(tiny_params, cfg, attention_impl="reference")
     eng.compile()
     prompt = np.array([5, 9, 17, 3], np.int32)
     padded = np.zeros((8,), np.int32)
     padded[:4] = prompt
-    length, slot = np.int32(4), np.int32(0)
+    length = np.int32(4)
     table = jnp.asarray([0, 1], jnp.int32)
     tables = jnp.asarray([[0, 1], [2, 2]], jnp.int32)
-    if paged:
-        cache = smodel.init_paged_cache(cfg, eng.num_blocks + 1, 8)
-        pf = jax.jit(lambda p, c, t: smodel.paged_prefill(
-            p, c, t, length, np.int32(0), table, cfg))
-        dec = jax.jit(lambda p, c, t, pos: smodel.paged_decode_step(
-            p, c, t, pos, tables, cfg))
-        eng_pf = (padded, length, np.int32(0), table)
-        eng_dec = (tables,)
-    else:
-        cache = smodel.init_cache(cfg, 2, 16)
-        pf = jax.jit(lambda p, c, t: smodel.prefill(
-            p, c, t, length, slot, cfg))
-        dec = jax.jit(lambda p, c, t, pos: smodel.decode_step(
-            p, c, t, pos, cfg))
-        eng_pf = (padded, length, slot)
-        eng_dec = ()
+    cache = smodel.init_paged_cache(cfg, eng.num_blocks + 1, 8)
+    pf = jax.jit(lambda p, c, t: smodel.paged_prefill(
+        p, c, t, length, np.int32(0), table, cfg))
+    dec = jax.jit(lambda p, c, t, pos: smodel.paged_decode_step(
+        p, c, t, pos, tables, cfg))
 
     cache, want = pf(tiny_params, cache, padded)
     eng._cache, got = eng._compiled_prefill[8](
-        eng.params, eng._cache, *eng_pf)
+        eng.params, eng._cache, padded, length, np.int32(0), table)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     tokens = np.zeros((2,), np.int32)
     positions = np.zeros((2,), np.int32)
@@ -336,7 +322,7 @@ def test_resident_weights_serve_bit_for_bit_what_the_f32_tree_does(
         tokens[0], positions[0] = greedy[-1], 4 + step
         cache, want = dec(tiny_params, cache, tokens, positions)
         eng._cache, got = eng._compiled_decode(
-            eng.params, eng._cache, tokens, positions, *eng_dec)
+            eng.params, eng._cache, tokens, positions, tables)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
         greedy.append(int(np.argmax(np.asarray(want)[0])))
 
@@ -593,12 +579,11 @@ def test_batcher_stop_fails_outstanding(tiny_params):
 # ---------------------------------------------------------------------------
 # Paged KV: attention-impl equivalence (ISSUE-11 acceptance) — greedy decode
 # through the paged path (Pallas kernel in interpret mode AND the jnp
-# reference gather) must match dense-cache decode and full-forward
-# gpt2.apply exactly, in f32.
+# reference gather) must match full-forward gpt2.apply exactly, in f32.
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("impl", ["reference", "pallas", "dense"])
+@pytest.mark.parametrize("impl", ["reference", "pallas"])
 def test_attention_impl_greedy_equivalence(tiny_params, kernel_tiny_params,
                                            impl):
     from jax.experimental.pallas import tpu as pltpu
@@ -626,35 +611,43 @@ def test_attention_impl_greedy_equivalence(tiny_params, kernel_tiny_params,
     assert out == reference_greedy(params, prompt, 8, cfg)
 
 
-def test_paged_reference_bitwise_matches_dense_decode(tiny_params):
-    """The jnp gather path does the *same arithmetic* as the dense lane:
-    with block_size dividing max_seq the gathered lane has identical shape
-    and element order, so the decode logits are bit-identical, not merely
-    argmax-identical."""
-    import jax.numpy as jnp
+def test_resolve_attention_impl_refuses_the_deleted_dense_layout():
+    """The slot-dense cache is gone: its spelling is refused like any
+    unknown one, with the three paths that exist named."""
+    from determined_tpu.serve.engine import resolve_attention_impl
 
+    with pytest.raises(ValueError, match="auto, pallas, reference"):
+        resolve_attention_impl("dense", TINY)
+
+
+def test_paged_reference_logits_match_full_forward(tiny_params):
+    """The jnp gather path computes what the training forward does: the
+    paged prefill's logits and the first decode step's, in float32, are
+    `gpt2.apply`'s at the same positions. They differ only in how the sums
+    are ordered (a softmax over a whole masked lane of 32 against a causal
+    one of five): read on the CPU (PR 30) the largest difference was
+    5.96e-8 at the prefill and 4.47e-8 at the decode step, on logits of
+    magnitude up to 0.6 — a few float32 ulps — and is held here to 1e-6."""
     from determined_tpu.serve import model as smodel
 
     prompt = np.array([5, 9, 17, 3], np.int32)
-    # Dense: prefill + one decode, capture logits.
-    dcache = smodel.init_cache(TINY, 1, 32)
-    dcache, dlog = smodel.prefill(
-        tiny_params, dcache, jnp.asarray(prompt), jnp.int32(4),
-        jnp.int32(0), TINY)
-    tok = jnp.argmax(dlog).astype(jnp.int32)
-    dcache, dstep = smodel.decode_step(
-        tiny_params, dcache, tok[None], jnp.asarray([4], jnp.int32), TINY)
-    # Paged reference: same prompt through the paged layout (bs=8 -> 4
-    # blocks tile max_seq 32 exactly).
-    pcache = smodel.init_paged_cache(TINY, 5, 8)  # 4 blocks + trash
+    # bs=8 -> 4 blocks tile max_seq 32 exactly; the fifth is the trash.
+    pcache = smodel.init_paged_cache(TINY, 5, 8)
     table = jnp.asarray([0, 1, 2, 3], jnp.int32)
     pcache, plog = smodel.paged_prefill(
         tiny_params, pcache, jnp.asarray(prompt), jnp.int32(4),
         jnp.int32(0), table, TINY)
+    tok = jnp.argmax(plog).astype(jnp.int32)
     pcache, pstep = smodel.paged_decode_step(
         tiny_params, pcache, tok[None], jnp.asarray([4], jnp.int32),
         table[None], TINY, attention_impl="reference")
-    assert np.array_equal(np.asarray(dstep), np.asarray(pstep))
+    ctx = jnp.asarray([list(prompt) + [int(tok)]], jnp.int32)
+    full = gpt2.apply(tiny_params, ctx, TINY)[0].astype(jnp.float32)
+    assert plog.dtype == pstep.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(plog), np.asarray(full[3]),
+                               rtol=0, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(pstep[0]), np.asarray(full[4]),
+                               rtol=0, atol=1e-6)
 
 
 @pytest.mark.parametrize("nh,dh", [(2, 64), (8, 32), (2, 128), (20, 64)],
@@ -713,29 +706,25 @@ def test_kernel_geometry_auto_falls_back_and_explicit_pallas_raises(
     assert resolve_attention_impl("auto", KERNEL_TINY) == "reference"
 
 
-def test_paged_decode_across_admissions_matches_dense(tiny_params):
+def test_paged_decode_across_admissions_matches_full_forward(tiny_params):
     """The suite's oracle over the batcher's whole life: greedy requests
-    admitted and retired in waves through two slots give the same tokens
-    from the carried, in-place pool as from the slot-dense cache."""
+    admitted and retired in waves through two slots give, from the
+    carried, in-place pool, the tokens the full forward generates."""
     prompts = [np.arange(1, 1 + n, dtype=np.int32) * 3 % 120 + 1
                for n in (3, 9, 5, 12, 4, 7)]
     lengths = [6, 3, 8, 4, 5, 2]
-    served = {}
-    for impl in ("reference", "dense"):
-        eng = ServingEngine(tiny_params, TINY, slots=2, max_seq_len=32,
-                            prefill_buckets=[8, 16], attention_impl=impl,
-                            kv_block_size=8)
-        b = make_batcher(eng).start()
-        try:
-            reqs = [b.submit(Request(p, max_new_tokens=n))
-                    for p, n in zip(prompts, lengths)]
-            served[impl] = [r.result(timeout=60)["tokens"] for r in reqs]
-        finally:
-            b.stop()
-    assert served["reference"] == served["dense"]
-    assert [len(t) for t in served["dense"]] == lengths
-    assert served["dense"][3] == reference_greedy(
-        tiny_params, prompts[3], lengths[3])
+    eng = ServingEngine(tiny_params, TINY, slots=2, max_seq_len=32,
+                        prefill_buckets=[8, 16], attention_impl="reference",
+                        kv_block_size=8)
+    b = make_batcher(eng).start()
+    try:
+        reqs = [b.submit(Request(p, max_new_tokens=n))
+                for p, n in zip(prompts, lengths)]
+        served = [r.result(timeout=60)["tokens"] for r in reqs]
+    finally:
+        b.stop()
+    assert served == [reference_greedy(tiny_params, p, n)
+                      for p, n in zip(prompts, lengths)]
 
 
 # ---------------------------------------------------------------------------
@@ -1571,18 +1560,13 @@ class TestLifecycleBitIdentity:
 
 
 @pytest.mark.slow
-def test_serve_drain_reschedule_e2e(tmp_path):
+def test_serve_drain_reschedule_e2e(tmp_path, native_binaries):
     """Acceptance: a serve replica under load receives a spot notice —
     it stops admitting, finishes every in-flight sequence inside the
     grace window (zero dropped), exits cleanly, and the master
     reschedules it onto the surviving agent (restarts >= 1, fresh proxy
     address, serving again)."""
-    from tests.test_platform_e2e import NATIVE_BIN, Devcluster
-    import subprocess
-
-    subprocess.run(["make", "-C", os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "native")], check=True, capture_output=True)
+    from tests.test_platform_e2e import Devcluster
 
     # A checkpoint to serve. The tiny model must match the serve config;
     # TINY here uses n_positions=64 to cover seq_len.
@@ -1599,7 +1583,7 @@ def test_serve_drain_reschedule_e2e(tmp_path):
     ctx.checkpoint.wait()
     ctx.close()
 
-    c = Devcluster(str(tmp_path), NATIVE_BIN, slots=1)
+    c = Devcluster(str(tmp_path), native_binaries, slots=1)
     c.start_master()
     notice_files = {}
     for agent_id in ("serve-a", "serve-b"):

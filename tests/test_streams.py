@@ -8,17 +8,16 @@ import pytest
 
 from determined_tpu.common.api import Session
 from determined_tpu.common.streams import StreamClient
-from tests.test_platform_e2e import (  # noqa: F401
+from tests.test_platform_e2e import (
     Devcluster,
     _create_experiment,
     _experiment_config,
     _wait_experiment,
-    native_binaries,
 )
 
 
 @pytest.fixture()
-def cluster(tmp_path, native_binaries):  # noqa: F811
+def cluster(tmp_path, native_binaries):
     c = Devcluster(str(tmp_path), native_binaries)
     c.start_master()
     c.start_agent()
@@ -64,7 +63,7 @@ def test_stream_events_during_experiment(cluster, tmp_path):
     assert not client.dropped
 
 
-def test_stream_resync_marker_on_overflow(tmp_path, native_binaries):  # noqa: F811
+def test_stream_resync_marker_on_overflow(tmp_path, native_binaries):
     """Bounded backlog (docs/cluster-ops.md "Overload, quotas & fair
     use"): a slow subscriber whose cursor fell off the capped ring gets a
     synthetic `resync` marker at the head of its next batch (plus the

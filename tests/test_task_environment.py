@@ -13,11 +13,10 @@ import time
 import pytest
 
 from determined_tpu.exec.launch import apply_task_environment
-from tests.test_platform_e2e import (  # noqa: F401
+from tests.test_platform_e2e import (
     FIXTURES,
     Devcluster,
     _wait_experiment,
-    native_binaries,
 )
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -91,7 +90,7 @@ class TestExpconfEnvironmentValidation:
 
 
 @pytest.fixture()
-def cluster(tmp_path, native_binaries):  # noqa: F811
+def cluster(tmp_path, native_binaries):
     c = Devcluster(str(tmp_path), native_binaries)
     c.start_master()
     c.start_agent()

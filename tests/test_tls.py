@@ -15,7 +15,7 @@ import urllib.request
 
 import pytest
 
-from tests.test_platform_e2e import Devcluster, native_binaries  # noqa: F401
+from tests.test_platform_e2e import Devcluster
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -32,7 +32,7 @@ def _gen_cert(tmp_path, cn="127.0.0.1"):
 
 
 @pytest.fixture()
-def tls_cluster(tmp_path, native_binaries):  # noqa: F811
+def tls_cluster(tmp_path, native_binaries):
     cert, key = _gen_cert(tmp_path)
     c = Devcluster(str(tmp_path), native_binaries)
     c.master_url = f"https://127.0.0.1:{c.port}"

@@ -9,12 +9,11 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 import pytest
 
-from tests.test_platform_e2e import (  # noqa: F401
+from tests.test_platform_e2e import (
     Devcluster,
     _create_experiment,
     _experiment_config,
     _wait_experiment,
-    native_binaries,
 )
 
 
@@ -44,7 +43,7 @@ class Sink:
 
 
 @pytest.fixture()
-def cluster(tmp_path, native_binaries):  # noqa: F811
+def cluster(tmp_path, native_binaries):
     c = Devcluster(str(tmp_path), native_binaries)
     c.start_master()
     c.start_agent()
