@@ -170,8 +170,9 @@ def phase_kernels(args, out):
               f"attention_bf16={bf16}: fwd {fwd:.4f} (tol {FWD_TOL}), "
               f"bwd {bwd:.4f} (tol {BWD_TOL})")
 
-    # paged decode: the serve phase's geometry, ragged positions, one
-    # inactive (all-trash) slot, the second of three layers of a pool.
+    # paged decode: the serve phase's geometry, ragged positions on both
+    # sides of a 128-token span's edge, an inactive (all-trash) slot in
+    # the middle and at the end, the second of three layers of a pool.
     slots, bs, mb = 8, 16, 1024 // 16
     rng = np.random.default_rng(1)
     pool = (3, slots * mb + 1, bs, h * d)
@@ -179,13 +180,19 @@ def phase_kernels(args, out):
     kp = jnp.asarray(rng.normal(size=pool), jnp.bfloat16)
     vp = jnp.asarray(rng.normal(size=pool), jnp.bfloat16)
     tbl = rng.permutation(slots * mb).reshape(slots, mb).astype(np.int32)
-    tbl[-1] = slots * mb
-    pos = np.array([0, 5, 15, 16, 300, 777, 1023, 0], np.int32)
+    idle = [3, 7]
+    tbl[idle] = slots * mb
+    pos = np.array([0, 5, 127, 0, 128, 777, 1023, 0], np.int32)
     layer = jnp.int32(1)
-    got = jax.jit(paged_attention_pallas)(q, kp, vp, layer, tbl, pos)
-    want = jax.jit(paged_attention_reference)(q, kp, vp, layer, tbl, pos)
-    err = rel_err(got[:-1], want[:-1])
+    got, want = (np.asarray(jax.jit(attend)(q, kp, vp, layer, tbl, pos),
+                            np.float32)
+                 for attend in (paged_attention_pallas,
+                                paged_attention_reference))
+    live = [lane for lane in range(slots) if lane not in idle]
+    err = rel_err(got[live], want[live])
     out["paged_decode"] = {"rel_err": round(err, 5)}
+    check(not got[idle].any(),
+          "paged decode kernel: an idle lane's row is not zeros")
     check(err <= FWD_TOL,
           f"paged decode kernel vs paged_attention_reference slots {slots} "
           f"H {h} Dh {d} block {bs}: {err:.4f} (tol {FWD_TOL})")

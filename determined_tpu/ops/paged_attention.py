@@ -26,29 +26,51 @@ Two interchangeable implementations (selected by
     (`gpt2.apply`) to a few ulps (tests/test_serving). Fast on CPU; the
     fallback anywhere Pallas is unavailable.
 
-  - `paged_attention_pallas` — the TPU kernel. Grid `(slots,
-    max_blocks)`; the block table, positions and the layer ride
-    `PrefetchScalarGridSpec` scalar prefetch so each program's K/V
-    BlockSpec `index_map` dereferences `(layer, table[s, b])` — the
-    gather IS the pipeline's block fetch, no materialized `[slots,
-    max_seq]` lane ever exists. The inner loop is an online softmax:
-    fp32 running max `m`, normalizer `l`, and accumulator `acc` live in
-    VMEM scratch across the `b` iterations of one slot; the output block
-    is written at the final block index. A block arrives as `[bs, H*Dh]`
-    rows and heads are taken as static lane-aligned slices of it, G
-    groups of W = max(128, Dh) lanes: two heads of 64 share a group, with
-    q laid block-diagonally (`[G, per, W]`, made outside the kernel) so
-    one product gives each head its own logits; nothing is transposed.
-    Both inner products are matmuls batched over the LEADING group dim
-    with 3-D operands (`[G, per, W] x [G, bs, W]`) — the form Mosaic
-    lowers; a 2-D lhs with a batch dim and no non-contracting dim is
-    refused by its dot-dimension parser. A geometry that cannot be cut
-    into such groups (`kernel_refusal`) raises with the reason. Compiles
-    through Mosaic unless the caller passes `interpret=True` (tests).
+  - `paged_attention_pallas` — the TPU kernel. It does work in
+    proportion to the live context. The grid is over lanes, `(slots,)`:
+    one program a lane, and no program per table entry. The block table,
+    positions and the layer ride `PrefetchScalarGridSpec` scalar
+    prefetch; the pools stay where they rest (`pl.ANY`) and the program
+    copies what it needs by hand. It walks the lane's table a **span**
+    at a time — `span_tokens`: `max(1, 128 // block_size)` consecutive
+    logical blocks, 8 blocks = 128 tokens at `block_size` 16, capped at
+    the table's length, read from the shapes and set by nothing else —
+    in a loop that ends at the lane's own position: `position // 128 +
+    1` folds, so a span past the position is neither fetched nor
+    multiplied, and of the last span only the blocks up to the position
+    are copied (`pool[layer, table[s, b]]` → rows `[j*bs, (j+1)*bs)` of
+    a `[span*bs, H*Dh]` VMEM tile: a block is rows, so a span's blocks
+    stack with no transpose). The tile is double-buffered: span i+1 is in
+    flight while span i is folded, and behind a lane's last fold the
+    NEXT live lane's first span is started (two SMEM words carry "which
+    buffer, whose span" from program to program), so no program opens on
+    a cold fetch. **An idle lane** — its table is the trash block
+    throughout and its position 0, which the kernel sees as `table[s, 0]
+    == pool_blocks - 1` — fetches nothing, multiplies nothing and writes
+    a row of zeros. Each fold is one online-softmax step over the span's
+    128 keys: fp32 running max `m`, normalizer `l` and accumulator `acc`
+    in VMEM scratch, the mask `index <= position`. Heads are static
+    lane-aligned slices of the tile, G groups of W = max(128, Dh) lanes:
+    two heads of 64 share a group, with q laid block-diagonally (`[G,
+    per, W]`, made outside the kernel) so one product gives each head its
+    own logits; nothing is transposed. Both inner products are matmuls
+    batched over the LEADING group dim with 3-D operands (`[G, per, W] x
+    [G, span*bs, W]`) — the form Mosaic lowers; a 2-D lhs with a batch
+    dim and no non-contracting dim is refused by its dot-dimension
+    parser. A geometry that cannot be cut into such groups
+    (`kernel_refusal`) raises with the reason. Compiles through Mosaic
+    unless the caller passes `interpret=True` (tests).
+
+`live_spans` is the same arithmetic on the host: the engine sums it into
+`decode_spans_live` / `decode_spans_grid` (`engine.stats()`, `/v1/stats`),
+spans that held a visible key and spans launched, per layer. The loop
+ends at the position, so the two are equal by construction; a grid over
+`(slots, table)` would launch the second whatever the first.
 
 Inactive slots point every table entry at a reserved trash block and sit
-at position 0 — they compute garbage the batcher discards, so the
-executable never depends on which slots are live.
+at position 0: the reference attends garbage there that the batcher
+discards, the kernel writes zeros — either way the executable never
+depends on which slots are live.
 """
 
 from __future__ import annotations
@@ -58,6 +80,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from determined_tpu.ops._pallas_common import (
     HAVE_PALLAS,
@@ -139,42 +162,129 @@ def _lane_groups(n_head: int, head_dim: int) -> Tuple[int, int]:
     return n_head // per, per
 
 
-def _paged_kernel(tbl_ref, pos_ref, lay_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, block_size, scale):
-    del lay_ref  # read by the K/V index maps only
-    s, b = pl.program_id(0), pl.program_id(1)
-    mb = pl.num_programs(1)
+def span_tokens(block_size: int, max_blocks: int) -> int:
+    """Tokens one fold of the kernel's online softmax covers: a span of
+    `max(1, 128 // block_size)` consecutive logical blocks of a lane,
+    capped at the lane's whole table. Read from the shapes alone."""
+    return min(max(1, LANES // block_size), max_blocks) * block_size
+
+
+def live_spans(positions, live, block_size: int, max_blocks: int) -> int:
+    """Spans a decode call folds in one layer: `position // span + 1`
+    for each live lane, none for an idle one (host arithmetic on what the
+    engine already holds; `engine.stats()` sums it)."""
+    tile = span_tokens(block_size, max_blocks)
+    return int(np.sum((np.asarray(positions) // tile + 1)[np.asarray(live)]))
+
+
+def _paged_kernel(tbl_ref, pos_ref, lay_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  kbuf, vbuf, sems, flight, acc_ref, m_ref, l_ref, *,
+                  block_size, span, scale):
+    s, slots = pl.program_id(0), pl.num_programs(0)
     groups, _, width = q_ref.shape[1:]
+    tile = span * block_size
+    trash = k_hbm.shape[1] - 1
+    layer = lay_ref[0]
 
-    @pl.when(b == 0)
-    def _init():
-        init_softmax_scratch(acc_ref, m_ref, l_ref)
+    def spans_of(lane):
+        """Spans that hold a visible key: none for an idle lane, whose
+        table is the trash block throughout."""
+        return jnp.where(tbl_ref[lane, 0] == trash, 0,
+                         pos_ref[lane] // tile + 1)
 
+    def copy_blocks(lane, i, buf, act):
+        """Start or wait for (`act`) the copies of the K and V blocks of
+        span i of `lane` into buffer `buf`, the blocks up to the lane's
+        position and no others: a block is `block_size` rows of the pool,
+        so the span's blocks stack as rows of one tile."""
+        def block(j, _):
+            at = tbl_ref[lane, i * span + j]
+            rows = pl.ds(pl.multiple_of(j * block_size, block_size),
+                         block_size)
+            for which, (hbm, vmem) in enumerate(((k_hbm, kbuf),
+                                                 (v_hbm, vbuf))):
+                act(pltpu.make_async_copy(
+                    hbm.at[layer, at], vmem.at[buf, rows],
+                    sems.at[buf, which]))
+
+        jax.lax.fori_loop(
+            0, jnp.minimum(span, (pos_ref[lane] - i * tile) // block_size
+                           + 1), block, None)
+
+    def start(lane, i, buf):
+        copy_blocks(lane, i, buf, lambda copy: copy.start())
+
+    def wait(lane, i, buf):
+        copy_blocks(lane, i, buf, lambda copy: copy.wait())
+
+    @pl.when(s == 0)
+    def _first_program():
+        # A block past a span's last live one is not fetched, and its
+        # rows of the tile keep what an earlier span left. A stale key
+        # gives a logit the mask replaces; a stale value is multiplied by
+        # a masked probability, exactly 0, and must not be the NaN of
+        # uninitialised memory: zeros first.
+        vbuf[...] = jnp.zeros_like(vbuf)
+        flight[0] = 0    # the buffer the next first span lands in
+        flight[1] = -1   # the lane whose first span is already in flight
+
+    n = spans_of(s)
+    buf0 = flight[0]
+
+    @pl.when(jnp.logical_and(n > 0, flight[1] != s))
+    def _own_first_span():
+        start(s, 0, buf0)
+
+    init_softmax_scratch(acc_ref, m_ref, l_ref)
     pos = pos_ref[s]
 
-    def by_group(ref):
-        """The block's `[bs, H*Dh]` rows as `[G, bs, W]`: static
+    def by_group(ref, buf):
+        """The tile's `[span*bs, H*Dh]` rows as `[G, span*bs, W]`: static
         lane-aligned slices, no transpose."""
-        return jnp.stack([ref[0, 0, :, g * width:(g + 1) * width]
+        return jnp.stack([ref[buf, :, g * width:(g + 1) * width]
                           for g in range(groups)])
 
-    # Blocks past the slot's write position hold nothing visible; their
-    # programs still run (the TPU grid is static) but touch no state.
-    @pl.when(b * block_size <= pos)
-    def _accumulate():
-        q = q_ref[0]                                           # [G, per, W]
-        st = jax.lax.dot_general(
-            q, by_group(k_ref), (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale        # [G, per, bs]
-        idx = b * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, block_size), 2)
-        st = jnp.where(idx <= pos, st, NEG_INF)
-        online_softmax_update(st, by_group(v_ref), acc_ref, m_ref, l_ref,
-                              (((2,), (1,)), ((0,), (0,))))    # [G, per, W]
+    def fold(i, _):
+        buf = (buf0 + i) % 2
 
-    @pl.when(b == mb - 1)
+        @pl.when(i + 1 < n)
+        def _next_span():
+            start(s, i + 1, 1 - buf)
+
+        @pl.when(i + 1 == n)
+        def _next_lane():
+            # The next live lane's first span rides behind this lane's
+            # last fold, so that no program opens on a cold fetch.
+            nxt = jax.lax.while_loop(
+                lambda j: jnp.logical_and(
+                    j < slots, spans_of(jnp.minimum(j, slots - 1)) == 0),
+                lambda j: j + 1, s + 1)
+            flight[1] = nxt
+
+            @pl.when(nxt < slots)
+            def _():
+                start(jnp.minimum(nxt, slots - 1), 0, 1 - buf)
+
+        wait(s, i, buf)
+        st = jax.lax.dot_general(
+            q_ref[0], by_group(kbuf, buf), (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale      # [G, per, tile]
+        idx = i * tile + jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, tile), 2)
+        st = jnp.where(idx <= pos, st, NEG_INF)
+        online_softmax_update(st, by_group(vbuf, buf), acc_ref, m_ref,
+                              l_ref, (((2,), (1,)), ((0,), (0,))))
+
+    jax.lax.fori_loop(0, n, fold, None)
+    flight[0] = (buf0 + n) % 2
+
+    @pl.when(n > 0)
     def _finish():
         finish_softmax_scratch(o_ref, acc_ref, l_ref, idx=0)
+
+    @pl.when(n == 0)
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
 
 
 def paged_attention_pallas(
@@ -192,28 +302,37 @@ def paged_attention_pallas(
     width = per * dh
     bs = k_pool.shape[2]
     mb = block_tables.shape[1]
+    tile = span_tokens(bs, mb)
+    span = tile // bs
+    # The table is read a span at a time: pad it to whole spans with the
+    # trash block (never fetched: it lies past every position).
+    block_tables = jnp.pad(block_tables, ((0, 0), (0, -mb % span)),
+                           constant_values=k_pool.shape[1] - 1)
     # Heads sharing a 128-lane group ride block-diagonally: row j of group
     # g is head g*per+j in its own Dh lanes and zeros in its neighbours',
-    # so one [per, W] x [W, bs] product gives every head's own logits. The
-    # rows are also the matmuls' non-contracting lhs dim, which Mosaic
+    # so one [per, W] x [W, tile] product gives every head's own logits.
+    # The rows are also the matmuls' non-contracting lhs dim, which Mosaic
     # needs and cannot make in-kernel from a packed bf16 [H, Dh] tile.
     eye = jnp.eye(per, dtype=q.dtype)[:, :, None]
     q_diag = (q.reshape(slots, groups, per, 1, dh) * eye).reshape(
         slots, groups, per, width)
     q_block = pl.BlockSpec((1, groups, per, width),
-                           lambda s, b, tbl, pos, lay: (s, 0, 0, 0))
-    kv_block = pl.BlockSpec(
-        (1, 1, bs, nh * dh),
-        lambda s, b, tbl, pos, lay: (lay[0], tbl[s, b], 0, 0))
+                           lambda s, tbl, pos, lay: (s, 0, 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)  # copied by hand, by table
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # block_tables, positions, layer
-        grid=(slots, mb),
-        in_specs=[q_block, kv_block, kv_block],
+        grid=(slots,),
+        in_specs=[q_block, pool, pool],
         out_specs=q_block,
-        scratch_shapes=softmax_scratch((groups, per), width),  # fp32, VMEM
+        scratch_shapes=[
+            pltpu.VMEM((2, tile, nh * dh), k_pool.dtype),  # K, two spans
+            pltpu.VMEM((2, tile, nh * dh), v_pool.dtype),  # V, two spans
+            pltpu.SemaphoreType.DMA((2, 2)),               # [buffer, K|V]
+            pltpu.SMEM((2,), jnp.int32),                   # across lanes
+        ] + softmax_scratch((groups, per), width),         # fp32, VMEM
     )
     kernel = functools.partial(
-        _paged_kernel, block_size=bs, scale=1.0 / (dh ** 0.5))
+        _paged_kernel, block_size=bs, span=span, scale=1.0 / (dh ** 0.5))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

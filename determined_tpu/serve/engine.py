@@ -30,6 +30,7 @@ import numpy as np
 
 from determined_tpu.common import trace
 from determined_tpu.models.gpt2 import Config
+from determined_tpu.ops.paged_attention import kernel_refusal, live_spans
 from determined_tpu.parallel.sharding import LogicalRules
 from determined_tpu.serve import model as smodel
 
@@ -130,7 +131,6 @@ def resolve_attention_impl(impl: str, cfg: Config) -> str:
     `pltpu.force_tpu_interpret_mode()`. A head geometry the kernel cannot
     take (ops/paged_attention.kernel_refusal) sends "auto" to the
     reference, said in the log, and makes an explicit "pallas" raise."""
-    from determined_tpu.ops.paged_attention import kernel_refusal
     from determined_tpu.parallel.mesh import on_tpu
 
     why_not = kernel_refusal(cfg.n_head, cfg.head_dim)
@@ -270,6 +270,10 @@ class ServingEngine:
         self.decode_steps = 0
         self.prefills = 0
         self.block_copies = 0
+        # What the decode kernel walks, per layer, summed over decode
+        # calls (ops/paged_attention.py: a lane's 128-token spans up to
+        # its position; an idle lane none).
+        self.decode_spans = 0
 
     # -- paged geometry ------------------------------------------------
 
@@ -641,6 +645,9 @@ class ServingEngine:
                 logits, np.asarray(temperatures, np.float32),
                 self._next_rng())
             self.decode_steps += 1
+            self.decode_spans += live_spans(
+                positions, self._tables[:, 0] != self.trash_block,
+                self.block_size, self.max_blocks_per_seq)
         with trace.phase("serve.step.fetch"):
             return np.asarray(toks)
 
@@ -660,5 +667,10 @@ class ServingEngine:
             "decode_steps": self.decode_steps,
             "prefills": self.prefills,
             "block_copies": self.block_copies,
+            # Spans that held a visible key, and spans the kernel was
+            # launched over: its grid is over lanes and its loop ends at
+            # the position, so one count is both.
+            "decode_spans_live": self.decode_spans,
+            "decode_spans_grid": self.decode_spans,
             "compile": dict(self.compile_stats),
         }

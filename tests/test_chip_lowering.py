@@ -84,14 +84,16 @@ def test_flash_fwd_bwd_lowers_through_mosaic(v5e, bf16):
 @pytest.mark.parametrize("geometry", [
     pytest.param((4, 2, 128, 16, 4), id="small-aligned"),
     pytest.param((8, 12, 64, 16, 64), id="gpt2-small"),
+    pytest.param((32, 20, 64, 16, 64), id="served-gpt2-large"),
 ])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
 def test_paged_decode_lowers_through_mosaic(v5e, geometry, dtype):
     """The kernel Mosaic refused before PR 21 (a 2-D lhs with a batch dim
-    and no non-contracting dim), at the served geometry: its operand is
-    the whole pool `[L, blocks, bs, H*Dh]` plus a layer index, and heads
-    are static 128-lane slices of a block's rows."""
+    and no non-contracting dim), at the served geometries: its operand is
+    the whole pool `[L, blocks, bs, H*Dh]` plus a layer index, left where
+    it rests and copied by hand a span of a lane's blocks at a time, and
+    heads are static 128-lane slices of the span's rows."""
     from determined_tpu.ops.paged_attention import paged_attention_pallas
 
     slots, heads, dh, bs, mb = geometry

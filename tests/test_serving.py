@@ -684,6 +684,109 @@ def test_paged_attention_pallas_matches_reference(nh, dh):
                                              pos)[:2]), np.asarray(ref[:2]))
 
 
+def _span_case(name):
+    """(tables, positions, idle lanes) of one span-edge case: 2 heads of
+    64, blocks of 8 tokens, so a span is 16 blocks = 128 tokens; 40 table
+    entries a lane (2.5 spans: the table is padded to 48), 5 lanes."""
+    slots, mb, bs = 5, 40, 8
+    trash = slots * mb
+    tbl = np.random.default_rng(11).permutation(trash).reshape(
+        slots, mb).astype(np.int32)
+    pos = np.array([0, 127, 128, 129, mb * bs - 1], np.int32)
+    idle = []
+    if name == "idle-between-live":
+        idle = [1, 3]
+    elif name == "idle-first-and-last":
+        idle = [0, 4]
+    elif name == "foreign-blocks-past-position":
+        # Dead entries hold ANOTHER lane's live blocks, not the trash.
+        for lane in range(4):
+            dead = pos[lane] // bs + 1
+            tbl[lane, dead:] = tbl[4, dead:]
+    elif name == "trash-past-position":
+        for lane in range(4):
+            tbl[lane, pos[lane] // bs + 1:] = trash
+    elif name == "one-lane-one-token":
+        idle = [1, 2, 3, 4]
+    else:
+        assert name == "span-edges"
+    for lane in idle:
+        tbl[lane], pos[lane] = trash, 0
+    return tbl, pos, idle
+
+
+@pytest.mark.parametrize("name", [
+    "span-edges", "idle-between-live", "idle-first-and-last",
+    "foreign-blocks-past-position", "trash-past-position",
+    "one-lane-one-token"])
+def test_paged_attention_pallas_span_edges(name):
+    """The kernel walks a lane's table a 128-token span at a time, up to
+    the lane's position: positions on both sides of a span's edge and at
+    the table's end, a table that is no whole number of spans, lanes of
+    different lengths in one call, idle lanes between, before and after
+    live ones (their rows are zeros, their neighbours' rows the
+    reference's), dead table entries that name another lane's live blocks
+    (nothing past a position reaches a result), a layer that is not the
+    pool's first.
+    Run by the TPU interpreter, whose uninitialised memory is NaN: a block
+    that was not fetched must not leak into a sum either."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from determined_tpu.ops.paged_attention import (
+        paged_attention_pallas, paged_attention_reference, span_tokens)
+
+    nh, dh, bs = 2, 64, 8
+    tbl, pos, idle = _span_case(name)
+    slots, mb = tbl.shape
+    assert span_tokens(bs, mb) == 128 and mb * bs % 128
+    rng = np.random.default_rng(7)
+    pool = (3, slots * mb + 1, bs, nh * dh)
+    q = jnp.asarray(rng.normal(size=(slots, nh, dh)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=pool), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=pool), jnp.float32)
+    args = (jnp.int32(1), jnp.asarray(tbl), jnp.asarray(pos))
+    ref = np.asarray(paged_attention_reference(q, kp, vp, *args))
+    out = np.asarray(paged_attention_pallas(
+        q, kp, vp, *args, interpret=pltpu.InterpretParams()))
+    live = [lane for lane in range(slots) if lane not in idle]
+    np.testing.assert_allclose(out[live], ref[live], atol=1e-5)
+    assert not out[idle].any()
+
+
+def test_engine_counts_the_decode_kernels_spans(tiny_params):
+    """`decode_spans_live` is the sum over decode calls and live lanes of
+    ceil((position + 1) / span): a lane crossing a span's edge counts two
+    from then on, an idle slot nothing; the kernel's loop ends at the
+    position, so what is launched is what is live."""
+    cfg = dataclasses.replace(TINY, n_positions=512)
+    params = gpt2.init(jax.random.PRNGKey(0), cfg)
+    eng = ServingEngine(params, cfg, slots=3, max_seq_len=512,
+                        prefill_buckets=[8, 128], kv_block_size=16)
+    rng = np.random.default_rng(0)
+    position = {0: 126, 2: 5}      # slot 1 stays idle
+    last = {slot: eng.prefill_request(
+        slot, rng.integers(0, cfg.vocab_size, n).astype(np.int32))
+        for slot, n in position.items()}
+    assert eng.stats()["decode_spans_live"] == 0
+    want = 0
+    for _ in range(4):             # slot 0 writes at 126, 127, 128, 129
+        tokens, positions = np.zeros(3, np.int32), np.zeros(3, np.int32)
+        for slot in position:
+            tokens[slot], positions[slot] = last[slot], position[slot]
+            want += -(-(position[slot] + 1) // 128)
+        out = eng.decode(tokens, positions, np.zeros(3, np.float32))
+        for slot in position:
+            last[slot], position[slot] = int(out[slot]), position[slot] + 1
+    stats = eng.stats()
+    assert want == 1 + 1 + 2 + 2 + 4
+    assert stats["decode_spans_live"] == want
+    assert stats["decode_spans_live"] <= stats["decode_spans_grid"]
+    eng.release_slot(0)
+    eng.decode(np.zeros(3, np.int32), np.array([0, 0, 9], np.int32),
+               np.zeros(3, np.float32))
+    assert eng.stats()["decode_spans_live"] == want + 1
+
+
 def test_kernel_geometry_auto_falls_back_and_explicit_pallas_raises(
         tiny_params, monkeypatch):
     """TINY's pool row is 2 x 16 = 32 lanes, which no 128-lane slice
