@@ -197,6 +197,55 @@ def phase_kernels(args, out):
           f"paged decode kernel vs paged_attention_reference slots {slots} "
           f"H {h} Dh {d} block {bs}: {err:.4f} (tol {FWD_TOL})")
 
+    # paged decode with shared K/V heads and the recurrent-state update,
+    # at serve-h1-decode's geometry: 64 lanes, 20 query heads over 4 K/V
+    # heads of 128; 32 state heads of 256 x 128 in 2 groups, float32;
+    # idle lanes between live ones and at the end, a middle layer.
+    from determined_tpu.ops.ssm_state import (ssm_state_reference,
+                                              ssm_state_update)
+
+    slots, hq, hkv, d = 64, 20, 4, 128
+    idle = [3, 17, 62, 63]
+    live = np.ones(slots, bool)
+    live[idle] = False
+    pool = (3, slots * 40 + 1, bs, hkv * d)
+    q = jnp.asarray(rng.normal(size=(slots, hq, d)), jnp.bfloat16)
+    kp = jnp.asarray(rng.normal(size=pool), jnp.bfloat16)
+    vp = jnp.asarray(rng.normal(size=pool), jnp.bfloat16)
+    tbl = rng.permutation(slots * 40).reshape(slots, 40).astype(np.int32)
+    tbl[idle] = slots * 40
+    pos = np.where(live, rng.integers(0, 640, slots), 0).astype(np.int32)
+    got, want = (np.asarray(jax.jit(attend)(q, kp, vp, layer, tbl, pos),
+                            np.float32)
+                 for attend in (paged_attention_pallas,
+                                paged_attention_reference))
+    err = rel_err(got[live], want[live])
+    out["paged_decode_grouped"] = {"rel_err": round(err, 5)}
+    check(not got[idle].any() and err <= FWD_TOL,
+          f"grouped paged decode kernel vs reference, {hq} query heads "
+          f"over {hkv} K/V heads of {d}: {err:.4f} (tol {FWD_TOL})")
+
+    heads, groups, n = 32, 2, 256
+    x = jnp.asarray(rng.normal(size=(slots, heads, d)), jnp.bfloat16)
+    dt = jnp.asarray(rng.uniform(1e-3, 0.1, (slots, heads)), jnp.float32)
+    a = -jnp.asarray(rng.uniform(1, 16, (heads,)), jnp.float32)
+    b, c = (jnp.asarray(rng.normal(size=(slots, groups, n)), jnp.bfloat16)
+            for _ in range(2))
+    state = jnp.asarray(rng.normal(size=(3, slots, heads, n, d)),
+                        jnp.float32)
+    want_pool, want_y = jax.jit(ssm_state_reference)(
+        x, dt, b, c, state, layer, live, a)
+    before = np.asarray(state[:, idle])
+    got_pool, got_y = jax.jit(ssm_state_update, donate_argnums=(4,))(
+        x, dt, b, c, state, layer, live, a)
+    err = max(rel_err(got_pool, want_pool), rel_err(got_y, want_y))
+    out["ssm_state"] = {"rel_err": round(err, 7)}
+    check(np.array_equal(np.asarray(got_pool[:, idle]), before),
+          "state kernel: an idle lane's state changed")
+    check(err <= 1e-5,
+          f"state kernel vs ssm_state_reference, {slots} lanes of {heads} "
+          f"x {n} x {d} float32: {err:.2e} (tol 1e-5)")
+
 
 # -------------------------------------------------------------------- train
 

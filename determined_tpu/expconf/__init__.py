@@ -445,6 +445,16 @@ def _validate_registry(block: Any, serving: Any,
         errors.append("registry.promote must be one of: best, latest")
 
 
+# serving.model → the module with that family's serving steps and its
+# `config_from(model_config)` (serve/engine.py `family_of`, serve/task.py
+# `build_model`). Kept here, the one list of families, because this module
+# imports without jax.
+SERVING_FAMILIES = {
+    "gpt2": "determined_tpu.serve.model",
+    "falcon_h1": "determined_tpu.serve.falcon_h1",
+}
+
+
 def _validate_serving(block: Any, errors: List[str]) -> None:
     """`serving:` — a `det serve` deployment (docs/serving.md): which
     checkpoint to load, the model family/config to rebuild it into, and
@@ -469,8 +479,9 @@ def _validate_serving(block: Any, errors: List[str]) -> None:
             "serving.checkpoint must be a checkpoint storage id or "
             "'latest'")
     model = block.get("model")
-    if model is not None and model not in ("gpt2",):
-        errors.append("serving.model must be one of: gpt2")
+    if model is not None and model not in SERVING_FAMILIES:
+        errors.append("serving.model must be one of: "
+                      + ", ".join(sorted(SERVING_FAMILIES)))
     mc = block.get("model_config")
     if mc is not None and not isinstance(mc, dict):
         errors.append("serving.model_config must be a mapping")

@@ -78,6 +78,8 @@ class Config:
     moe_capacity_factor: float = 1.25
     moe_aux_coef: float = 0.01
 
+    family = "gpt2"  # its serving steps: serve/model.py
+
     @property
     def ff_dim(self) -> int:
         return self.d_ff or 4 * self.d_model

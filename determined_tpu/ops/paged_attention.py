@@ -6,7 +6,10 @@ query token over K/V that live in a **block pool** — `[L, num_blocks,
 block_size, H*Dh]`, every layer in one buffer and a token's heads side by
 side in one row — addressed through a per-slot **block table** (`[slots,
 max_blocks]` int32, logical block i of the sequence → pool block
-`table[s, i]`). No `slots × max_seq` lane is reserved: HBM holds exactly
+`table[s, i]`). `H` there is the number of K/V heads: a model whose query
+heads share K/V heads (grouped-query attention) has a row of `Hkv*Dh`
+lanes, and both implementations read the number off the pool's row. No
+`slots × max_seq` lane is reserved: HBM holds exactly
 the blocks sequences actually own, and admission packs sequences into
 that budget.
 
@@ -53,7 +56,10 @@ Two interchangeable implementations (selected by
     lane-aligned slices of the tile, G groups of W = max(128, Dh) lanes:
     two heads of 64 share a group, with q laid block-diagonally (`[G,
     per, W]`, made outside the kernel) so one product gives each head its
-    own logits; nothing is transposed. Both inner products are matmuls
+    own logits; nothing is transposed. With shared K/V heads (`Hkv < H`,
+head dim a multiple of 128) a group is a K/V head and its rows are the
+query heads that share it (`[Hkv, H/Hkv, Dh]`), no block diagonal; with
+`Hkv == H` the traced program is what it was. Both inner products are matmuls
     batched over the LEADING group dim with 3-D operands (`[G, per, W] x
     [G, span*bs, W]`) — the form Mosaic lowers; a 2-D lhs with a batch
     dim and no non-contracting dim is refused by its dot-dimension
@@ -103,8 +109,8 @@ if HAVE_PALLAS:
 
 def paged_attention_reference(
     q: jax.Array,             # [slots, H, Dh]
-    k_pool: jax.Array,        # [L, pool_blocks, block_size, H*Dh]
-    v_pool: jax.Array,        # [L, pool_blocks, block_size, H*Dh]
+    k_pool: jax.Array,        # [L, pool_blocks, block_size, Hkv*Dh]
+    v_pool: jax.Array,        # [L, pool_blocks, block_size, Hkv*Dh]
     layer: jax.Array,         # scalar int32: the layer attended
     block_tables: jax.Array,  # [slots, max_blocks] int32 pool indices
     positions: jax.Array,     # [slots] int32: index written this step
@@ -112,14 +118,25 @@ def paged_attention_reference(
     """Pure-jnp paged decode attention → [slots, H, Dh] in q.dtype.
 
     Gathers each slot's lane (`pool[layer, table]` → `[max_blocks ×
-    block_size, H, Dh]`: the lanes' blocks, never a layer of the pool) and
-    attends over it: fp32 logits, `index <= position` mask, fp32 softmax,
-    probs cast back to the compute dtype.
+    block_size, Hkv, Dh]`: the lanes' blocks, never a layer of the pool)
+    and attends over it: fp32 logits, `index <= position` mask, fp32
+    softmax, probs cast back to the compute dtype. The pool's row says how
+    many K/V heads there are; with fewer than query heads, query head i
+    reads K/V head `i // (H / Hkv)`.
     """
     slots, mb = block_tables.shape
     _, nh, dh = q.shape
     bs = k_pool.shape[2]
     scale = 1.0 / (dh ** 0.5)
+    hkv = k_pool.shape[-1] // dh
+    # Two bodies on purpose: with `Hkv == H` the grouped einsum is this
+    # one with a row axis of 1, but it lowers to other HLO, and ISSUE 32
+    # holds a K/V head per query head to the traced program it had (the
+    # engine calls' lowered text is compared side against side on the CPU,
+    # where this reference is the path).
+    if hkv != nh:
+        return _grouped_reference(q, k_pool, v_pool, layer, block_tables,
+                                  positions, hkv)
     k_lane = k_pool[layer, block_tables].reshape(slots, mb * bs, nh, dh)
     v_lane = v_pool[layer, block_tables].reshape(slots, mb * bs, nh, dh)
     mask = jnp.arange(mb * bs)[None] <= positions[:, None]  # [slots, S]
@@ -130,6 +147,25 @@ def paged_attention_reference(
     return jnp.einsum("bhm,bmhd->bhd", probs, v_lane)
 
 
+def _grouped_reference(q, k_pool, v_pool, layer, block_tables, positions,
+                       hkv: int):
+    """The reference with the query heads that share a K/V head as rows
+    of that head."""
+    slots, mb = block_tables.shape
+    _, nh, dh = q.shape
+    bs = k_pool.shape[2]
+    k_lane = k_pool[layer, block_tables].reshape(slots, mb * bs, hkv, dh)
+    v_lane = v_pool[layer, block_tables].reshape(slots, mb * bs, hkv, dh)
+    mask = jnp.arange(mb * bs)[None] <= positions[:, None]  # [slots, S]
+    qg = q.reshape(slots, hkv, nh // hkv, dh)
+    logits = jnp.einsum("bgrd,bmgd->bgrm", qg, k_lane).astype(jnp.float32)
+    logits = jnp.where(mask[:, None, None], logits / (dh ** 0.5),
+                       jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    return jnp.einsum("bgrm,bmgd->bgrd", probs, v_lane).reshape(
+        slots, nh, dh)
+
+
 # ---------------------------------------------------------------------------
 # Pallas kernel: scalar-prefetched block-table gather + online softmax.
 # ---------------------------------------------------------------------------
@@ -137,29 +173,45 @@ def paged_attention_reference(
 LANES = 128
 
 
-def kernel_refusal(n_head: int, head_dim: int) -> Optional[str]:
+def kernel_refusal(q_heads: int, kv_heads: int,
+                   head_dim: int) -> Optional[str]:
     """Why the kernel cannot take this head geometry, or None if it can.
 
-    The kernel cuts a pool row (`H*Dh` lanes) into static lane-aligned
-    slices, so whole heads have to tile whole 128-lane groups."""
+    The kernel cuts a pool row (`Hkv*Dh` lanes) into static lane-aligned
+    slices, so whole heads have to tile whole 128-lane groups; query heads
+    that share a K/V head are the rows of that head's slice, which then
+    has to be a whole number of 128-lane groups by itself."""
     if not HAVE_PALLAS:
         return "pallas is not in this jax build"
-    if (n_head * head_dim) % LANES:
-        return (f"{n_head} heads of {head_dim} make a pool row of "
-                f"{n_head * head_dim} lanes, not a multiple of {LANES}")
+    if q_heads % kv_heads:
+        return f"{q_heads} query heads do not divide over {kv_heads} K/V heads"
+    if (kv_heads * head_dim) % LANES:
+        return (f"{kv_heads} heads of {head_dim} make a pool row of "
+                f"{kv_heads * head_dim} lanes, not a multiple of {LANES}")
     if head_dim % LANES and LANES % head_dim:
         return f"head dim {head_dim} neither divides nor is divided by {LANES}"
+    if kv_heads != q_heads and head_dim % LANES:
+        return (f"shared K/V heads of {head_dim} are not whole {LANES}-lane "
+                "groups")
     return None
 
 
-def _lane_groups(n_head: int, head_dim: int) -> Tuple[int, int]:
-    """(groups, heads per group): a group is one slice of the pool row,
-    `max(128, Dh)` lanes — two heads of 64, one head of 128 or 256."""
-    why_not = kernel_refusal(n_head, head_dim)
+def _lane_groups(q_heads: int, kv_heads: int,
+                 head_dim: int) -> Tuple[int, int, int]:
+    """(groups, rows per group, lanes per group) of the query tile.
+
+    A K/V head of its own for each query head: a group is one slice of
+    the pool row, `max(128, Dh)` lanes — two heads of 64 (laid block-
+    diagonally, a row each), one head of 128 or 256. Shared K/V heads: a
+    group is a K/V head and its rows are the query heads that share it,
+    no block diagonal."""
+    why_not = kernel_refusal(q_heads, kv_heads, head_dim)
     if why_not:
         raise ValueError(f"the paged decode kernel cannot run: {why_not}")
+    if kv_heads != q_heads:
+        return kv_heads, q_heads // kv_heads, head_dim
     per = max(1, LANES // head_dim)
-    return n_head // per, per
+    return q_heads // per, per, per * head_dim
 
 
 def span_tokens(block_size: int, max_blocks: int) -> int:
@@ -289,8 +341,8 @@ def _paged_kernel(tbl_ref, pos_ref, lay_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 def paged_attention_pallas(
     q: jax.Array,             # [slots, H, Dh]
-    k_pool: jax.Array,        # [L, pool_blocks, block_size, H*Dh]
-    v_pool: jax.Array,        # [L, pool_blocks, block_size, H*Dh]
+    k_pool: jax.Array,        # [L, pool_blocks, block_size, Hkv*Dh]
+    v_pool: jax.Array,        # [L, pool_blocks, block_size, Hkv*Dh]
     layer: jax.Array,         # scalar int32
     block_tables: jax.Array,  # [slots, max_blocks] int32
     positions: jax.Array,     # [slots] int32
@@ -298,8 +350,9 @@ def paged_attention_pallas(
 ) -> jax.Array:
     """Pallas paged decode attention → [slots, H, Dh] in q.dtype."""
     slots, nh, dh = q.shape
-    groups, per = _lane_groups(nh, dh)
-    width = per * dh
+    row = k_pool.shape[-1]
+    hkv = row // dh
+    groups, per, width = _lane_groups(nh, hkv, dh)
     bs = k_pool.shape[2]
     mb = block_tables.shape[1]
     tile = span_tokens(bs, mb)
@@ -313,9 +366,12 @@ def paged_attention_pallas(
     # so one [per, W] x [W, tile] product gives every head's own logits.
     # The rows are also the matmuls' non-contracting lhs dim, which Mosaic
     # needs and cannot make in-kernel from a packed bf16 [H, Dh] tile.
-    eye = jnp.eye(per, dtype=q.dtype)[:, :, None]
-    q_diag = (q.reshape(slots, groups, per, 1, dh) * eye).reshape(
-        slots, groups, per, width)
+    if hkv == nh:
+        eye = jnp.eye(per, dtype=q.dtype)[:, :, None]
+        q_diag = (q.reshape(slots, groups, per, 1, dh) * eye).reshape(
+            slots, groups, per, width)
+    else:   # the rows of a K/V head are the query heads that share it
+        q_diag = q.reshape(slots, groups, per, width)
     q_block = pl.BlockSpec((1, groups, per, width),
                            lambda s, tbl, pos, lay: (s, 0, 0, 0))
     pool = pl.BlockSpec(memory_space=pl.ANY)  # copied by hand, by table
@@ -325,8 +381,8 @@ def paged_attention_pallas(
         in_specs=[q_block, pool, pool],
         out_specs=q_block,
         scratch_shapes=[
-            pltpu.VMEM((2, tile, nh * dh), k_pool.dtype),  # K, two spans
-            pltpu.VMEM((2, tile, nh * dh), v_pool.dtype),  # V, two spans
+            pltpu.VMEM((2, tile, row), k_pool.dtype),      # K, two spans
+            pltpu.VMEM((2, tile, row), v_pool.dtype),      # V, two spans
             pltpu.SemaphoreType.DMA((2, 2)),               # [buffer, K|V]
             pltpu.SMEM((2,), jnp.int32),                   # across lanes
         ] + softmax_scratch((groups, per), width),         # fp32, VMEM
@@ -341,12 +397,14 @@ def paged_attention_pallas(
             # Worst case: every table entry live. 2 matmuls over the lane.
             flops=int(4 * slots * mb * bs * nh * dh),
             bytes_accessed=int(
-                2 * slots * mb * bs * nh * dh * k_pool.dtype.itemsize),
+                2 * slots * mb * bs * row * k_pool.dtype.itemsize),
             transcendentals=int(slots * mb * bs * nh),
         ),
         interpret=interpret,
     )(block_tables, positions, jnp.reshape(layer, (1,)).astype(jnp.int32),
       q_diag, k_pool, v_pool)
+    if hkv != nh:
+        return out.reshape(slots, nh, dh)
     # Row j of a group holds head j's output in its own Dh lanes (the
     # others are that row's probabilities over a neighbour's values).
     out = out.reshape(slots, groups, per, per, dh)

@@ -10,17 +10,22 @@ Layout:
   model.py      KV-cached GPT-2 prefill/decode steps over the paged
                 block pool (paged_prefill/paged_decode_step; shape-static,
                 AOT)
+  falcon_h1.py  a second family's steps: a Mamba-2 mixer beside grouped-
+                query attention; a recurrent-state pool indexed by lane
+                beside the paged pool (docs/serving.md "Families with
+                recurrent state")
   kv_cache.py   KV block manager: paged admission accounting, refcounted
                 prefix caching, copy-on-write
   engine.py     checkpoint loading + compiled executables + device state
-                (paged pool + block tables)
+                (the family's cache + block tables); `family_of` finds a
+                family's steps
   scheduler.py  bounded admission queue + the continuous batcher
   http.py       HTTP front-end (generate/stats/health)
   task.py       cluster entrypoint (drain lifecycle, proxy registration)
 
 The paged decode-attention kernel itself lives in
 determined_tpu/ops/paged_attention.py (docs/serving.md "Paged KV &
-prefix caching").
+prefix caching"), the recurrent-state kernel in ops/ssm_state.py.
 
 Docs: docs/serving.md.
 """

@@ -13,8 +13,15 @@ Owns everything device-side for one serve replica:
     bucket (`jit(...).lower(...).compile()`), so no request ever pays a
     trace — the serving analogue of the trial preflight discipline:
     all compilation happens before the first request is admitted,
-  - holds the paged KV pool (donated through every call: one copy in
-    HBM), the per-slot block tables and a step-folded sampling rng.
+  - holds the cache — a pytree of pools that is the family's own: the
+    paged KV pool, and for a family with recurrent state a state pool
+    indexed by lane beside it (donated through every call: one copy in
+    HBM) —, the per-slot block tables and a step-folded sampling rng.
+
+The engine is one for every family: what is a family's own — the
+resident tree, the cache, prefill, the decode step, the block copy — it
+takes from the family's serving module (`family_of`: `serve/model.py` for
+GPT-2, `serve/falcon_h1.py`).
 
 The engine is intentionally single-consumer: only the batcher thread
 (scheduler.py) calls prefill/decode; stats reads are lock-free counters.
@@ -22,6 +29,7 @@ The engine is intentionally single-consumer: only the batcher thread
 
 from __future__ import annotations
 
+import importlib
 import logging
 import time
 from typing import Any, Dict, List, Optional, Sequence
@@ -29,14 +37,31 @@ from typing import Any, Dict, List, Optional, Sequence
 import numpy as np
 
 from determined_tpu.common import trace
-from determined_tpu.models.gpt2 import Config
-from determined_tpu.ops.paged_attention import kernel_refusal, live_spans
+from determined_tpu.expconf import SERVING_FAMILIES
+from determined_tpu.ops.paged_attention import live_spans
+from determined_tpu.ops.ssm_state import live_lanes
 from determined_tpu.parallel.sharding import LogicalRules
-from determined_tpu.serve import model as smodel
 
 logger = logging.getLogger("determined_tpu.serve")
 
 DEFAULT_BUCKETS = (32, 64, 128, 256, 512, 1024)
+
+def family_module(name: str):
+    """The serving module of the family `serving.model` names
+    (`expconf.SERVING_FAMILIES`): `config_from`, `resident_params`,
+    `init_cache` / `cache_bytes` / `state_bytes`, `prefill`,
+    `decode_step`, `copy_block` (None where no block can be shared),
+    `sample`, `kernel_refusal`, `position_limit` and `RECURRENT_STATE`."""
+    if name not in SERVING_FAMILIES:
+        raise ValueError(
+            f"unknown serving.model {name!r}; supported: "
+            f"{', '.join(sorted(SERVING_FAMILIES))}")
+    return importlib.import_module(SERVING_FAMILIES[name])
+
+
+def family_of(cfg):
+    """The serving module of `cfg`'s family, which the Config names."""
+    return family_module(cfg.family)
 
 
 def default_buckets(max_seq: int) -> List[int]:
@@ -122,18 +147,20 @@ def _tree_bytes(tree) -> int:
                for x in jax.tree_util.tree_leaves(tree))
 
 
-def resolve_attention_impl(impl: str, cfg: Config) -> str:
+def resolve_attention_impl(impl: str, cfg) -> str:
     """serving.attention_impl → the engine's concrete path.
 
-    "auto" picks the Pallas kernel on TPU and the jnp gather reference
+    "auto" picks the Pallas kernels on TPU and the jnp references
     elsewhere; "pallas"/"reference" force a path —
     a forced "pallas" off-TPU compiles only under a test's
-    `pltpu.force_tpu_interpret_mode()`. A head geometry the kernel cannot
-    take (ops/paged_attention.kernel_refusal) sends "auto" to the
-    reference, said in the log, and makes an explicit "pallas" raise."""
+    `pltpu.force_tpu_interpret_mode()`. A geometry one of the family's
+    kernels cannot take (its `kernel_refusal`: the paged decode kernel's,
+    and for a family with recurrent state the state kernel's) sends "auto"
+    to the reference, said in the log, and makes an explicit "pallas"
+    raise: the name is "pallas" only when every kernel is."""
     from determined_tpu.parallel.mesh import on_tpu
 
-    why_not = kernel_refusal(cfg.n_head, cfg.head_dim)
+    why_not = family_of(cfg).kernel_refusal(cfg)
     if impl == "auto":
         if not on_tpu():
             return "reference"
@@ -159,19 +186,22 @@ class ServingEngine:
     """Compiled prefill/decode over a fixed slot batch + KV cache.
 
     The cache is paged (docs/serving.md "Paged KV & prefix caching"): a
-    block pool `[L, num_blocks + 1, block_size, H*Dh]` (the extra block
+    block pool `[L, num_blocks + 1, block_size, Hkv*Dh]` (the extra block
     is the trash block for padded/inactive writes) plus per-slot block
     tables the batcher hands in at prefill. Every executable takes the
     table as an input canonicalized to the full
     `max_seq_len // block_size` length, so ONE decode executable and one
     prefill executable per token bucket cover every table — joining,
-    retiring and prefix sharing never recompile.
+    retiring and prefix sharing never recompile. A family with recurrent
+    state (docs/serving.md "Families with recurrent state") keeps a
+    second pool in the same pytree, indexed by lane: prefill is told the
+    lane, and nothing of that cache can be shared or copied.
     """
 
     def __init__(
         self,
         params: Dict[str, Any],
-        cfg: Config,
+        cfg,
         *,
         slots: int = 8,
         max_seq_len: int = 256,
@@ -189,8 +219,10 @@ class ServingEngine:
         if slots <= 0:
             raise ValueError("slots must be positive")
         self.cfg = cfg
+        self.family = fam = family_of(cfg)
         self.slots = slots
-        self.max_seq_len = min(max_seq_len, cfg.n_positions)
+        limit = fam.position_limit(cfg)   # a position table, where any
+        self.max_seq_len = min(max_seq_len, limit) if limit else max_seq_len
         buckets = sorted(set(
             min(b, self.max_seq_len)
             for b in (prefill_buckets or default_buckets(self.max_seq_len))))
@@ -201,12 +233,12 @@ class ServingEngine:
         device = jax.local_devices()[0]
         placed = jax.device_put(params, device)
         # Compute-ready once, here, and not again in every call
-        # (smodel.resident_params): one jitted cast where a leaf is wider
+        # (the family's `resident_params`): one jitted cast where a leaf is wider
         # than the serving dtype, else the tree as placed. Nothing is
         # donated: the caller keeps what it handed in, and self.params is
         # the only copy the engine holds.
         def narrow(p):
-            return smodel.resident_params(p, cfg)
+            return fam.resident_params(p, cfg)
 
         self.weights_hbm_bytes = _tree_bytes(jax.eval_shape(narrow, placed))
         self.weights_narrowed_bytes = (
@@ -226,6 +258,12 @@ class ServingEngine:
         self.adapter_ids: Dict[str, int] = {"base": 0}
         self._adapter_stack = None
         self._slot_adapters = None
+        if adapters and fam.RECURRENT_STATE:
+            raise ValueError(
+                "serving.adapters: a family with recurrent state has no "
+                "adapter arm (an adapter swaps the tied embedding table "
+                "under one shared cache; this family's head is untied and "
+                "its state is the lane's own)")
         if adapters:
             base_wte = self.params["wte"]
             tables = [base_wte]
@@ -255,6 +293,7 @@ class ServingEngine:
         self._compiled_decode = None
         self._compiled_prefill: Dict[int, Any] = {}
         self._compiled_sample = None
+        self._first_rows = None   # host [slots, vocab] for the first token
         self._compiled_copy_block = None
         self.compile_stats: Dict[str, float] = {
             "weights_hbm_bytes": self.weights_hbm_bytes,
@@ -272,8 +311,10 @@ class ServingEngine:
         self.block_copies = 0
         # What the decode kernel walks, per layer, summed over decode
         # calls (ops/paged_attention.py: a lane's 128-token spans up to
-        # its position; an idle lane none).
+        # its position; an idle lane none), and the lanes whose recurrent
+        # state the state kernel moves (ops/ssm_state.py: the live ones).
         self.decode_spans = 0
+        self.state_lanes = 0
 
     # -- paged geometry ------------------------------------------------
 
@@ -343,9 +384,23 @@ class ServingEngine:
         self._check_geometry()
 
     def cache_hbm_bytes(self) -> int:
-        """HBM the KV cache occupies (the admission budget's anchor)."""
-        return smodel.paged_cache_bytes(
-            self.cfg, self.num_blocks + 1, self.block_size)
+        """HBM the cache occupies, every pool of it (the admission
+        budget's anchor)."""
+        return self.family.cache_bytes(
+            self.cfg, self.num_blocks + 1, self.block_size, self.slots)
+
+    def cache_avals(self) -> Dict[str, Any]:
+        """Shape and dtype of every pool of the cache, nothing allocated
+        (the AOT signature keys them: serve/task.py serving_signature)."""
+        import jax
+
+        return jax.eval_shape(lambda: self.family.init_cache(
+            self.cfg, self.num_blocks + 1, self.block_size, self.slots))
+
+    def state_hbm_bytes(self) -> int:
+        """The part of it a lane owns whatever its context: the recurrent
+        state pool (0 for a family that leaves only K/V)."""
+        return self.family.state_bytes(self.cfg, self.slots)
 
     # -- compilation ---------------------------------------------------
 
@@ -395,10 +450,10 @@ class ServingEngine:
             return compiled
 
         t_all = time.monotonic()
-        cfg, rules = self.cfg, self.rules
+        cfg, rules, fam = self.cfg, self.rules, self.family
         if self._cache is None:
-            self._cache = smodel.init_paged_cache(
-                cfg, self.num_blocks + 1, self.block_size)
+            self._cache = fam.init_cache(
+                cfg, self.num_blocks + 1, self.block_size, self.slots)
             self._tables = np.full(
                 (self.slots, self.max_blocks_per_seq),
                 self.trash_block, np.int32)
@@ -411,60 +466,48 @@ class ServingEngine:
         mb = self.max_blocks_per_seq
         impl = self.attention_impl
 
-        # Adapter stack aval (multi-adapter replicas): every decode/
-        # prefill executable takes the [A+1, V, D] table stack plus the
-        # per-lane index as INPUTS — adapter routing changes operands,
-        # never executables, so N fine-tunes share one compile.
-        stack_sd = None
+        # What a call takes beside (params, cache, ...): operands, never
+        # executables. Multi-adapter replicas hand every decode / prefill
+        # the [A+1, V, D] table stack and the per-lane index, so N
+        # fine-tunes share one compile; a family with recurrent state
+        # tells prefill which lane's state it writes.
+        decode_extra: Dict[str, Any] = {}
+        prefill_extra: Dict[str, Any] = {}
         if self.has_adapters:
             stack_sd = sds(self._adapter_stack.shape,
                            self._adapter_stack.dtype)
+            decode_extra = {"adapters": stack_sd,
+                            "slot_adapters": sds((self.slots,), i32)}
+            prefill_extra = {"adapters": stack_sd,
+                             "slot_adapter": sds((), i32)}
+        if fam.RECURRENT_STATE:
+            prefill_extra = {"slot": sds((), i32)}
 
         t0 = time.monotonic()
 
         def build_decode():
-            if stack_sd is not None:
-                decode = jax.jit(
-                    lambda p, c, t, pos, tbl, ad, sa:
-                        smodel.paged_decode_step(
-                            p, c, t, pos, tbl, cfg, rules,
-                            attention_impl=impl, adapters=ad,
-                            slot_adapters=sa),
-                    donate_argnums=(1,))
-                return decode.lower(
-                    params_sd, cache_sd, sds((self.slots,), i32),
-                    sds((self.slots,), i32), sds((self.slots, mb), i32),
-                    stack_sd, sds((self.slots,), i32)).compile()
             decode = jax.jit(
-                lambda p, c, t, pos, tbl: smodel.paged_decode_step(
-                    p, c, t, pos, tbl, cfg, rules, attention_impl=impl),
+                lambda p, c, t, pos, tbl, *extra: fam.decode_step(
+                    p, c, t, pos, tbl, cfg, rules, attention_impl=impl,
+                    **dict(zip(decode_extra, extra))),
                 donate_argnums=(1,))
             return decode.lower(
                 params_sd, cache_sd, sds((self.slots,), i32),
-                sds((self.slots,), i32),
-                sds((self.slots, mb), i32)).compile()
+                sds((self.slots,), i32), sds((self.slots, mb), i32),
+                *decode_extra.values()).compile()
         self._compiled_decode = acquire("decode", build_decode)
         self.compile_stats["decode_s"] = round(time.monotonic() - t0, 3)
 
         def build_prefill(bucket):
-            if stack_sd is not None:
-                pf = jax.jit(
-                    lambda p, c, t, ln, pfx, tbl, ad, sa:
-                        smodel.paged_prefill(
-                            p, c, t, ln, pfx, tbl, cfg, rules,
-                            adapters=ad, slot_adapter=sa),
-                    donate_argnums=(1,))
-                return pf.lower(
-                    params_sd, cache_sd, sds((bucket,), i32),
-                    sds((), i32), sds((), i32), sds((mb,), i32),
-                    stack_sd, sds((), i32)).compile()
             pf = jax.jit(
-                lambda p, c, t, ln, pfx, tbl: smodel.paged_prefill(
-                    p, c, t, ln, pfx, tbl, cfg, rules),
+                lambda p, c, t, ln, pfx, tbl, *extra: fam.prefill(
+                    p, c, t, ln, pfx, tbl, cfg, rules,
+                    **dict(zip(prefill_extra, extra))),
                 donate_argnums=(1,))
             return pf.lower(
                 params_sd, cache_sd, sds((bucket,), i32),
-                sds((), i32), sds((), i32), sds((mb,), i32)).compile()
+                sds((), i32), sds((), i32), sds((mb,), i32),
+                *prefill_extra.values()).compile()
 
         for bucket in self.prefill_buckets:
             t0 = time.monotonic()
@@ -473,19 +516,21 @@ class ServingEngine:
             self.compile_stats[f"prefill_{bucket}_s"] = round(
                 time.monotonic() - t0, 3)
 
-        t0 = time.monotonic()
+        if fam.copy_block is not None:
+            t0 = time.monotonic()
 
-        def build_copy():
-            cp = jax.jit(smodel.copy_paged_block, donate_argnums=(0,))
-            return cp.lower(
-                cache_sd, sds((), i32), sds((), i32)).compile()
-        self._compiled_copy_block = acquire("copy_block", build_copy)
-        self.compile_stats["copy_block_s"] = round(time.monotonic() - t0, 3)
+            def build_copy():
+                cp = jax.jit(fam.copy_block, donate_argnums=(0,))
+                return cp.lower(
+                    cache_sd, sds((), i32), sds((), i32)).compile()
+            self._compiled_copy_block = acquire("copy_block", build_copy)
+            self.compile_stats["copy_block_s"] = round(
+                time.monotonic() - t0, 3)
 
         t0 = time.monotonic()
 
         def build_sample():
-            sample = jax.jit(smodel.sample)
+            sample = jax.jit(fam.sample)
             return sample.lower(
                 sds((self.slots, cfg.vocab_size), f32),
                 sds((self.slots,), f32),
@@ -540,6 +585,11 @@ class ServingEngine:
         """Copy-on-write device copy: pool block `src` → `dst` across all
         layers (both K and V). The BlockManager decides WHEN (a shared
         block is about to be written); this mirrors it on-device."""
+        if self.family.copy_block is None:
+            raise ValueError(
+                "copy_block: a recurrent state is the lane's own and no "
+                "block of this family's cache is shared, so there is "
+                "nothing to copy on write")
         if self._compiled_decode is None:
             self.compile()
         self._cache = self._compiled_copy_block(
@@ -576,6 +626,11 @@ class ServingEngine:
             raise ValueError("engine has no adapters resident")
         self.set_slot_adapter(slot, adapter)
         length = int(tokens.shape[0])
+        if cached_len and self.family.RECURRENT_STATE:
+            raise ValueError(
+                f"cached_len {cached_len}: a recurrent state cannot be "
+                "rebuilt from shared prefix blocks, so this family "
+                "prefills every prompt whole (serving.prefix_cache: false)")
         if not 0 <= cached_len < length:
             raise ValueError(
                 f"cached_len {cached_len} must leave >= 1 novel token "
@@ -600,6 +655,8 @@ class ServingEngine:
                 np.int32(s_len), np.int32(cached_len), table]
         if self.has_adapters:
             args += [self._adapter_stack, np.int32(adapter)]
+        if self.family.RECURRENT_STATE:
+            args.append(np.int32(slot))
         ph.set(bucket=bucket, novel=s_len)
         self._cache, logits = self._compiled_prefill[bucket](*args)
         self._tables[slot] = table
@@ -611,9 +668,15 @@ class ServingEngine:
         logits; the rest are padding lanes). Fetching the logits is where
         the host waits for the prefill."""
         with trace.phase("serve.admit.first_token"):
-            batch = np.zeros((self.slots, self.cfg.vocab_size), np.float32)
+            if self._first_rows is None:
+                # The padding lanes stay zero: the rows are made once
+                # ([slots, vocab] float32 is 67 MB at 64 x 261,120) and
+                # free again before this returns (the tokens are fetched).
+                self._first_rows = (
+                    np.zeros((self.slots, self.cfg.vocab_size), np.float32),
+                    np.zeros((self.slots,), np.float32))
+            batch, temps = self._first_rows
             batch[0] = np.asarray(logits, np.float32)
-            temps = np.zeros((self.slots,), np.float32)
             temps[0] = temperature
             toks = self._compiled_sample(batch, temps, self._next_rng())
             return int(np.asarray(toks)[0])
@@ -645,9 +708,11 @@ class ServingEngine:
                 logits, np.asarray(temperatures, np.float32),
                 self._next_rng())
             self.decode_steps += 1
+            live = self._tables[:, 0] != self.trash_block
             self.decode_spans += live_spans(
-                positions, self._tables[:, 0] != self.trash_block,
-                self.block_size, self.max_blocks_per_seq)
+                positions, live, self.block_size, self.max_blocks_per_seq)
+            if self.family.RECURRENT_STATE:
+                self.state_lanes += live_lanes(live)
         with trace.phase("serve.step.fetch"):
             return np.asarray(toks)
 
@@ -662,6 +727,7 @@ class ServingEngine:
             "kv_block_size": self.block_size,
             "kv_num_blocks": self.num_blocks,
             "cache_hbm_bytes": self.cache_hbm_bytes(),
+            "state_hbm_bytes": self.state_hbm_bytes(),
             "weights_hbm_bytes": self.weights_hbm_bytes,
             "weights_narrowed_bytes": self.weights_narrowed_bytes,
             "decode_steps": self.decode_steps,
@@ -672,5 +738,10 @@ class ServingEngine:
             # the position, so one count is both.
             "decode_spans_live": self.decode_spans,
             "decode_spans_grid": self.decode_spans,
+            # Lanes whose recurrent state a decode call held live, and
+            # lanes whose state the kernel moved, per layer: its programs
+            # stand on live lanes only, so one count is both.
+            "state_lanes_live": self.state_lanes,
+            "state_lanes_grid": self.state_lanes,
             "compile": dict(self.compile_stats),
         }

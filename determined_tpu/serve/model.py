@@ -36,6 +36,7 @@ backend.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -53,6 +54,16 @@ from determined_tpu.parallel.sharding import LogicalRules, shard_logical
 _COMPUTE_DTYPE_BLOCK_LEAVES = ("qkv", "attn_out", "mlp_up", "mlp_down")
 
 
+def narrowed(x: jax.Array, dtype: Any) -> jax.Array:
+    """A floating leaf wider than `dtype` cast to it; any other leaf, and
+    one already that narrow, as it came (every family's
+    `resident_params`)."""
+    dt = jnp.dtype(dtype)
+    wider = (jnp.issubdtype(x.dtype, jnp.floating)
+             and jnp.dtype(x.dtype).itemsize > dt.itemsize)
+    return x.astype(dt) if wider else x
+
+
 def resident_params(params: Dict[str, Any], cfg: Config) -> Dict[str, Any]:
     """The tree the step functions are to be called with, call after call.
 
@@ -64,13 +75,7 @@ def resident_params(params: Dict[str, Any], cfg: Config) -> Dict[str, Any]:
     hoisted out of the layer scan and run again in every call (PERF.md,
     PR 29). The `.astype` in the step functions is a no-op on this tree
     and keeps direct callers with a float32 tree working."""
-    dt = jnp.dtype(cfg.dtype)
-
-    def narrow(x):
-        wider = (jnp.issubdtype(x.dtype, jnp.floating)
-                 and jnp.dtype(x.dtype).itemsize > dt.itemsize)
-        return x.astype(dt) if wider else x
-
+    narrow = functools.partial(narrowed, dtype=cfg.dtype)
     out = dict(params)
     for name in ("wte", "wpe"):
         out[name] = narrow(params[name])
@@ -378,6 +383,73 @@ def copy_paged_block(
         "k": cache["k"].at[:, dst].set(cache["k"][:, src]),
         "v": cache["v"].at[:, dst].set(cache["v"][:, src]),
     }
+
+
+# ---------------------------------------------------------------- family
+#
+# What the engine asks a serving family (serve/engine.py `family_of`): its
+# cache, its two steps and its block copy, under names every family shares.
+
+RECURRENT_STATE = False      # K/V is the only thing a sequence leaves
+
+
+def config_from(mc: Dict[str, Any]) -> Config:
+    """serving.model_config → the Config: a named size with every
+    architecture dim overridable. The config must reproduce the trained
+    checkpoint's exact shapes or the engine's first trace fails loudly at
+    startup (the intended failure mode for a mismatch)."""
+    base = {
+        "tiny": Config.tiny,
+        "small": Config.small,
+        "medium": Config.medium,
+        "large": Config.large,
+    }[mc.get("model_size", "small")]()
+    dtypes = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
+    seq_len = int(mc.get("seq_len", base.n_positions))
+    return Config(
+        vocab_size=int(mc.get("vocab_size", base.vocab_size)),
+        n_positions=max(int(mc.get("n_positions", base.n_positions)),
+                        seq_len),
+        d_model=int(mc.get("d_model", base.d_model)),
+        n_layer=int(mc.get("n_layer", base.n_layer)),
+        n_head=int(mc.get("n_head", base.n_head)),
+        dtype=dtypes[mc.get("dtype", "bfloat16")],
+        attention_impl="dot",  # decode attends over the KV cache directly
+        num_experts=int(mc.get("num_experts", 1)),
+        moe_top_k=int(mc.get("moe_top_k", 2)),
+    )
+
+
+prefill = paged_prefill
+decode_step = paged_decode_step
+copy_block = copy_paged_block
+
+
+def position_limit(cfg: Config) -> int:
+    """The learned position table bounds the context."""
+    return cfg.n_positions
+
+
+def kernel_refusal(cfg: Config) -> Optional[str]:
+    from determined_tpu.ops.paged_attention import kernel_refusal as refusal
+
+    return refusal(cfg.n_head, cfg.n_head, cfg.head_dim)
+
+
+def init_cache(cfg: Config, pool_blocks: int, block_size: int,
+               slots: int) -> Dict[str, jax.Array]:
+    del slots                # nothing here is indexed by lane
+    return init_paged_cache(cfg, pool_blocks, block_size)
+
+
+def cache_bytes(cfg: Config, pool_blocks: int, block_size: int,
+                slots: int) -> int:
+    del slots
+    return paged_cache_bytes(cfg, pool_blocks, block_size)
+
+
+def state_bytes(cfg: Config, slots: int) -> int:
+    return 0
 
 
 def sample(

@@ -99,44 +99,17 @@ class ReplicaHeartbeat:
 
 
 def build_model(serving: Dict[str, Any]):
-    """serving.model/model_config → a models/* Config (gpt2 family)."""
-    import jax.numpy as jnp
+    """serving.model/model_config → the family's models/* Config, made by
+    the family's own `config_from`; `gpt2` where none is named."""
+    from determined_tpu.serve.engine import family_module
 
-    from determined_tpu.models import gpt2
-
-    family = serving.get("model", "gpt2")
-    if family != "gpt2":
-        raise ValueError(
-            f"unknown serving.model {family!r}; supported: gpt2")
-    mc = dict(serving.get("model_config") or {})
-    size = mc.get("model_size", "small")
-    base = {
-        "tiny": gpt2.Config.tiny,
-        "small": gpt2.Config.small,
-        "medium": gpt2.Config.medium,
-        "large": gpt2.Config.large,
-    }[size]()
-    dtypes = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
-    seq_len = int(mc.get("seq_len", base.n_positions))
-    # Every architecture dim is overridable: the config must reproduce
-    # the trained checkpoint's exact shapes or the engine's first trace
-    # fails loudly at startup (the intended failure mode for a mismatch).
-    return gpt2.Config(
-        vocab_size=int(mc.get("vocab_size", base.vocab_size)),
-        n_positions=max(int(mc.get("n_positions", base.n_positions)),
-                        seq_len),
-        d_model=int(mc.get("d_model", base.d_model)),
-        n_layer=int(mc.get("n_layer", base.n_layer)),
-        n_head=int(mc.get("n_head", base.n_head)),
-        dtype=dtypes[mc.get("dtype", "bfloat16")],
-        attention_impl="dot",  # decode attends over the KV cache directly
-        num_experts=int(mc.get("num_experts", 1)),
-        moe_top_k=int(mc.get("moe_top_k", 2)),
-    )
+    return family_module(serving.get("model", "gpt2")).config_from(
+        dict(serving.get("model_config") or {}))
 
 
 def serving_signature(serving: Dict[str, Any],
-                      params: Optional[Dict[str, Any]] = None) -> str:
+                      params: Optional[Dict[str, Any]] = None,
+                      cache: Optional[Dict[str, Any]] = None) -> str:
     """Compile-farm signature for a serving config: every shape-affecting
     knob (model geometry, slots, buckets, paged-KV layout) plus the
     runtime tag, so two replicas of the same deployment — or a respawn
@@ -144,7 +117,8 @@ def serving_signature(serving: Dict[str, Any],
     change can never load a stale executable. `params` is the engine's
     resident tree: the executables take its leaves as arguments, so its
     dtypes and shapes (a float32 or a bfloat16 checkpoint, and what the
-    engine narrowed at load) are part of the key."""
+    engine narrowed at load) are part of the key; `cache` is the engine's
+    `cache_avals()`, every pool the executables carry."""
     import hashlib
 
     from determined_tpu.compile.signature import runtime_tag
@@ -154,6 +128,11 @@ def serving_signature(serving: Dict[str, Any],
                   "attention_impl", "seed", "adapters")
     key = {k: serving.get(k) for k in shape_keys}
     key["runtime_tag"] = runtime_tag()
+    if cache is not None:
+        # every pool the executables carry: a family with recurrent state
+        # has a state pool beside the paged one, shaped by the lanes
+        key["cache_avals"] = {name: f"{x.dtype}{list(x.shape)}"
+                              for name, x in sorted(cache.items())}
     if params is not None:
         import jax
 
@@ -178,7 +157,7 @@ def build_replica(config: Dict[str, Any], session=None):
     CLI mode, tests, and the bench."""
     from determined_tpu.core._checkpoint import CheckpointContext
     from determined_tpu.serve.engine import (
-        ServingEngine, load_checkpoint_params)
+        ServingEngine, family_of, load_checkpoint_params)
     from determined_tpu.serve.kv_cache import BlockManager
     from determined_tpu.serve.scheduler import (
         AdmissionQueue, ContinuousBatcher)
@@ -186,6 +165,17 @@ def build_replica(config: Dict[str, Any], session=None):
 
     serving = config.get("serving") or {}
     cfg = build_model(serving)
+    prefix_cache = bool(serving.get("prefix_cache", True))
+    if family_of(cfg).RECURRENT_STATE:
+        # A recurrent state is the whole prefix folded into one tensor a
+        # lane: it cannot be rebuilt from shared prefix blocks.
+        if "prefix_cache" not in serving:
+            prefix_cache = False
+        elif prefix_cache:
+            raise ValueError(
+                "serving.prefix_cache: true — a family with recurrent "
+                "state cannot share prefix blocks (the state after a "
+                "prefix is not in them); set prefix_cache: false")
     storage = from_config(config.get("checkpoint_storage"))
     ckpt_ctx = CheckpointContext(
         session, storage, trial_id=_trial_id_for(serving), async_save=False)
@@ -211,7 +201,8 @@ def build_replica(config: Dict[str, Any], session=None):
         adapters[str(a["name"])] = load_checkpoint_params(a_ctx, a_ckpt)
 
     slots = int(serving.get("max_batch_size", 8))
-    max_seq = int(serving.get("max_seq_len", min(cfg.n_positions, 1024)))
+    max_seq = int(serving.get(
+        "max_seq_len", min(family_of(cfg).position_limit(cfg) or 1024, 1024)))
     block_size = int(serving.get("kv_block_size", 16))
     num_blocks = serving.get("kv_num_blocks")
     engine = ServingEngine(
@@ -234,11 +225,12 @@ def build_replica(config: Dict[str, Any], session=None):
 
         engine.farm = FarmClient(
             session=session,
-            signature=serving_signature(serving, engine.params))
+            signature=serving_signature(serving, engine.params,
+                                        engine.cache_avals()))
     # The device pool IS the budget: the manager mirrors it exactly.
     blocks = BlockManager(
         num_blocks=engine.num_blocks, block_size=engine.block_size,
-        prefix_cache=bool(serving.get("prefix_cache", True)))
+        prefix_cache=prefix_cache)
     queue = AdmissionQueue(maxsize=int(serving.get("queue_depth", 64)))
     batcher = ContinuousBatcher(engine, queue=queue, block_manager=blocks)
     return engine, batcher

@@ -106,6 +106,51 @@ def test_paged_decode_lowers_through_mosaic(v5e, geometry, dtype):
     assert hlo.count("tpu_custom_call") == 1
 
 
+def test_paged_decode_with_shared_kv_heads_lowers_through_mosaic(v5e):
+    """serve-h1-decode's attention: 20 query heads over 4 K/V heads of
+    128, the pool's row 512 lanes; a group is a K/V head and its five
+    query heads are its rows."""
+    from determined_tpu.ops.paged_attention import paged_attention_pallas
+
+    slots, hq, hkv, dh, bs, mb = 64, 20, 4, 128, 16, 64
+    mesh = _mesh(v5e[:1], data=1)
+    pool = _sds(mesh, (6, 2561, bs, hkv * dh), jnp.bfloat16)
+    hlo = jax.jit(paged_attention_pallas).lower(
+        _sds(mesh, (slots, hq, dh), jnp.bfloat16), pool, pool,
+        _sds(mesh, (), jnp.int32), _sds(mesh, (slots, mb), jnp.int32),
+        _sds(mesh, (slots,), jnp.int32)).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 1
+    assert "bf16[64,4,5,128]" in hlo
+
+
+def test_state_kernel_lowers_through_mosaic_and_moves_no_pool(v5e):
+    """The recurrent-state update at serve-h1-decode's geometry (64 lanes
+    of 32 x 256 x 128 float32, 6 layers: a 1.61 GB pool): Mosaic takes
+    it, the pool is aliased onto the output, and the call keeps nothing
+    of the pool's size beside it. The custom call bears the name the
+    benchmark's reader looks for."""
+    from determined_tpu.ops.ssm_state import ssm_state_update
+
+    slots, heads, groups, p, n = 64, 32, 2, 128, 256
+    mesh = _mesh(v5e[:1], data=1)
+    pool = _sds(mesh, (6, slots, heads, n, p), jnp.float32)
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        compiled = jax.jit(ssm_state_update, donate_argnums=(4,)).lower(
+            _sds(mesh, (slots, heads, p), jnp.bfloat16),
+            _sds(mesh, (slots, heads), jnp.float32),
+            _sds(mesh, (slots, groups, n), jnp.bfloat16),
+            _sds(mesh, (slots, groups, n), jnp.bfloat16), pool,
+            _sds(mesh, (), jnp.int32), _sds(mesh, (slots,), jnp.bool_),
+            _sds(mesh, (heads,), jnp.float32)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 1
+    assert re.search(r"%ssm_state_update[.\d]* = \(f32\[64,4,8,128\]", hlo)
+    memory = compiled.memory_analysis()
+    pool_bytes = 6 * slots * heads * n * p * 4
+    assert memory.alias_size_in_bytes == pool_bytes
+    assert memory.temp_size_in_bytes < pool_bytes // 1000
+
+
 def _opcodes_with_result(hlo, shapes):
     """Instructions of a compiled module (fused computations' bodies too)
     whose result has one of `shapes`, by opcode → count."""

@@ -796,9 +796,14 @@ def test_kernel_geometry_auto_falls_back_and_explicit_pallas_raises(
     from determined_tpu.parallel import mesh
     from determined_tpu.serve.engine import resolve_attention_impl
 
-    assert kernel_refusal(20, 64) is None and kernel_refusal(2, 128) is None
-    assert "multiple of 128" in kernel_refusal(TINY.n_head, TINY.head_dim)
-    assert "head dim 96" in kernel_refusal(4, 96)
+    assert kernel_refusal(20, 20, 64) is None
+    assert kernel_refusal(2, 2, 128) is None
+    assert kernel_refusal(20, 4, 128) is None   # query heads share K/V heads
+    assert "multiple of 128" in kernel_refusal(
+        TINY.n_head, TINY.n_head, TINY.head_dim)
+    assert "head dim 96" in kernel_refusal(4, 4, 96)
+    assert "whole 128-lane groups" in kernel_refusal(8, 2, 64)
+    assert "do not divide" in kernel_refusal(20, 3, 128)
     monkeypatch.setattr(mesh, "on_tpu", lambda *a, **k: True)
     assert resolve_attention_impl("auto", KERNEL_TINY) == "pallas"
     assert resolve_attention_impl("auto", TINY) == "reference"
