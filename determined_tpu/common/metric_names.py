@@ -258,8 +258,9 @@ SPAN_NAMES: Dict[str, Tuple[str, str]] = {
         "serve", "Phase: host side of one prefill up to and with its "
         "enqueue; bucket, novel tokens, slot, request"),
     "serve.admit.first_token": (
-        "serve", "Phase: fetch of the prefill's logits (the host waits "
-        "for the device here) and sampling of the first token"),
+        "serve", "Phase: the 4-byte fetch of the first token, which the "
+        "prefill call sampled itself (the host waits for the prefill "
+        "here)"),
     "serve.loop.step": (
         "serve", "Phase: one decode step of the batcher; lanes, slots "
         "and gaps_ms (each live lane's wait since its previous token), "

@@ -293,7 +293,6 @@ class ServingEngine:
         self._compiled_decode = None
         self._compiled_prefill: Dict[int, Any] = {}
         self._compiled_sample = None
-        self._first_rows = None   # host [slots, vocab] for the first token
         self._compiled_copy_block = None
         self.compile_stats: Dict[str, float] = {
             "weights_hbm_bytes": self.weights_hbm_bytes,
@@ -309,6 +308,9 @@ class ServingEngine:
         self.decode_steps = 0
         self.prefills = 0
         self.block_copies = 0
+        # Bytes that crossed between host and device to sample first
+        # tokens: the prefill call samples its own, so 4 a prefill.
+        self.first_token_host_bytes = 0
         # What the decode kernel walks, per layer, summed over decode
         # calls (ops/paged_attention.py: a lane's 128-token spans up to
         # its position; an idle lane none), and the lanes whose recurrent
@@ -498,21 +500,36 @@ class ServingEngine:
         self._compiled_decode = acquire("decode", build_decode)
         self.compile_stats["decode_s"] = round(time.monotonic() - t0, 3)
 
+        def prefill_and_first(p, c, t, ln, pfx, tbl, temp, key, step,
+                              *extra):
+            """The family's prefill and, in the same call, its sampler
+            over the one row it produced: the token is on the device
+            before the host could have fetched the logits. Temperature,
+            base key and step counter are operands (the key is folded
+            here, as `_next_rng` folds it for decode), so a bucket is one
+            executable. The logits stay an output, left on the device."""
+            cache, logits = fam.prefill(
+                p, c, t, ln, pfx, tbl, cfg, rules,
+                **dict(zip(prefill_extra, extra)))
+            first = fam.sample(logits[None], temp[None],
+                               jax.random.fold_in(key, step))[0]
+            return cache, first, logits
+
         def build_prefill(bucket):
-            pf = jax.jit(
-                lambda p, c, t, ln, pfx, tbl, *extra: fam.prefill(
-                    p, c, t, ln, pfx, tbl, cfg, rules,
-                    **dict(zip(prefill_extra, extra))),
-                donate_argnums=(1,))
+            pf = jax.jit(prefill_and_first, donate_argnums=(1,))
             return pf.lower(
                 params_sd, cache_sd, sds((bucket,), i32),
                 sds((), i32), sds((), i32), sds((mb,), i32),
+                sds((), f32), sds((2,), np.uint32), sds((), i32),
                 *prefill_extra.values()).compile()
 
         for bucket in self.prefill_buckets:
             t0 = time.monotonic()
+            # Keyed by the call's contract as well as its bucket (the
+            # serving signature keys shapes, not outputs): an artifact of
+            # a build whose prefill returned logits alone never loads here.
             self._compiled_prefill[bucket] = acquire(
-                f"prefill_{bucket}", lambda: build_prefill(bucket))
+                f"prefill_tok_{bucket}", lambda: build_prefill(bucket))
             self.compile_stats[f"prefill_{bucket}_s"] = round(
                 time.monotonic() - t0, 3)
 
@@ -613,15 +630,19 @@ class ServingEngine:
             self.compile()
         with trace.phase("serve.admit.prefill", slot=slot,
                          request=request_id) as ph:
-            logits = self._enqueue_prefill(
-                ph, slot, tokens, block_table, cached_len, adapter)
-        return self._sample_first(logits, temperature)
+            first, _ = self._enqueue_prefill(
+                ph, slot, tokens, temperature, block_table, cached_len,
+                adapter)
+        return self._sample_first(first, temperature)
 
     def _enqueue_prefill(self, ph, slot: int, tokens: np.ndarray,
+                         temperature: float,
                          block_table: Optional[Sequence[int]],
                          cached_len: int, adapter: int):
-        """Host work of a prefill up to and with its enqueue → the logits,
-        still on the device."""
+        """Host work of a prefill up to and with its enqueue → the first
+        token, sampled in that call, and the logits it was sampled from,
+        both still on the device (the serving path fetches the token
+        alone)."""
         if adapter and not self.has_adapters:
             raise ValueError("engine has no adapters resident")
         self.set_slot_adapter(slot, adapter)
@@ -651,35 +672,30 @@ class ServingEngine:
                 f"bucket ({self.prefill_buckets[-1]})")
         padded = np.zeros((bucket,), np.int32)
         padded[:s_len] = suffix
+        self._step_counter += 1      # this call's fold of the key
         args = [self.params, self._cache, padded,
-                np.int32(s_len), np.int32(cached_len), table]
+                np.int32(s_len), np.int32(cached_len), table,
+                np.float32(temperature), self._rng,
+                np.int32(self._step_counter)]
         if self.has_adapters:
             args += [self._adapter_stack, np.int32(adapter)]
         if self.family.RECURRENT_STATE:
             args.append(np.int32(slot))
         ph.set(bucket=bucket, novel=s_len)
-        self._cache, logits = self._compiled_prefill[bucket](*args)
+        self._cache, first, logits = self._compiled_prefill[bucket](*args)
         self._tables[slot] = table
         self.prefills += 1
-        return logits
+        return first, logits
 
-    def _sample_first(self, logits, temperature: float) -> int:
-        """Sample via the slot-wide compiled sampler (slot 0 carries the
-        logits; the rest are padding lanes). Fetching the logits is where
-        the host waits for the prefill."""
+    def _sample_first(self, first, temperature: float) -> int:
+        """Fetch the token the prefill call sampled (with `temperature`,
+        which went in as its operand): four bytes, and where the host
+        waits for the prefill."""
+        del temperature
         with trace.phase("serve.admit.first_token"):
-            if self._first_rows is None:
-                # The padding lanes stay zero: the rows are made once
-                # ([slots, vocab] float32 is 67 MB at 64 x 261,120) and
-                # free again before this returns (the tokens are fetched).
-                self._first_rows = (
-                    np.zeros((self.slots, self.cfg.vocab_size), np.float32),
-                    np.zeros((self.slots,), np.float32))
-            batch, temps = self._first_rows
-            batch[0] = np.asarray(logits, np.float32)
-            temps[0] = temperature
-            toks = self._compiled_sample(batch, temps, self._next_rng())
-            return int(np.asarray(toks)[0])
+            token = np.asarray(first)
+            self.first_token_host_bytes += token.nbytes
+            return int(token)
 
     def release_slot(self, slot: int) -> None:
         """Point a retired slot's table at the trash block so later
@@ -733,6 +749,7 @@ class ServingEngine:
             "decode_steps": self.decode_steps,
             "prefills": self.prefills,
             "block_copies": self.block_copies,
+            "first_token_host_bytes": self.first_token_host_bytes,
             # Spans that held a visible key, and spans the kernel was
             # launched over: its grid is over lanes and its loop ends at
             # the position, so one count is both.

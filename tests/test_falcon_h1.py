@@ -259,8 +259,9 @@ def _replica(config, params, prefix_cache=False, **engine_kwargs):
 
 class _Logits:
     """Stands where the engine samples and keeps the logits it sampled
-    from: a prefill's with its lane and prompt, a decode call's with its
-    positions."""
+    from: a prefill's (the call's third output, which the serving path
+    leaves on the device) with its lane, its prompt and the token the
+    call sampled, a decode call's with its positions."""
 
     def __init__(self, engine):
         self.events, self.positions = [], None
@@ -268,20 +269,18 @@ class _Logits:
                                    engine._compiled_sample)
 
         def enqueue_prefill(ph, slot, tokens, *args):
-            logits = prefill(ph, slot, tokens, *args)
+            first, logits = prefill(ph, slot, tokens, *args)
             self.events.append(("prefill", slot, tuple(tokens.tolist()),
-                                np.asarray(logits)))
-            return logits
+                                np.asarray(logits), int(first)))
+            return first, logits
 
         def decode_call(tokens, positions, temperatures):
             self.positions = np.array(positions)
             return decode(tokens, positions, temperatures)
 
         def compiled_sample(logits, temps, rng):
-            if self.positions is not None:    # a decode call's, not the
-                self.events.append(           # first token's padded row
-                    ("decode", self.positions, np.asarray(logits)))
-                self.positions = None
+            self.events.append(
+                ("decode", self.positions, np.asarray(logits)))
             return sample(logits, temps, rng)
 
         engine._enqueue_prefill = enqueue_prefill
@@ -329,11 +328,13 @@ def test_prefill_then_decode_through_the_batcher_gives_the_references_logits():
     owner, prefills, compared = {}, {}, 0
     for event in seen.events:
         if event[0] == "prefill":
-            _, slot, prompt, logits = event
+            _, slot, prompt, logits, first = event
             owner[slot] = row[prompt]
             prefills[slot] = prefills.get(slot, 0) + 1
             np.testing.assert_allclose(
                 logits, ref[row[prompt], len(prompt) - 1], atol=2e-4)
+            assert first == int(np.argmax(logits))
+            assert first == requests[row[prompt]].out_tokens[0]
             compared += 1
         else:
             _, positions, logits = event
@@ -347,6 +348,30 @@ def test_prefill_then_decode_through_the_batcher_gives_the_references_logits():
     stats = engine.stats()
     assert stats["state_lanes_live"] == sum(n - 1 for _, n in shapes)
     assert stats["state_lanes_grid"] == stats["state_lanes_live"]
+    assert stats["first_token_host_bytes"] == 4 * len(shapes)
+
+
+def test_first_token_is_sampled_in_the_prefill_call():
+    """The engine's one first-token path in this family: greedy is the
+    argmax of the reference's logits, a temperature draws from them under
+    the engine's key for that step, four bytes a prefill come back."""
+    from tests.test_serving import check_first_tokens
+
+    config = tiny_config()
+    config["serve"] = dict(config["serve"], dtype="float32")
+    params, dims = float_params(config, seed=5, scale=6.0)
+    engine, _ = _replica(config, params, seed=11)
+    rng = np.random.default_rng(6)
+    prompts = [rng.integers(0, dims["vocab_size"], n, np.int32)
+               for n in (5, 16, 19)]
+    calls = [dict(slot=slot, tokens=p,
+                  block_table=[slot * 4 + i for i in range(4)])
+             for slot, p in enumerate(prompts)]
+    reference = [
+        np.asarray(ADAPTER.logits(
+            params, p[None], np.arange(len(p), dtype=np.int32)[None],
+            dims))[0, -1] for p in prompts]
+    check_first_tokens(engine, calls, reference, seed=11)
 
 
 def test_idle_lanes_keep_their_state_and_counters_count_the_state_pool(

@@ -312,9 +312,11 @@ def test_resident_weights_serve_bit_for_bit_what_the_f32_tree_does(
         p, c, t, pos, tables, cfg))
 
     cache, want = pf(tiny_params, cache, padded)
-    eng._cache, got = eng._compiled_prefill[8](
-        eng.params, eng._cache, padded, length, np.int32(0), table)
+    eng._cache, tok, got = eng._compiled_prefill[8](
+        eng.params, eng._cache, padded, length, np.int32(0), table,
+        np.float32(0), eng._rng, np.int32(1))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert int(tok) == int(np.argmax(np.asarray(want)))
     tokens = np.zeros((2,), np.int32)
     positions = np.zeros((2,), np.int32)
     greedy = [int(np.argmax(np.asarray(want)))]
@@ -333,6 +335,82 @@ def test_resident_weights_serve_bit_for_bit_what_the_f32_tree_does(
         served.append(int(eng.decode(
             tokens, positions, np.zeros((2,), np.float32))[0]))
     assert served == greedy
+
+
+def check_first_tokens(engine, calls, reference, seed=0):
+    """The first token's contract, for any family (also
+    tests/test_falcon_h1.py): the prefill call samples its own. Each of
+    `calls` (keywords of `prefill_request`) runs greedy and then at a
+    temperature; `reference[i]` is call i's logits by a plain forward.
+    Greedy returns their argmax; a temperature returns
+    `jax.random.categorical` of the call's logits / T under the engine's
+    key folded with that call's step; four bytes a prefill cross to the
+    host, and the engine keeps no `[slots, vocab]` rows there."""
+    kept = []
+    enqueue = engine._enqueue_prefill
+
+    def keep_logits(*args):
+        first, logits = enqueue(*args)
+        kept.append(np.asarray(logits))
+        return first, logits
+
+    engine._enqueue_prefill = keep_logits
+    bytes_before = engine.stats()["first_token_host_bytes"]
+    sampled, greedy = [], []
+    for temperature in (0.0, 0.7):
+        for call, want in zip(calls, reference):
+            step = engine._step_counter + 1
+            token = engine.prefill_request(temperature=temperature, **call)
+            np.testing.assert_allclose(kept[-1], want, atol=2e-4)
+            if not temperature:
+                assert token == int(np.argmax(want))
+                greedy.append(token)
+                continue
+            key = jax.random.fold_in(jax.random.PRNGKey(seed), step)
+            assert token == int(jax.random.categorical(
+                key, kept[-1][None] / np.float32(temperature), axis=-1)[0])
+            sampled.append(token)
+    assert sampled != greedy             # the temperature was an operand
+    assert (engine.stats()["first_token_host_bytes"] - bytes_before
+            == 4 * len(kept) == 8 * len(calls))
+    rows = engine.slots * engine.cfg.vocab_size
+    assert not [leaf for leaf in jax.tree_util.tree_leaves(vars(engine))
+                if isinstance(leaf, np.ndarray) and leaf.size >= rows]
+
+
+@pytest.mark.parametrize("arm", ["base", "adapter_and_cached_prefix"])
+def test_first_token_is_sampled_in_the_prefill_call(tiny_params,
+                                                    finetuned_params, arm):
+    prompts = [np.array([5, 9, 17, 3], np.int32),
+               np.arange(1, 21, dtype=np.int32),
+               np.array([7] * 9, np.int32)]
+    if arm == "base":
+        eng = make_engine(tiny_params, slots=4)
+        calls = [dict(slot=i, tokens=p) for i, p in enumerate(prompts)]
+        trees = [tiny_params] * 3
+    else:
+        # Lane 0 fills two blocks with the fine-tune's keys; lane 1 takes
+        # them as its cached prefix and prefills its four novel tokens.
+        eng = ServingEngine(tiny_params, TINY, slots=4, max_seq_len=32,
+                            prefill_buckets=[8, 16, 32], kv_block_size=8,
+                            adapters={"ft": finetuned_params}, seed=3)
+        shared = np.arange(1, 21, dtype=np.int32)
+        eng.prefill_request(0, shared, block_table=[0, 1, 2, 3], adapter=1)
+        prompts[1] = np.concatenate([shared[:16], [40, 41, 42, 43]]
+                                    ).astype(np.int32)
+        calls = [dict(slot=1, tokens=prompts[1], block_table=[0, 1, 4, 5],
+                      cached_len=16, adapter=1),
+                 dict(slot=2, tokens=prompts[0], adapter=1,
+                      block_table=[6, 7, 8, 9]),
+                 dict(slot=3, tokens=prompts[2], adapter=0,
+                      block_table=[10, 11, 12, 13])]
+        prompts = [prompts[1], prompts[0], prompts[2]]
+        trees = [finetuned_params, finetuned_params, tiny_params]
+    reference = [
+        np.asarray(gpt2.apply(tree, jnp.asarray(p)[None], TINY))[0, -1]
+        for tree, p in zip(trees, prompts)]
+    check_first_tokens(eng, calls, reference,
+                       seed=0 if arm == "base" else 3)
 
 
 def test_engine_warm_aot_deserializes_on_second_boot(tiny_params, tmp_path):
