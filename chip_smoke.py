@@ -47,6 +47,7 @@ Other modes (builder's tools, not what the driver runs):
 """
 
 import argparse
+import functools
 import json
 import math
 import os
@@ -245,6 +246,66 @@ def phase_kernels(args, out):
     check(err <= 1e-5,
           f"state kernel vs ssm_state_reference, {slots} lanes of {heads} "
           f"x {n} x {d} float32: {err:.2e} (tol 1e-5)")
+
+    # the latent decode kernel and the expert layer's grouped matmul at
+    # GLM-4.7-Flash's published widths: 20 heads against a 640-lane latent row
+    # (512 + 64) at contexts up to 3,584 tokens, idle lanes among live
+    # ones; 256 assignments over 64 experts of 2,048 x 1,536, top-4.
+    from determined_tpu.ops import mla_attention as mla
+    from determined_tpu.ops import moe
+
+    slots, heads, rank, rope, mb = 64, 20, 512, 64, 224
+    row = mla.latent_row(rank, rope)
+    idle = [3, 17, 62, 63]
+    live = np.ones(slots, bool)
+    live[idle] = False
+    latents = np.zeros((2, slots * mb + 1, bs, row), np.float32)
+    latents[..., :rank + rope] = rng.normal(
+        size=latents.shape[:-1] + (rank + rope,))
+    latents = jnp.asarray(latents, jnp.bfloat16)
+    tbl = rng.permutation(slots * mb).reshape(slots, mb).astype(np.int32)
+    tbl[idle] = slots * mb
+    pos = np.where(live, rng.integers(2048, 3584, slots), 0).astype(np.int32)
+    pos[:3] = (0, 127, 3583)
+    q = mla.absorbed_query(
+        jnp.asarray(rng.normal(size=(slots, heads, rank)), jnp.bfloat16),
+        jnp.asarray(rng.normal(size=(slots, heads, rope)), jnp.bfloat16),
+        row)
+    scale = 256 ** -0.5
+    got, want = (np.asarray(
+        jax.jit(attend, static_argnums=(5, 6))(
+            q, latents, layer, tbl, pos, rank, scale), np.float32)
+        for attend in (mla.mla_decode_attention,
+                       mla.mla_attention_reference))
+    err = rel_err(got[live], want[live])
+    out["mla_decode"] = {"rel_err": round(err, 5)}
+    check(not got[idle].any() and err <= FWD_TOL,
+          f"latent decode kernel vs mla_attention_reference, {heads} heads "
+          f"x {row} lanes at contexts to 3,584: {err:.4f} (tol {FWD_TOL})")
+
+    experts, d_model, width, top_k = 64, 2048, 1536, 4
+    x = jnp.asarray(rng.normal(size=(slots, d_model)), jnp.bfloat16)
+    layer_params = {
+        "router": jnp.asarray(rng.normal(size=(d_model, experts)) * 0.02,
+                              jnp.bfloat16),
+        "router_bias": jnp.asarray(rng.normal(size=(experts,)) * 0.02,
+                                   jnp.bfloat16),
+        "w13": jnp.asarray(rng.normal(size=(2, experts, d_model, 2 * width))
+                           * 0.02, jnp.bfloat16),
+        "w2": jnp.asarray(rng.normal(size=(2, experts, width, d_model))
+                          * 0.02, jnp.bfloat16)}
+    got, want = (jax.jit(functools.partial(
+        moe.dropless_moe, top_k=top_k, routed_scaling_factor=1.8,
+        impl=impl))(x, layer_params, layer=layer)
+        for impl in ("pallas", "reference"))
+    err = rel_err(got[0], want[0])
+    out["moe_grouped_matmul"] = {"rel_err": round(err, 5),
+                                 "assignments": int(got[1].sum())}
+    check(np.array_equal(np.asarray(got[1]), np.asarray(want[1]))
+          and int(got[1].sum()) == slots * top_k and err <= FWD_TOL,
+          f"dropless expert layer, kernel vs lax.ragged_dot, {slots * top_k}"
+          f" assignments over {experts} experts of {d_model} x {width}: "
+          f"{err:.4f} (tol {FWD_TOL})")
 
 
 # -------------------------------------------------------------------- train

@@ -163,6 +163,21 @@ SERVE_METRICS: Dict[str, Tuple[str, str]] = {
     "det_serve_requests_total": ("counter", "Requests completed"),
     "det_serve_tokens_total": ("counter", "Tokens generated"),
     "det_serve_draining": ("gauge", "1 while draining, else 0"),
+    # Engine counters (`engine.stats()`, docs/serving.md "Latent attention
+    # & routed experts"); 0 for a family without the mechanism.
+    "det_serve_prefix_hit_tokens_total": (
+        "counter", "Prompt tokens whose cache entries a prefill found "
+        "and did not recompute"),
+    "det_serve_prefix_novel_tokens_total": (
+        "counter", "Prompt tokens prefills ran through the model"),
+    "det_serve_moe_assignments_total": (
+        "counter", "Token-to-expert assignments computed (tokens run x "
+        "experts per token x expert layers)"),
+    "det_serve_moe_expert_load_max": (
+        "gauge", "Assignments the busiest (layer, expert) has drawn, from "
+        "the load leaf the steps keep on the device"),
+    "det_serve_latent_hbm_bytes": (
+        "gauge", "HBM of the latent (MLA) pool as allocated"),
     # Token-latency SLO histograms (docs/serving.md "Request latency &
     # SLOs") — also on the replica heartbeat, aggregated per deployment.
     "det_serve_ttft_seconds": (

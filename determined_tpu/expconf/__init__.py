@@ -452,6 +452,7 @@ def _validate_registry(block: Any, serving: Any,
 SERVING_FAMILIES = {
     "gpt2": "determined_tpu.serve.model",
     "falcon_h1": "determined_tpu.serve.falcon_h1",
+    "glm4_moe_lite": "determined_tpu.serve.glm4_moe_lite",
 }
 
 

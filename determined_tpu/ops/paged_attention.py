@@ -229,14 +229,23 @@ def live_spans(positions, live, block_size: int, max_blocks: int) -> int:
     return int(np.sum((np.asarray(positions) // tile + 1)[np.asarray(live)]))
 
 
-def _paged_kernel(tbl_ref, pos_ref, lay_ref, q_ref, k_hbm, v_hbm, o_ref,
-                  kbuf, vbuf, sems, flight, acc_ref, m_ref, l_ref, *,
-                  block_size, span, scale):
+def walk_live_spans(tbl_ref, pos_ref, layer, pools, sems, flight, *,
+                    block_size, span, fold, first_program=None):
+    """The walk every paged decode kernel makes over its lane's table (the
+    grid is over lanes): a span of `span` blocks at a time up to the
+    lane's position, the span's live blocks copied by hand from each pool
+    of `pools` — `(pool in HBM, [2, span*block_size, row] VMEM buffer)`
+    pairs, copied alike under `sems[buffer, pool]` — and `fold(i, buf)`
+    called with span i resident in buffer `buf`. Span i+1 is in flight
+    while span i is folded, and behind a lane's last fold the NEXT live
+    lane's first span is started (`flight`, two SMEM words, carries "which
+    buffer, whose span" from program to program). An idle lane — its
+    table the trash block throughout — copies and folds nothing.
+    `first_program()` runs once, in the first program, before any copy.
+    → the lane's number of spans."""
     s, slots = pl.program_id(0), pl.num_programs(0)
-    groups, _, width = q_ref.shape[1:]
     tile = span * block_size
-    trash = k_hbm.shape[1] - 1
-    layer = lay_ref[0]
+    trash = pools[0][0].shape[1] - 1
 
     def spans_of(lane):
         """Spans that hold a visible key: none for an idle lane, whose
@@ -245,16 +254,15 @@ def _paged_kernel(tbl_ref, pos_ref, lay_ref, q_ref, k_hbm, v_hbm, o_ref,
                          pos_ref[lane] // tile + 1)
 
     def copy_blocks(lane, i, buf, act):
-        """Start or wait for (`act`) the copies of the K and V blocks of
-        span i of `lane` into buffer `buf`, the blocks up to the lane's
-        position and no others: a block is `block_size` rows of the pool,
-        so the span's blocks stack as rows of one tile."""
+        """Start or wait for (`act`) the copies of the blocks of span i
+        of `lane` into buffer `buf`, the blocks up to the lane's position
+        and no others: a block is `block_size` rows of a pool, so the
+        span's blocks stack as rows of one tile."""
         def block(j, _):
             at = tbl_ref[lane, i * span + j]
             rows = pl.ds(pl.multiple_of(j * block_size, block_size),
                          block_size)
-            for which, (hbm, vmem) in enumerate(((k_hbm, kbuf),
-                                                 (v_hbm, vbuf))):
+            for which, (hbm, vmem) in enumerate(pools):
                 act(pltpu.make_async_copy(
                     hbm.at[layer, at], vmem.at[buf, rows],
                     sems.at[buf, which]))
@@ -271,12 +279,8 @@ def _paged_kernel(tbl_ref, pos_ref, lay_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     @pl.when(s == 0)
     def _first_program():
-        # A block past a span's last live one is not fetched, and its
-        # rows of the tile keep what an earlier span left. A stale key
-        # gives a logit the mask replaces; a stale value is multiplied by
-        # a masked probability, exactly 0, and must not be the NaN of
-        # uninitialised memory: zeros first.
-        vbuf[...] = jnp.zeros_like(vbuf)
+        if first_program is not None:
+            first_program()
         flight[0] = 0    # the buffer the next first span lands in
         flight[1] = -1   # the lane whose first span is already in flight
 
@@ -287,16 +291,7 @@ def _paged_kernel(tbl_ref, pos_ref, lay_ref, q_ref, k_hbm, v_hbm, o_ref,
     def _own_first_span():
         start(s, 0, buf0)
 
-    init_softmax_scratch(acc_ref, m_ref, l_ref)
-    pos = pos_ref[s]
-
-    def by_group(ref, buf):
-        """The tile's `[span*bs, H*Dh]` rows as `[G, span*bs, W]`: static
-        lane-aligned slices, no transpose."""
-        return jnp.stack([ref[buf, :, g * width:(g + 1) * width]
-                          for g in range(groups)])
-
-    def fold(i, _):
+    def step(i, _):
         buf = (buf0 + i) % 2
 
         @pl.when(i + 1 < n)
@@ -318,6 +313,35 @@ def _paged_kernel(tbl_ref, pos_ref, lay_ref, q_ref, k_hbm, v_hbm, o_ref,
                 start(jnp.minimum(nxt, slots - 1), 0, 1 - buf)
 
         wait(s, i, buf)
+        fold(i, buf)
+
+    jax.lax.fori_loop(0, n, step, None)
+    flight[0] = (buf0 + n) % 2
+    return n
+
+
+def _paged_kernel(tbl_ref, pos_ref, lay_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  kbuf, vbuf, sems, flight, acc_ref, m_ref, l_ref, *,
+                  block_size, span, scale):
+    groups, _, width = q_ref.shape[1:]
+    tile = span * block_size
+    pos = pos_ref[pl.program_id(0)]
+
+    def zero_values():
+        # A block past a span's last live one is not fetched, and its
+        # rows of the tile keep what an earlier span left. A stale key
+        # gives a logit the mask replaces; a stale value is multiplied by
+        # a masked probability, exactly 0, and must not be the NaN of
+        # uninitialised memory: zeros first.
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    def by_group(ref, buf):
+        """The tile's `[span*bs, H*Dh]` rows as `[G, span*bs, W]`: static
+        lane-aligned slices, no transpose."""
+        return jnp.stack([ref[buf, :, g * width:(g + 1) * width]
+                          for g in range(groups)])
+
+    def fold(i, buf):
         st = jax.lax.dot_general(
             q_ref[0], by_group(kbuf, buf), (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale      # [G, per, tile]
@@ -327,8 +351,11 @@ def _paged_kernel(tbl_ref, pos_ref, lay_ref, q_ref, k_hbm, v_hbm, o_ref,
         online_softmax_update(st, by_group(vbuf, buf), acc_ref, m_ref,
                               l_ref, (((2,), (1,)), ((0,), (0,))))
 
-    jax.lax.fori_loop(0, n, fold, None)
-    flight[0] = (buf0 + n) % 2
+    init_softmax_scratch(acc_ref, m_ref, l_ref)
+    n = walk_live_spans(
+        tbl_ref, pos_ref, lay_ref[0], ((k_hbm, kbuf), (v_hbm, vbuf)), sems,
+        flight, block_size=block_size, span=span, fold=fold,
+        first_program=zero_values)
 
     @pl.when(n > 0)
     def _finish():

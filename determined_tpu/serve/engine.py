@@ -21,10 +21,12 @@ Owns everything device-side for one serve replica:
 The engine is one for every family: what is a family's own — the
 resident tree, the cache, prefill, the decode step, the block copy — it
 takes from the family's serving module (`family_of`: `serve/model.py` for
-GPT-2, `serve/falcon_h1.py`).
+GPT-2, `serve/falcon_h1.py`, `serve/glm4_moe_lite.py`).
 
 The engine is intentionally single-consumer: only the batcher thread
-(scheduler.py) calls prefill/decode; stats reads are lock-free counters.
+(scheduler.py) calls prefill/decode. `stats()` reads lock-free host
+counters and, for a family whose cache holds a counter (the experts'
+load), makes the one device read there is outside the batcher's thread.
 """
 
 from __future__ import annotations
@@ -51,7 +53,13 @@ def family_module(name: str):
     (`expconf.SERVING_FAMILIES`): `config_from`, `resident_params`,
     `init_cache` / `cache_bytes` / `state_bytes`, `prefill`,
     `decode_step`, `copy_block` (None where no block can be shared),
-    `sample`, `kernel_refusal`, `position_limit` and `RECURRENT_STATE`."""
+    `sample`, `kernel_refusal`, `adapter_refusal`, `position_limit`,
+    `RECURRENT_STATE`, `assignments_per_token` (expert assignments a
+    token's forward makes; 0 without routed experts) and `cache_counters`
+    (what `stats()` reads out of the cache itself, fetched only then; {}
+    where the cache holds no counter). `prefill` and `decode_step` are
+    both handed the resolved `attention_impl`, which names every kernel
+    of the family: a prefill with no kernel in it ignores it."""
     if name not in SERVING_FAMILIES:
         raise ValueError(
             f"unknown serving.model {name!r}; supported: "
@@ -258,12 +266,9 @@ class ServingEngine:
         self.adapter_ids: Dict[str, int] = {"base": 0}
         self._adapter_stack = None
         self._slot_adapters = None
-        if adapters and fam.RECURRENT_STATE:
-            raise ValueError(
-                "serving.adapters: a family with recurrent state has no "
-                "adapter arm (an adapter swaps the tied embedding table "
-                "under one shared cache; this family's head is untied and "
-                "its state is the lane's own)")
+        why_not = fam.adapter_refusal(cfg) if adapters else None
+        if why_not:
+            raise ValueError(f"serving.adapters: {why_not}")
         if adapters:
             base_wte = self.params["wte"]
             tables = [base_wte]
@@ -317,6 +322,15 @@ class ServingEngine:
         # state the state kernel moves (ops/ssm_state.py: the live ones).
         self.decode_spans = 0
         self.state_lanes = 0
+        # Prompt tokens prefills found cached (their K/V or latents were
+        # not recomputed) and tokens they ran, summed over prefills.
+        self.prefix_hit_tokens = 0
+        self.prefix_novel_tokens = 0
+        # Expert assignments made (a family with routed experts: tokens
+        # run x experts per token x expert layers), host arithmetic.
+        self._assignments_per_token = fam.assignments_per_token(cfg)
+        self.moe_assignments = 0
+        self._cache_counters: Dict[str, Any] = {}
 
     # -- paged geometry ------------------------------------------------
 
@@ -509,7 +523,7 @@ class ServingEngine:
             here, as `_next_rng` folds it for decode), so a bucket is one
             executable. The logits stay an output, left on the device."""
             cache, logits = fam.prefill(
-                p, c, t, ln, pfx, tbl, cfg, rules,
+                p, c, t, ln, pfx, tbl, cfg, rules, attention_impl=impl,
                 **dict(zip(prefill_extra, extra)))
             first = fam.sample(logits[None], temp[None],
                                jax.random.fold_in(key, step))[0]
@@ -600,8 +614,9 @@ class ServingEngine:
 
     def copy_block(self, src: int, dst: int) -> None:
         """Copy-on-write device copy: pool block `src` → `dst` across all
-        layers (both K and V). The BlockManager decides WHEN (a shared
-        block is about to be written); this mirrors it on-device."""
+        layers (every pool the table addresses). The BlockManager decides
+        WHEN (a shared block is about to be written); this mirrors it
+        on-device."""
         if self.family.copy_block is None:
             raise ValueError(
                 "copy_block: a recurrent state is the lane's own and no "
@@ -685,6 +700,9 @@ class ServingEngine:
         self._cache, first, logits = self._compiled_prefill[bucket](*args)
         self._tables[slot] = table
         self.prefills += 1
+        self.prefix_hit_tokens += cached_len
+        self.prefix_novel_tokens += s_len
+        self.moe_assignments += s_len * self._assignments_per_token
         return first, logits
 
     def _sample_first(self, first, temperature: float) -> int:
@@ -727,13 +745,28 @@ class ServingEngine:
             live = self._tables[:, 0] != self.trash_block
             self.decode_spans += live_spans(
                 positions, live, self.block_size, self.max_blocks_per_seq)
+            n_live = live_lanes(live)
             if self.family.RECURRENT_STATE:
-                self.state_lanes += live_lanes(live)
+                self.state_lanes += n_live
+            self.moe_assignments += n_live * self._assignments_per_token
         with trace.phase("serve.step.fetch"):
             return np.asarray(toks)
 
     def stats(self) -> Dict[str, Any]:
+        if self._cache is not None:
+            # Read out of the cache itself, here and in no step: for a
+            # family that keeps a counter there this is a device fetch,
+            # made on the caller's thread (the HTTP thread for /metrics
+            # and /v1/stats). A read that lost the race with a call the
+            # batcher donated the cache to returns None: keep the last.
+            self._cache_counters = self.family.cache_counters(
+                self.cfg, self._cache, self.num_blocks + 1,
+                self.block_size) or self._cache_counters
         return {
+            **self._cache_counters,
+            "prefix_hit_tokens": self.prefix_hit_tokens,
+            "prefix_novel_tokens": self.prefix_novel_tokens,
+            "moe_assignments": self.moe_assignments,
             "slots": self.slots,
             "adapters": self.adapter_names,
             "max_seq_len": self.max_seq_len,

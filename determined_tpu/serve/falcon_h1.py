@@ -68,6 +68,7 @@ import jax.numpy as jnp
 
 from determined_tpu.models.falcon_h1 import Config
 from determined_tpu.ops import paged_attention, ssm_state
+from determined_tpu.ops.norm_rope import rms_norm, rotary
 from determined_tpu.serve.model import (  # noqa: F401
     _write_rows, narrowed, sample)
 
@@ -82,6 +83,21 @@ config_from = Config.from_published
 def position_limit(cfg: Config) -> Optional[int]:
     """Rotary positions need no table: nothing clips `max_seq_len`."""
     return None
+
+
+def adapter_refusal(cfg: Config) -> Optional[str]:
+    return ("a family with recurrent state has no adapter arm (an adapter "
+            "swaps the tied embedding table under one shared cache; this "
+            "family's head is untied and its state is the lane's own)")
+
+
+def assignments_per_token(cfg: Config) -> int:
+    return 0                 # no routed experts
+
+
+def cache_counters(cfg: Config, cache, pool_blocks: int,
+                   block_size: int) -> Dict[str, Any]:
+    return {}                # the cache holds no counter
 
 
 def kernel_refusal(cfg: Config) -> Optional[str]:
@@ -154,25 +170,6 @@ def cache_bytes(cfg: Config, pool_blocks: int, block_size: int,
 # ----------------------------------------------------------------- block
 
 
-def _rms_norm(x, weight, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(
-        jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
-    return (y * weight.astype(jnp.float32)).astype(x.dtype)
-
-
-def _rotary(x, positions, theta: float):
-    """x [T, heads, Dh] at `positions` [T], the rotate_half convention."""
-    dh = x.shape[-1]
-    inv = 1.0 / theta ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
-    angle = positions.astype(jnp.float32)[:, None] * inv[None]
-    cos = jnp.concatenate([jnp.cos(angle)] * 2, axis=-1)[:, None]
-    sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)[:, None]
-    x32 = x.astype(jnp.float32)
-    turned = jnp.concatenate([-x32[..., dh // 2:], x32[..., :dh // 2]], -1)
-    return (x32 * cos + turned * sin).astype(x.dtype)
-
-
 def _qkv(u, lp, positions, cfg: Config):
     """u [T, d] → q [T, H, Dh], k, v [T, Hkv, Dh], rotated."""
     t = u.shape[0]
@@ -182,8 +179,8 @@ def _qkv(u, lp, positions, cfg: Config):
     qkv = jnp.einsum("td,de->te", u * cfg.attention_in_multiplier,
                      lp["qkv"].astype(dt))
     q, k, v = jnp.split(qkv, [hq * dh, (hq + hkv) * dh], axis=-1)
-    q = _rotary(q.reshape(t, hq, dh), positions, cfg.rope_theta)
-    k = _rotary(k.reshape(t, hkv, dh) * cfg.key_multiplier, positions,
+    q = rotary(q.reshape(t, hq, dh), positions, cfg.rope_theta)
+    k = rotary(k.reshape(t, hkv, dh) * cfg.key_multiplier, positions,
                 cfg.rope_theta)
     return q, k, v.reshape(t, hkv, dh)
 
@@ -234,7 +231,7 @@ def _step_sizes(lp, dt, cfg: Config):
 
 def _mlp(h, lp, cfg: Config):
     dt = cfg.dtype
-    v = _rms_norm(h, lp["pre_ff_norm"], cfg.rms_norm_eps)
+    v = rms_norm(h, lp["pre_ff_norm"], cfg.rms_norm_eps)
     gate = jax.nn.silu(jnp.einsum("td,df->tf", v, lp["gate"].astype(dt))
                        * cfg.mlp_multipliers[0])
     up = jnp.einsum("td,df->tf", v, lp["up"].astype(dt))
@@ -244,7 +241,7 @@ def _mlp(h, lp, cfg: Config):
 
 def _logits(params, h, cfg: Config):
     """h [T, d] → logits [T, V] float32."""
-    h = _rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
+    h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
     return jnp.einsum("td,vd->tv", h, params["lm_head"].astype(cfg.dtype),
                       preferred_element_type=jnp.float32) \
         * cfg.lm_head_multiplier
@@ -268,10 +265,11 @@ def prefill(
     cfg: Config,
     rules=None,
     slot: jax.Array = None,  # scalar int32: the lane whose state this is
+    attention_impl: str = "reference",   # no kernel in this prefill
 ) -> Tuple[Dict[str, jax.Array], jax.Array]:
     """Prefill one prompt from a zero state into lane `slot` → (cache',
     the last real position's logits [V] float32)."""
-    del prefix_len, rules
+    del prefix_len, rules, attention_impl
     s = tokens.shape[0]
     mb = block_table.shape[0]
     bs = cache["k"].shape[2]
@@ -294,7 +292,7 @@ def prefill(
     def body(carry, layer_in):
         h, pool = carry
         lp, layer = layer_in
-        u = _rms_norm(h, lp["input_norm"], cfg.rms_norm_eps)
+        u = rms_norm(h, lp["input_norm"], cfg.rms_norm_eps)
         # attention over the prompt's own keys; their rows go to the pool
         q, k, v = _qkv(u, lp, pos, cfg)
         pool = dict(pool, **_write_rows(pool, layer, dest_blk, dest_off,
@@ -365,7 +363,7 @@ def decode_step(
     def body(carry, layer_in):
         h, pool = carry
         lp, layer = layer_in
-        u = _rms_norm(h, lp["input_norm"], cfg.rms_norm_eps)
+        u = rms_norm(h, lp["input_norm"], cfg.rms_norm_eps)
         q, k, v = _qkv(u, lp, positions, cfg)
         pool = dict(pool, **_write_rows(pool, layer, wblk, woff, k, v))
         attn = paged_attention.paged_decode_attention(

@@ -91,6 +91,17 @@ def prometheus_exposition(stats: Dict[str, Any],
         "# TYPE det_serve_tokens_total counter",
         f"det_serve_tokens_total {stats.get('generated_tokens', 0)}",
     ]
+    engine = stats.get("engine") or {}
+    for name, mtype, key in (
+        ("det_serve_prefix_hit_tokens_total", "counter",
+         "prefix_hit_tokens"),
+        ("det_serve_prefix_novel_tokens_total", "counter",
+         "prefix_novel_tokens"),
+        ("det_serve_moe_assignments_total", "counter", "moe_assignments"),
+        ("det_serve_moe_expert_load_max", "gauge", "moe_expert_tokens_max"),
+        ("det_serve_latent_hbm_bytes", "gauge", "latent_hbm_bytes"),
+    ):
+        lines += [f"# TYPE {name} {mtype}", f"{name} {engine.get(key, 0)}"]
     if latency_wire:
         for name, key in (
             ("det_serve_ttft_seconds", "ttft"),
@@ -135,7 +146,8 @@ def _make_handler(batcher: ContinuousBatcher):
             if self.path == "/metrics":
                 latency = batcher.heartbeat_stats().get("latency")
                 data = prometheus_exposition(
-                    batcher.stats(), latency_wire=latency).encode()
+                    dict(batcher.stats(), engine=batcher.engine.stats()),
+                    latency_wire=latency).encode()
                 self.send_response(200)
                 self.send_header("Content-Type",
                                  "text/plain; version=0.0.4")

@@ -28,10 +28,12 @@ decode mask (`index <= position`) never admits a stale index before the
 step that overwrites it, and padded/inactive writes land in a dedicated
 trash block.
 
-Works for dense and MoE blocks (the MoE FFN routes per token, so a
-1-token decode step reuses ops/moe.moe_block unchanged). All functions are
-shape-static and jit/AOT-friendly; tier-1 exercises them on the CPU
-backend.
+Works for GPT-2's dense blocks and for its Switch-style MoE variant
+(`ops/moe.moe_block`: capacity with drops, routed per token, so a 1-token
+decode step reuses it unchanged). A published sparse model is another
+family, served through the dropless layer (`serve/glm4_moe_lite.py`). All
+functions are shape-static and jit/AOT-friendly; tier-1 exercises them on
+the CPU backend.
 """
 
 from __future__ import annotations
@@ -231,6 +233,7 @@ def paged_prefill(
     rules: Optional[LogicalRules] = None,
     adapters: Optional[jax.Array] = None,   # [A+1, V, D] stack
     slot_adapter: Optional[jax.Array] = None,  # scalar int32 stack index
+    attention_impl: str = "reference",   # no kernel in this prefill
 ) -> Tuple[Dict[str, jax.Array], jax.Array]:
     """Prefill the suffix `tokens[prefix_len:]` of a prompt whose first
     `prefix_len` tokens' K/V already sit in `block_table`'s blocks.
@@ -240,6 +243,7 @@ def paged_prefill(
     over the gathered lane (cached prefix + just-written suffix). With
     `prefix_len == 0` this is a full prefill — same executable.
     """
+    del attention_impl
     s = tokens.shape[0]
     mb = block_table.shape[0]
     bs = cache["k"].shape[2]
@@ -391,6 +395,23 @@ def copy_paged_block(
 # cache, its two steps and its block copy, under names every family shares.
 
 RECURRENT_STATE = False      # K/V is the only thing a sequence leaves
+
+
+def adapter_refusal(cfg: Config) -> Optional[str]:
+    """Adapters swap this family's tied embedding table: served."""
+    return None
+
+
+def assignments_per_token(cfg: Config) -> int:
+    """The Switch variant's routing is not counted: no published sparse
+    model is served through this family."""
+    return 0
+
+
+def cache_counters(cfg: Config, cache, pool_blocks: int,
+                   block_size: int) -> Dict[str, Any]:
+    """This family's cache holds K and V and no counter."""
+    return {}
 
 
 def config_from(mc: Dict[str, Any]) -> Config:

@@ -151,6 +151,59 @@ def test_state_kernel_lowers_through_mosaic_and_moves_no_pool(v5e):
     assert memory.temp_size_in_bytes < pool_bytes // 1000
 
 
+def test_latent_decode_kernel_lowers_through_mosaic(v5e):
+    """GLM-4.7-Flash's attention as configs/glm-4.7-flash.json serves it: 20 heads as the rows of one matmul
+    against a 640-lane latent row (512 + 64, padded), 64 lanes over a
+    pool of 14,336 blocks under a 256-entry table. The custom call bears
+    the name the benchmark's reader looks for."""
+    from determined_tpu.ops.mla_attention import mla_decode_attention
+
+    slots, heads, rank, row, bs, mb = 64, 20, 512, 640, 16, 256
+    mesh = _mesh(v5e[:1], data=1)
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        hlo = jax.jit(mla_decode_attention,
+                      static_argnames=("rank", "scale")).lower(
+            _sds(mesh, (slots, heads, row), jnp.bfloat16),
+            _sds(mesh, (8, 14337, bs, row), jnp.bfloat16),
+            _sds(mesh, (), jnp.int32), _sds(mesh, (slots, mb), jnp.int32),
+            _sds(mesh, (slots,), jnp.int32), rank=rank,
+            scale=256 ** -0.5).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 1
+    assert re.search(r"%mla_decode_attention[.\d]* = bf16\[64,20,512\]", hlo)
+
+
+@pytest.mark.parametrize("tokens", [64, 160, 3200],
+                         ids=["decode", "turn", "first-turn"])
+def test_dropless_expert_layer_lowers_through_mosaic(v5e, tokens):
+    """GLM-4.7-Flash's expert layer at the published widths (64
+    experts of 2,048 x 1,536, top-4) for a decode step's 64 tokens and
+    for both prefill buckets: two grouped matmuls under the name the
+    reader looks for, and no [tokens, experts, capacity] tensor."""
+    from determined_tpu.ops import moe
+
+    e, d, f, k = 64, 2048, 1536, 4
+    mesh = _mesh(v5e[:1], data=1)
+
+    def layer(x, router, bias, w13, w2):
+        return moe.dropless_moe(
+            x, {"router": router, "router_bias": bias, "w13": w13,
+                "w2": w2}, top_k=k, routed_scaling_factor=1.8,
+            impl="pallas")
+
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        compiled = jax.jit(layer).lower(
+            _sds(mesh, (tokens, d), jnp.bfloat16),
+            _sds(mesh, (d, e), jnp.bfloat16), _sds(mesh, (e,), jnp.bfloat16),
+            _sds(mesh, (e, d, 2 * f), jnp.bfloat16),
+            _sds(mesh, (e, f, d), jnp.bfloat16)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 2
+    assert len(re.findall(r"%moe_grouped_matmul[.\d]* = ", hlo)) == 2
+    # nothing of tokens x experts x width: the rows are the assignments'
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 6 * tokens * k * (d + 2 * f) * 4
+
+
 def _opcodes_with_result(hlo, shapes):
     """Instructions of a compiled module (fused computations' bodies too)
     whose result has one of `shapes`, by opcode → count."""

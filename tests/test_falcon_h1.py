@@ -439,7 +439,7 @@ def test_a_family_with_recurrent_state_refuses_what_it_cannot_share():
                                cached_len=16)
     with pytest.raises(ValueError, match="copy_block.*recurrent state"):
         engine.copy_block(0, 1)
-    with pytest.raises(ValueError, match="falcon_h1, gpt2"):
+    with pytest.raises(ValueError, match="falcon_h1, glm4_moe_lite, gpt2"):
         build_model({"model": "mamba"})
     with pytest.raises(ValueError, match="mamba_norm_before_gate"):
         build_model({"model": "falcon_h1", "model_config": dict(
