@@ -29,10 +29,13 @@ Two implementations under `serving.attention_impl`:
     masked softmax over them;
   - `mla_attention_pallas` — the TPU kernel: the span walk of
     `ops/paged_attention.py` (`walk_live_spans`: a grid over lanes, a
-    lane's live 128-token spans only, double-buffered hand copies, the
-    next live lane's first span behind a lane's last fold, idle lanes
-    cost nothing) over ONE pool, and per span one `[H, row] x [row, 128]`
-    product, an online-softmax fold, one `[H, 128] x [128, kv_lora_rank]`
+    lane's live spans only, double-buffered hand copies, the next live
+    lane's first span behind a lane's last fold, idle lanes cost nothing)
+    over ONE pool, in spans of its own, `latent_span_tokens` (512 tokens:
+    the K/V kernel's 128 were chosen for two pools, and with one pool of
+    1,280 B a token their copies are too short to hide a fold's fixed
+    cost, PERF.md §6, PR 36), and per span one `[H, row] x [row, 512]`
+    product, an online-softmax fold, one `[H, 512] x [512, kv_lora_rank]`
     product. Per byte of cache it reads, the kernel does `2 H (row +
     rank) / (2 row)` ≈ 38 operations at 20 heads — far under the chip's
     197e12 / 819e9 = 240, but the 20 heads fill 20 of an MXU pass's 128
@@ -56,8 +59,7 @@ from determined_tpu.ops._pallas_common import (
     online_softmax_update,
     softmax_scratch,
 )
-from determined_tpu.ops.paged_attention import (LANES, span_tokens,
-                                                walk_live_spans)
+from determined_tpu.ops.paged_attention import LANES, walk_live_spans
 
 if HAVE_PALLAS:
     from jax.experimental import pallas as pl
@@ -67,6 +69,14 @@ if HAVE_PALLAS:
 def latent_row(kv_lora_rank: int, rope_dim: int) -> int:
     """Lanes of a pool row: latent and rotary key, in whole 128s."""
     return -(-(kv_lora_rank + rope_dim) // LANES) * LANES
+
+
+def latent_span_tokens(block_size: int, max_blocks: int) -> int:
+    """Tokens one fold of the latent kernel covers: a span of
+    `max(1, 512 // block_size)` consecutive logical blocks of a lane,
+    capped at the lane's whole table. Read from the shapes alone, as the
+    K/V kernel's `span_tokens` is."""
+    return min(max(1, 512 // block_size), max_blocks) * block_size
 
 
 def kernel_refusal(kv_lora_rank: int, rope_dim: int) -> Optional[str]:
@@ -156,7 +166,7 @@ def mla_attention_pallas(q, pool, layer, block_tables, positions, rank: int,
         raise ValueError(f"the latent decode kernel cannot run: {why_not}")
     bs = pool.shape[2]
     mb = block_tables.shape[1]
-    tile = span_tokens(bs, mb)
+    tile = latent_span_tokens(bs, mb)
     span = tile // bs
     # The table is read a span at a time: pad it to whole spans with the
     # trash block (never fetched: it lies past every position).

@@ -221,11 +221,11 @@ def span_tokens(block_size: int, max_blocks: int) -> int:
     return min(max(1, LANES // block_size), max_blocks) * block_size
 
 
-def live_spans(positions, live, block_size: int, max_blocks: int) -> int:
-    """Spans a decode call folds in one layer: `position // span + 1`
-    for each live lane, none for an idle one (host arithmetic on what the
+def live_spans(positions, live, tile: int) -> int:
+    """Spans a decode call folds in one layer: `position // tile + 1`
+    for each live lane, none for an idle one, where `tile` is the tokens
+    of the family's decode kernel's span (host arithmetic on what the
     engine already holds; `engine.stats()` sums it)."""
-    tile = span_tokens(block_size, max_blocks)
     return int(np.sum((np.asarray(positions) // tile + 1)[np.asarray(live)]))
 
 

@@ -55,7 +55,10 @@ def family_module(name: str):
     `decode_step`, `copy_block` (None where no block can be shared),
     `sample`, `kernel_refusal`, `adapter_refusal`, `position_limit`,
     `RECURRENT_STATE`, `assignments_per_token` (expert assignments a
-    token's forward makes; 0 without routed experts) and `cache_counters`
+    token's forward makes; 0 without routed experts),
+    `decode_span_tokens(block_size, max_blocks)` (the tokens one fold of
+    the family's decode kernel covers, which `decode_spans_live` counts
+    in) and `cache_counters`
     (what `stats()` reads out of the cache itself, fetched only then; {}
     where the cache holds no counter). `prefill` and `decode_step` are
     both handed the resolved `attention_impl`, which names every kernel
@@ -744,7 +747,8 @@ class ServingEngine:
             self.decode_steps += 1
             live = self._tables[:, 0] != self.trash_block
             self.decode_spans += live_spans(
-                positions, live, self.block_size, self.max_blocks_per_seq)
+                positions, live, self.family.decode_span_tokens(
+                    self.block_size, self.max_blocks_per_seq))
             n_live = live_lanes(live)
             if self.family.RECURRENT_STATE:
                 self.state_lanes += n_live

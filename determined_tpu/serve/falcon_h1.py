@@ -75,6 +75,7 @@ from determined_tpu.serve.model import (  # noqa: F401
 # What the engine asks a family (serve/engine.py `family_of`).
 RECURRENT_STATE = True
 copy_block = None        # no block of this cache can be shared
+decode_span_tokens = paged_attention.span_tokens   # the grouped K/V kernel's
 # serving.model_config is spelt as the published config.json is, with
 # `dtype` and `state_dtype` (the recurrent state at rest) beside.
 config_from = Config.from_published

@@ -81,6 +81,7 @@ from determined_tpu.serve.model import narrowed, sample  # noqa: F401
 # What the engine asks a family (serve/engine.py `family_of`).
 RECURRENT_STATE = False      # latent blocks are shared as K/V blocks are
 config_from = Config.from_published
+decode_span_tokens = mla_attention.latent_span_tokens
 
 
 def position_limit(cfg: Config) -> Optional[int]:

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from determined_tpu.models.gpt2 import Config, _embed_tokens, _layer_norm
+from determined_tpu.ops.paged_attention import span_tokens
 from determined_tpu.parallel.sharding import LogicalRules, shard_logical
 
 
@@ -444,6 +445,7 @@ def config_from(mc: Dict[str, Any]) -> Config:
 prefill = paged_prefill
 decode_step = paged_decode_step
 copy_block = copy_paged_block
+decode_span_tokens = span_tokens
 
 
 def position_limit(cfg: Config) -> int:

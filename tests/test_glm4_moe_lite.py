@@ -262,29 +262,52 @@ def _latent_case(seed, slots=4, heads=4, rank=128, rope=8, bs=16, mb=20):
             jnp.asarray(q_rope), row)
 
 
-@pytest.mark.parametrize("positions", [
-    (0, 15, 127, 128), (300, 129, 255, 17), (5, 0, 0, 319)],
-    ids=["span-edges", "long", "idle-lanes"])
-def test_latent_kernel_agrees_with_its_reference(positions):
+@pytest.mark.parametrize("positions,mb", [
+    ((0, 15, 127, 128), 20), ((300, 129, 255, 17), 20), ((5, 0, 0, 319), 20),
+    ((511, 512, 1023, 1535), 100), ((1599, 0, 40, 1024), 100)],
+    ids=["span-edges", "long", "idle-lanes", "512-span-edges",
+         "short-beside-long"])
+def test_latent_kernel_agrees_with_its_reference(positions, mb):
     """The Pallas kernel under the TPU interpreter (uninitialised memory
     is NaN there, semaphores simulated) against the jnp twin: positions
     at block and span edges, lanes of several spans, idle lanes (table all
-    trash) between live ones, which write zeros."""
+    trash) between live ones, which write zeros. A table of 20 blocks is
+    one span of 320 tokens (the span is capped at the table); one of 100
+    is three spans of 512 tokens and 4 blocks, padded to whole spans with
+    the trash block, with a lane shorter than a span beside long ones.
+    One jitted call: an eager op dispatched while the interpreter's
+    callbacks are in flight can deadlock."""
     from jax.experimental.pallas import tpu as pltpu
 
-    mla, pool, tables, q_lat, q_rope, row = _latent_case(1)
+    mla, pool, tables, q_lat, q_rope, row = _latent_case(1, mb=mb)
+    assert mla.latent_span_tokens(16, mb) == min(mb * 16, 512)
     trash = pool.shape[1] - 1
     positions = np.array(positions, np.int32)
     idle = (positions == 0) & (np.arange(4) > 0)
     tables = np.where(idle[:, None], trash, tables).astype(np.int32)
     q = mla.absorbed_query(q_lat, q_rope, row)
     args = (q, pool, jnp.int32(1), jnp.asarray(tables),
-            jnp.asarray(positions), 128, 0.25)
-    want = np.asarray(mla.mla_attention_reference(*args))
-    got = np.asarray(mla.mla_attention_pallas(
-        *args, interpret=pltpu.InterpretParams()))
+            jnp.asarray(positions))
+    want = np.asarray(mla.mla_attention_reference(*args, 128, 0.25))
+    kernel = jax.jit(functools.partial(
+        mla.mla_attention_pallas, rank=128, scale=0.25,
+        interpret=pltpu.InterpretParams()))
+    got = np.asarray(kernel(*args))
     assert not got[idle].any()
     np.testing.assert_allclose(got[~idle], want[~idle], atol=2e-5)
+
+
+def test_the_latent_and_kv_kernels_keep_their_own_spans():
+    """The latent kernel folds 512 tokens a span (one pool of 1,280 B a
+    token), capped at the lane's table; the K/V kernel keeps its 128
+    (two pools): each read from its own shapes."""
+    from determined_tpu.ops.mla_attention import latent_span_tokens
+    from determined_tpu.ops.paged_attention import span_tokens
+
+    assert latent_span_tokens(16, 256) == 512
+    assert latent_span_tokens(16, 20) == 320
+    assert latent_span_tokens(32, 256) == 512
+    assert span_tokens(16, 256) == 128
 
 
 def test_absorbed_decode_is_plain_attention_on_the_same_latents():
@@ -471,6 +494,34 @@ def test_first_token_is_sampled_in_the_prefill_call():
             params, p[None], np.arange(len(p), dtype=np.int32)[None],
             dims))[0, -1] for p in prompts]
     check_first_tokens(engine, calls, reference, seed=11)
+
+
+def test_engine_counts_the_latent_kernels_spans():
+    """`decode_spans_live` counts the latent kernel's own 512-token spans:
+    a lane writing at 510..513 counts 1, 1, 2, 2 (it crosses position
+    512), a short lane 1 a step, an idle slot nothing."""
+    config = _float32(TINY)
+    config["serve"].update(max_batch_size=3, max_seq_len=1024,
+                           kv_num_blocks=192, prefill_buckets=[16, 512])
+    params, dims = float_params(config, seed=2)
+    engine, _ = _replica(config, params)
+    assert engine.family.decode_span_tokens(16, 64) == 512
+    rng = np.random.default_rng(8)
+    position = {0: 510, 2: 5}       # slot 1 stays idle
+    last = {slot: engine.prefill_request(
+        slot, rng.integers(0, dims["vocab_size"], n, np.int32))
+        for slot, n in position.items()}
+    assert engine.stats()["decode_spans_live"] == 0
+    for _ in range(4):
+        tokens, positions = np.zeros(3, np.int32), np.zeros(3, np.int32)
+        for slot in position:
+            tokens[slot], positions[slot] = last[slot], position[slot]
+        out = engine.decode(tokens, positions, np.zeros(3, np.float32))
+        for slot in position:
+            last[slot], position[slot] = int(out[slot]), position[slot] + 1
+    stats = engine.stats()
+    assert stats["decode_spans_live"] == (1 + 1 + 2 + 2) + 4
+    assert stats["decode_spans_grid"] == stats["decode_spans_live"]
 
 
 def test_the_family_allows_sharing_and_refuses_adapters():
